@@ -26,7 +26,7 @@ import numpy as np
 
 from . import dtypes as dt
 
-_U32 = jnp.uint64(0xFFFFFFFF)
+_U32 = np.uint64(0xFFFFFFFF)
 
 
 def _u(x):

@@ -755,9 +755,11 @@ CLUSTER_BARRIER_TIMEOUT = conf("srt.cluster.barrierTimeoutSec") \
 
 PALLAS_TILE_ROWS = conf("srt.sql.pallas.tileRows") \
     .doc("Row-tile size for fused pallas reductions (one HBM->VMEM DMA "
-         "per tile; must be a multiple of 1024).") \
-    .check(lambda v: None if v % 1024 == 0 and v > 0
-           else "must be a positive multiple of 1024") \
+         "per tile; must be a multiple of 4096 — a tile is laid out "
+         "(rows/128, 128) and 8-bit masks pack 32 sublanes to a "
+         "register).") \
+    .check(lambda v: None if v % 4096 == 0 and v > 0
+           else "must be a positive multiple of 4096") \
     .integer(8192)
 
 JOIN_BLOOM_ENABLED = conf("srt.sql.join.bloomFilter.enabled") \

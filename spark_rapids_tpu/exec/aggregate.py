@@ -299,17 +299,17 @@ class HashAggregateExec(TpuExec):
 
     def _grouped_pallas_fn(self, ctx: ExecContext):
         """The jitted grouped-lane update, or None (gate miss, either
-        pallas conf off, wrong platform, or Mosaic warmup failure).
+        pallas conf off, or off the chip without the test force).
         srt.sql.pallas.enabled is the master switch owning the
         f32-tile deviation contract; groupedAgg.enabled scopes this
-        lane alone."""
+        lane alone. Every condition is static: a lane chosen here
+        runs, and a Mosaic refusal raises out of the query."""
         from ..conf import PALLAS_ENABLED, PALLAS_GROUPED_ENABLED
         from . import pallas_agg
         if self._eager or not self._pallas_grouped_gate \
                 or not ctx.conf.get(PALLAS_ENABLED) \
                 or not ctx.conf.get(PALLAS_GROUPED_ENABLED) \
-                or not pallas_agg.grouped_lane_on() \
-                or not pallas_agg.grouped_kernel_ok():
+                or not pallas_agg.grouped_lane_on():
             return None
         fn = self._pallas_cache.get("grouped_update")
         if fn is None:
@@ -552,12 +552,11 @@ class HashAggregateExec(TpuExec):
     def _pallas_stream_or_none(self, ctx: ExecContext, agg_time: Metric):
         """Fused filter+aggregate via ops/pallas_kernels.tile_reduce —
         one HBM pass per batch, no filtered intermediate. None keeps the
-        stock XLA path (gate miss, conf off, or warmup lowering
-        failure)."""
+        stock XLA path (static gate miss or conf off); past that the
+        kernel runs or its compile error propagates."""
         from ..conf import PALLAS_ENABLED
         from . import pallas_agg
-        if not self._pallas_gate or not ctx.conf.get(PALLAS_ENABLED) \
-                or self._pallas_cache.get("failed"):
+        if not self._pallas_gate or not ctx.conf.get(PALLAS_ENABLED):
             return None
         from .basic import CoalesceBatchesExec, FilterExec
         source, pred = self.children[0], None
@@ -571,11 +570,8 @@ class HashAggregateExec(TpuExec):
         entry = self._pallas_cache.get(key)
         if entry is None:
             plan = pallas_agg.build_plan(self, pred)
-            fn = jax.jit(plan.batch_fn())
-            if not self._pallas_warmup(plan, fn):
-                self._pallas_cache["failed"] = True
-                return None
-            entry = self._pallas_cache[key] = (plan, fn)
+            entry = self._pallas_cache[key] = (plan,
+                                               jax.jit(plan.batch_fn()))
         plan, fn = entry
 
         def stream():
@@ -604,31 +600,6 @@ class HashAggregateExec(TpuExec):
                 with ctx.semaphore:
                     yield self._jit_merge(packed)
         return stream()
-
-    def _pallas_warmup(self, plan, fn) -> bool:
-        """Compile-check the fused kernel on a tiny synthetic batch so a
-        Mosaic lowering gap falls back BEFORE the child stream is
-        consumed."""
-        schema_d = dict(self.input_schema)
-        cols, names = [], []
-        for n in plan.ref_names:
-            t = schema_d[n]
-            cols.append(ColumnVector(jnp.zeros(8, t.physical),
-                                     jnp.zeros(8, jnp.bool_), t))
-            names.append(n)
-        for n in getattr(plan, "str_names", ()):
-            from ..columnar.vector import StringColumn
-            cols.append(StringColumn(jnp.zeros(9, jnp.int32),
-                                     jnp.zeros(8, jnp.uint8),
-                                     jnp.zeros(8, jnp.bool_),
-                                     pad_bucket=8))
-            names.append(n)
-        try:
-            out = fn(ColumnarBatch(cols, names, jnp.int32(0)))
-            jax.block_until_ready(out)
-            return True
-        except Exception:  # pragma: no cover - backend specific
-            return False
 
     def _empty_global_result(self) -> ColumnarBatch:
         cap = 8
@@ -665,5 +636,13 @@ class HashAggregateExec(TpuExec):
     def node_description(self) -> str:
         aggs = ", ".join(f"{fn.name} as {n}" for fn, n in self.agg_exprs)
         keys = ", ".join(self._key_names)
+        # the statically chosen aggregate lane (the pallas conf switches
+        # are read at execute time and can still keep the XLA path)
+        from . import pallas_agg
+        lane = ""
+        if self._pallas_gate:
+            lane = " (pallas-global)"
+        elif self._pallas_grouped_gate and pallas_agg.grouped_lane_on():
+            lane = " (pallas-grouped)"
         return (f"HashAggregate[{self.mode}, keys=({keys}), "
-                f"aggs=({aggs})]")
+                f"aggs=({aggs})]{lane}")

@@ -18,9 +18,12 @@ float32 tile arithmetic on TPU is the same class of deviation the
 reference ships behind spark.rapids.sql.variableFloatAgg.enabled.
 
 The gate is static and conservative: unsupported aggregate/expression
-shapes simply keep the stock XLA path. A one-time warmup compile on a
-tiny synthetic batch guards against Mosaic lowering gaps at runtime —
-if it fails, the exec permanently falls back before consuming its child.
+shapes keep the stock XLA path, and that is a plan decision
+(``pallas_eligible`` / ``grouped_eligible`` / the conf switches, shown
+in the plan's node description). Once a lane is chosen it either runs
+or raises: a Mosaic refusal on the chip is an error, never a quiet
+switch of lanes. tests/test_tpu_compile.py asks the chip's compiler for
+both kernels at the main path's shapes.
 """
 
 from __future__ import annotations
@@ -43,12 +46,18 @@ from ..ops import pallas_kernels as PK
 
 _SAFE_NODES = (
     E.ColumnRef, E.Literal, E.Alias, Cast,
-    A.Add, A.Subtract, A.Multiply, A.Divide, A.UnaryMinus,
-    A.UnaryPositive, A.Abs, A.Least, A.Greatest,
+    A.Add, A.Subtract, A.Multiply, A.UnaryMinus,
+    A.UnaryPositive, A.Abs,
     Pr.EqualTo, Pr.LessThan, Pr.GreaterThan, Pr.LessThanOrEqual,
     Pr.GreaterThanOrEqual, Pr.EqualNullSafe, Pr.And, Pr.Or, Pr.Not,
-    Pr.IsNull, Pr.IsNotNull, Pr.IsNaN, Pr.InSet,
+    Pr.IsNull, Pr.IsNotNull, Pr.IsNaN,
 )
+# Not here, because the chip's compiler refuses them in a kernel body
+# (each was asked, tests/test_tpu_compile.py style): Divide (Spark's
+# result is always double and Mosaic has no f64), numeric InSet
+# (captures an array constant; string IN is rewritten to
+# _PaddedStrPred first), Least/Greatest (a NaN mask of a splat literal
+# is laid out replicated and the row masks cannot join it).
 _SAFE_DTYPES = (dt.BOOL, dt.INT8, dt.INT16, dt.INT32, dt.DATE,
                 dt.FLOAT32, dt.FLOAT64)
 _FLOATY = (dt.FLOAT32, dt.FLOAT64)
@@ -61,10 +70,10 @@ class _PaddedStrPred(E.Expression):
     """Kernel-side string predicate over the padded byte-lane view —
     the string-predicate kernel family (reference: cuDF string
     comparison kernels feeding filtered reductions). The referenced
-    column's (tile, W) char block + lengths + validity ride the kernel
-    batch's ``str_lanes``; comparison is pure VPU byte arithmetic in
-    VMEM, so dim-filter predicates like cd_gender='M' fuse into the
-    single-pass reduction."""
+    column's W byte planes (W, rows/128, 128) + lengths + validity ride
+    the kernel batch's ``str_lanes``; comparison is pure VPU byte
+    arithmetic in VMEM, so dim-filter predicates like cd_gender='M'
+    fuse into the single-pass reduction."""
 
     def __init__(self, name: str, choices: Sequence[bytes],
                  prefix: bool = False):
@@ -81,17 +90,17 @@ class _PaddedStrPred(E.Expression):
 
     def eval(self, batch) -> ColumnVector:
         chars, lens, valid = batch.str_lanes[self.name]
-        tile, w = chars.shape
-        hit = jnp.zeros(tile, jnp.bool_)
+        w = chars.shape[0]
+        hit = jnp.zeros(lens.shape, jnp.bool_)
         for lit in self.choices:
             m = len(lit)
             if m > w:
                 continue  # longer than any string in this batch
-            eq = jnp.ones(tile, jnp.bool_)
+            eq = jnp.ones(lens.shape, jnp.bool_)
             for j in range(m):  # m is tiny (literal length)
                 # python-int scalars: array constants can't be
                 # captured inside a pallas kernel trace
-                eq = eq & (chars[:, j].astype(jnp.int32) == lit[j])
+                eq = eq & (chars[j].astype(jnp.int32) == lit[j])
             if self.prefix:
                 eq = eq & (lens >= m)
             else:
@@ -192,15 +201,33 @@ def _expr_safe(expr: E.Expression, schema, no_f64: bool = False) -> bool:
     return all(_expr_safe(c, schema, no_f64) for c in expr.children)
 
 
-def _demote_f64(expr: E.Expression) -> E.Expression:
-    """float64 -> float32 rewrite for the TPU kernel trace (Mosaic has
-    no f64). Column data itself is cast outside the kernel; this fixes
-    the literals/casts inside the tree so no f64 op is ever traced."""
-    if isinstance(expr, E.Literal) and expr.dtype == dt.FLOAT64:
-        return E.Literal(float(np.float32(expr.value)), dt.FLOAT32)
-    if isinstance(expr, Cast) and expr.to == dt.FLOAT64:
-        return Cast(_demote_f64(expr.children[0]), dt.FLOAT32, expr.ansi)
-    kids = [_demote_f64(c) for c in expr.children]
+class _KernelLit(E.Literal):
+    """A literal inside the kernel: the splat alone, valid on live rows.
+    ``Literal.eval`` also zeroes dead lanes with a select over two
+    constants, which Mosaic lays out replicated and then cannot relayout
+    the live mask into; every kernel consumer masks by validity anyway."""
+
+    def eval(self, batch) -> ColumnVector:
+        return ColumnVector(
+            jnp.full(batch.capacity, self.physical_value(),
+                     self.dtype.physical),
+            batch.live_mask(), self.dtype)
+
+
+def _kernel_expr(expr: E.Expression, demote_f64: bool) -> E.Expression:
+    """Rewrite an expression tree for tracing inside the kernel:
+    literals become :class:`_KernelLit`, and with ``demote_f64`` (the
+    chip: Mosaic has no f64) float64 literals and casts become float32.
+    Column data itself is cast outside the kernel; this fixes the
+    literals/casts inside the tree so no f64 op is ever traced."""
+    if isinstance(expr, E.Literal):
+        if demote_f64 and expr.dtype == dt.FLOAT64:
+            return _KernelLit(float(np.float32(expr.value)), dt.FLOAT32)
+        return _KernelLit(expr.value, expr.dtype)
+    if demote_f64 and isinstance(expr, Cast) and expr.to == dt.FLOAT64:
+        return Cast(_kernel_expr(expr.children[0], True), dt.FLOAT32,
+                    expr.ansi)
+    kids = [_kernel_expr(c, demote_f64) for c in expr.children]
     if all(a is b for a, b in zip(kids, expr.children)):
         return expr
     clone = copy.copy(expr)
@@ -217,11 +244,17 @@ def _collect_refs(exprs, names: set) -> None:
 
 class _KernelBatch(ColumnarBatch):
     """Shim batch for tracing expressions inside the kernel: live_mask
-    comes from a block input instead of an iota (Mosaic-unfriendly)."""
+    comes from a block input instead of an iota (Mosaic-unfriendly), and
+    ``capacity`` is the (rows/128, 128) row-block SHAPE, so literals
+    (``jnp.full(capacity, v)``) materialize in the tile's layout."""
 
-    def __init__(self, columns, names, num_rows, live):
-        super().__init__(columns, names, num_rows)
+    def __init__(self, columns, names, live):
+        super().__init__(columns, names, live.size)
         self._live = live
+
+    @property
+    def capacity(self):
+        return self._live.shape
 
     def live_mask(self):
         return self._live
@@ -239,7 +272,7 @@ class PallasAggPlan:
             self.str_names = sorted(snames)
         self.pred = pred
         demote = PK.on_tpu()
-        self._prep = _demote_f64 if demote else (lambda e: e)
+        self._prep = lambda e: _kernel_expr(e, demote)
         self.kinds: List[str] = []
         # per agg: list of (state_name, slot_index, state_dtype)
         self.agg_slots: List[List[Tuple[str, int, dt.DType]]] = []
@@ -362,13 +395,12 @@ class PallasAggPlan:
             arrays.append(batch.live_mask().astype(jnp.uint8))
 
             def row_fn(blocks):
-                tile = blocks[-1].shape[0]
                 cols = []
                 for i, (n, st) in enumerate(zip(names, col_dtypes)):
                     cols.append(ColumnVector(blocks[2 * i],
                                              blocks[2 * i + 1] != 0, st))
                 live = blocks[-1] != 0
-                kb = _KernelBatch(cols, list(names), tile, live)
+                kb = _KernelBatch(cols, list(names), live)
                 kb.str_lanes = {}
                 for k, sn in enumerate(str_names):
                     chars = blocks[n_scalar + 3 * k]
@@ -483,26 +515,6 @@ def grouped_lane_on() -> bool:
     SRT_PALLAS_GROUPED_FORCE=1) but costs Python dispatch per tile."""
     import os
     return PK.on_tpu() or os.environ.get("SRT_PALLAS_GROUPED_FORCE") == "1"
-
-
-_GROUPED_WARMUP: dict = {}
-
-
-def grouped_kernel_ok() -> bool:
-    """One-time Mosaic-lowering probe for tile_group_reduce (the same
-    guard-then-permanently-fallback contract as the global lane's
-    warmup): a failure on the real chip must degrade to the XLA path,
-    never crash a query."""
-    if "ok" not in _GROUPED_WARMUP:
-        try:
-            gid = jnp.zeros(16, jnp.int32)
-            vals = [jnp.ones(16, jnp.float32)]
-            out = PK.tile_group_reduce(gid, vals, num_buckets=8,
-                                       tile_rows=8)
-            _GROUPED_WARMUP["ok"] = float(out[0][0]) == 16.0
-        except Exception:
-            _GROUPED_WARMUP["ok"] = False
-    return _GROUPED_WARMUP["ok"]
 
 
 def pallas_eligible(agg_exec) -> bool:

@@ -276,13 +276,19 @@ class Literal(Expression):
                 return Decimal128Column(jnp.where(live, hi, z64),
                                         jnp.where(live, lo, zu),
                                         live, self.dtype)
-        import datetime
-        if isinstance(value, datetime.datetime):
-            value = int(value.replace(tzinfo=datetime.timezone.utc).timestamp() * 1_000_000)
-        elif isinstance(value, datetime.date):
-            value = (value - datetime.date(1970, 1, 1)).days
-        data = jnp.full(cap, value, phys)
+        data = jnp.full(cap, self.physical_value(), phys)
         return ColumnVector(jnp.where(live, data, jnp.zeros((), phys)), live, self.dtype)
+
+    def physical_value(self):
+        """The scalar as its physical lane holds it (temporal values as
+        epoch micros / days)."""
+        import datetime
+        value = self.value
+        if isinstance(value, datetime.datetime):
+            return int(value.replace(tzinfo=datetime.timezone.utc).timestamp() * 1_000_000)
+        if isinstance(value, datetime.date):
+            return (value - datetime.date(1970, 1, 1)).days
+        return value
 
     def __repr__(self):
         return f"lit({self.value!r})"
@@ -341,5 +347,10 @@ def merged_validity(*cols: Column):
 
 def make_result(data, validity, dtype: dt.DType) -> ColumnVector:
     """Standard result construction: zero data lanes under nulls."""
-    data = jnp.where(validity, data, jnp.zeros((), data.dtype))
+    if data.dtype == jnp.bool_:
+        # mask algebra, not a select: Mosaic refuses a select between
+        # bool vectors (it reaches it as an i8->i1 truncation)
+        data = data & validity
+    else:
+        data = jnp.where(validity, data, jnp.zeros((), data.dtype))
     return ColumnVector(data, validity, dtype)

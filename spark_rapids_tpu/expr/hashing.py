@@ -16,14 +16,15 @@ from __future__ import annotations
 from typing import List, Sequence
 
 import jax.numpy as jnp
+import numpy as np
 
 from ..columnar import dtypes as dt
 from ..columnar.vector import Column, ColumnVector, ColumnarBatch, StringColumn
 from ..utils import bits
 from .core import Expression, Schema, make_result
 
-_C1 = jnp.uint32(0xCC9E2D51)
-_C2 = jnp.uint32(0x1B873593)
+_C1 = np.uint32(0xCC9E2D51)
+_C2 = np.uint32(0x1B873593)
 
 
 def _rotl32(x, r: int):
@@ -180,11 +181,11 @@ class Murmur3Hash(Expression):
 # xxhash64 (Spark's XxHash64 expression; JNI Hash.xxhash64 in the reference)
 # ---------------------------------------------------------------------------
 
-_P1 = jnp.uint64(0x9E3779B185EBCA87)
-_P2 = jnp.uint64(0xC2B2AE3D27D4EB4F)
-_P3 = jnp.uint64(0x165667B19E3779F9)
-_P4 = jnp.uint64(0x85EBCA77C2B2AE63)
-_P5 = jnp.uint64(0x27D4EB2F165667C5)
+_P1 = np.uint64(0x9E3779B185EBCA87)
+_P2 = np.uint64(0xC2B2AE3D27D4EB4F)
+_P3 = np.uint64(0x165667B19E3779F9)
+_P4 = np.uint64(0x85EBCA77C2B2AE63)
+_P5 = np.uint64(0x27D4EB2F165667C5)
 
 
 def _rotl64(x, r: int):

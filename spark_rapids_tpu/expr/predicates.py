@@ -151,9 +151,9 @@ class BinaryComparison(Expression):
 def _nan_safe_lt(a, b):
     """a < b with NaN greatest (Spark ordering)."""
     if jnp.issubdtype(a.dtype, jnp.floating):
-        a_nan = jnp.isnan(a)
-        b_nan = jnp.isnan(b)
-        return jnp.where(a_nan, False, jnp.where(b_nan, True, a < b))
+        # pure mask algebra (not where(nan, False, ...)): a select over
+        # bool constants reaches Mosaic as an i8->i1 truncation it refuses
+        return ~jnp.isnan(a) & (jnp.isnan(b) | (a < b))
     return a < b
 
 

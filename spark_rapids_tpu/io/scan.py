@@ -609,7 +609,12 @@ def _iter_file_tables(path: str, fmt: str, schema: Schema,
             # is a row-level pruning OPTIMIZATION only — the Filter
             # node above the scan stays (push_down_filters), so
             # skipping it in the native path is correct.
+            from .. import native
             from .native_parquet import iter_row_group_tables_native
+            # a library that does not build is a failure of the
+            # installation, not one of the per-file surprises caught
+            # below: it raises here instead of becoming a host decode
+            native.load()
             failed = False
             first = None
             try:

@@ -192,6 +192,14 @@ class _SharedProgram:
     def __getattr__(self, name):
         return getattr(self.fn, name)
 
+    def hlo_texts(self) -> list:
+        """Optimized (post-partitioning) HLO of every AOT-cached
+        executable — where the collectives the compiler placed, and any
+        ``tpu_custom_call`` kernels, can be read."""
+        with self._lock:
+            recs = [r for r in self._sigs.values() if r is not None]
+        return [compiled.as_text() for compiled, _, _ in recs]
+
     def drop_executables(self) -> None:
         """Release AOT executables (mmap-guard / cache hygiene; the
         next call re-compiles through the ledger, which records it as

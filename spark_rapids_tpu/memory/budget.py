@@ -273,6 +273,26 @@ _DEVICE_BUDGET: Optional[MemoryBudget] = None
 _BUDGET_LOCK = threading.Lock()
 
 
+# The CPU backend reports no memory_stats(); tests and virtual-mesh
+# rehearsals run there against this explicit stand-in for one chip's HBM.
+CPU_TEST_HBM_BYTES = 16 << 30
+
+
+def device_hbm_bytes(dev) -> int:
+    """``bytes_limit`` of ``dev`` as the backend reports it. The CPU
+    backend gets the documented test budget; any other device that
+    cannot report its limit is an error, not a guess."""
+    stats = dev.memory_stats()
+    if stats and "bytes_limit" in stats:
+        return int(stats["bytes_limit"])
+    if dev.platform == "cpu":
+        return CPU_TEST_HBM_BYTES
+    raise RuntimeError(
+        f"device {dev} ({dev.platform}/{dev.device_kind}) reports no "
+        f"memory_stats()['bytes_limit']; set srt.memory.tpu.poolSize "
+        f"explicitly to size the device budget")
+
+
 def device_budget() -> MemoryBudget:
     """Process-wide device budget, sized from config on first use
     (GpuDeviceManager.initializeRmm analogue)."""
@@ -285,13 +305,7 @@ def device_budget() -> MemoryBudget:
             limit = conf.get(DEVICE_MEMORY_LIMIT)
             if limit <= 0:
                 import jax
-                dev = jax.devices()[0]
-                stats = {}
-                try:
-                    stats = dev.memory_stats() or {}
-                except Exception:
-                    pass
-                hbm = stats.get("bytes_limit", 16 << 30)
+                hbm = device_hbm_bytes(jax.devices()[0])
                 limit = int(hbm * conf.get(DEVICE_MEMORY_FRACTION))
             _DEVICE_BUDGET = MemoryBudget(limit)
         return _DEVICE_BUDGET

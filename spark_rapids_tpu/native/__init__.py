@@ -324,6 +324,14 @@ def orc_decimal64(src: np.ndarray, out: np.ndarray, count: int) -> int:
         out.ctypes.data_as(_ct.POINTER(_ct.c_int64)), count))
 
 
+def load() -> None:
+    """Build (once, from the committed native/*.cpp into native/build/)
+    and load the native library. Raises what g++ or the loader raised —
+    for callers to whom a broken build is a failure, not a reason to
+    take the host path."""
+    _lib()
+
+
 def native_available() -> bool:
     try:
         _lib()

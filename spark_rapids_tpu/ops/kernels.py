@@ -445,7 +445,6 @@ def group_aggregate_pallas(batch: ColumnarBatch, key_cols: Sequence[Column],
                            agg_inputs: Sequence[Optional[Column]],
                            agg_fns: Sequence, row_offset=0,
                            num_buckets: int = 1024,
-                           interpret: Optional[bool] = None,
                            max_capacity: int = 1 << 24,
                            ) -> Tuple[ColumnarBatch, List[dict], jnp.ndarray]:
     """Grouped update pass with the pallas one-hot MXU lane.
@@ -527,8 +526,7 @@ def group_aggregate_pallas(batch: ColumnarBatch, key_cols: Sequence[Column],
             else:  # Count
                 values.append((live & inp.validity).astype(jnp.float32))
         outs = PKn.tile_group_reduce(gid_c, values,
-                                     num_buckets=num_buckets,
-                                     interpret=interpret)
+                                     num_buckets=num_buckets)
         pad = cap - num_buckets
 
         def to_cap(arr, dtype):

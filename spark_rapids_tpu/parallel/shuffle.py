@@ -40,8 +40,6 @@ from __future__ import annotations
 from typing import List, Optional, Sequence
 
 import jax
-
-from .. import shims as _shims
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -196,6 +194,6 @@ def distributed_aggregate(agg_exec, mesh: Mesh,
         return _expand_shard(out)
 
     return jax.jit(
-        _shims.shard_map()(shard_step, mesh=mesh,
+        jax.shard_map(shard_step, mesh=mesh,
                       in_specs=P(DATA_AXIS), out_specs=P(DATA_AXIS),
                       check_vma=False))

@@ -277,6 +277,12 @@ class MeshQueryExecutor:
             out, ok = program(*args)
             if bool(jnp.all(ok)):
                 record["retries"] = retries
+                # where the stage's output actually lives: the ids of
+                # the devices holding its shards (all of the mesh, if
+                # the work was spread)
+                record["output_devices"] = sorted(
+                    {d.id for x in jax.tree_util.tree_leaves(out)
+                     for d in x.devices()})
                 self.stage_records.append(record)
                 return out
             if growth * 2 > max_growth:
@@ -406,19 +412,9 @@ class MeshQueryExecutor:
             return jax.tree_util.tree_map(lambda x: x[None], (out, ok))
 
         def build_program():
-            from ..shims import shard_map as _shard_map
-            sm = _shard_map()
-            # the replication-check kwarg was renamed check_rep ->
-            # check_vma across jax releases; pass whichever applies
-            import inspect
-            sm_params = inspect.signature(sm).parameters
-            check_kw = {}
-            for name in ("check_vma", "check_rep"):
-                if name in sm_params:
-                    check_kw[name] = False
-                    break
-            inner = sm(shard_step, mesh=mesh, in_specs=in_specs,
-                       out_specs=P(ax), **check_kw)
+            inner = jax.shard_map(shard_step, mesh=mesh,
+                                  in_specs=in_specs, out_specs=P(ax),
+                                  check_vma=False)
 
             def staged(*xs):
                 # pin every input to its partition-rule sharding: a
@@ -439,6 +435,7 @@ class MeshQueryExecutor:
             donate_argnums=donate)
         record = {
             "label": label,
+            "program": program,
             "n_inputs": len(slots),
             "donated": list(donate),
             "growth": build.growth,

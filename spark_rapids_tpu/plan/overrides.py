@@ -1243,8 +1243,7 @@ def _insert_fusion(root, conf: SrtConf):
         use_pallas = bool(
             isinstance(terminal, HashAggregateExec) and grouped_conf
             and terminal._pallas_grouped_gate
-            and pallas_agg.grouped_lane_on()
-            and pallas_agg.grouped_kernel_ok())
+            and pallas_agg.grouped_lane_on())
         if len(stages) >= 2 and isinstance(src, (BatchScanExec,
                                                  FileSourceScanExec)):
             # donation is sound only when the source's buffers are

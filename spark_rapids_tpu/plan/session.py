@@ -41,54 +41,58 @@ except ValueError:
 _mmap_counter = [0]
 
 
-def _mmap_guard(session) -> None:
-    """Self-defense against memory-mapping exhaustion (SURVEY §5
-    failure-detection role; observed live in round 4): every compiled
-    XLA executable holds mmap'd code pages, the engine mints fresh jit
-    wrappers per plan, and long many-query processes (the 99-query NDS
-    suite) accumulate mappings monotonically until the kernel's
-    vm.max_map_count (65530 default) is hit — at which point jaxlib
-    SIGSEGVs inside whatever allocation crosses the line (compile OR
-    cache-load). When usage nears the limit, drop every in-memory
-    executable (the persistent disk cache keeps recompiles cheap) and
-    the session's plan cache (its exec trees pin traced jits)."""
-    _mmap_counter[0] += 1
-    if _mmap_counter[0] % _MMAP_CHECK_EVERY:
-        return
+def mmap_pressure() -> bool:
+    """True when this process's memory mappings near the kernel's
+    vm.max_map_count (65530 default): every compiled XLA executable
+    holds mmap'd code pages, the engine mints fresh jit wrappers per
+    plan, and long many-query processes (the 99-query NDS suite, the
+    test suite) accumulate mappings monotonically until the limit is hit
+    — at which point jaxlib SIGSEGVs inside whatever allocation crosses
+    the line (compile, cache write OR cache load)."""
     try:
         with open("/proc/self/maps", "rb") as f:
             used = sum(1 for _ in f)
         with open("/proc/sys/vm/max_map_count", "rb") as f:
             limit = int(f.read())
     except OSError:  # non-Linux: nothing to defend against
-        return
+        return False
     try:
         frac = float(_os.environ.get("SRT_MMAP_GUARD_FRACTION", 0.5))
     except ValueError:
         frac = 0.5
-    debug = _os.environ.get("SRT_MMAP_GUARD_DEBUG")
-    if used < frac * limit:
-        if debug:
-            print(f"[mmap_guard] used={used} limit={limit} (ok)",
-                  file=_sys.stderr, flush=True)
-        return
+    if _os.environ.get("SRT_MMAP_GUARD_DEBUG"):
+        print(f"[mmap_guard] used={used} limit={limit}",
+              file=_sys.stderr, flush=True)
+    return used >= frac * limit
+
+
+def release_compiled_programs() -> None:
+    """Drop every in-memory executable (the persistent disk cache keeps
+    recompiles cheap), returning their mappings to the kernel."""
     import gc
 
     import jax
 
     from ..jit_registry import release_executables
-    session._plan_cache.clear()
     jax.clear_caches()
     # the ledger wrappers hold AOT executables jax's caches don't
     # track — release those mappings too, or the guard under-frees
     release_executables()
     gc.collect()
-    if debug:
-        with open("/proc/self/maps", "rb") as f:
-            after = sum(1 for _ in f)
-        print(f"[mmap_guard] used={used} -> {after} after clear "
-              f"(limit {limit})",
-              file=_sys.stderr, flush=True)
+
+
+def _mmap_guard(session) -> None:
+    """Self-defense against memory-mapping exhaustion (SURVEY §5
+    failure-detection role; observed live in round 4, and again under
+    jax 0.9.0 in PR 21): when usage nears the limit, drop the session's
+    plan cache (its exec trees pin traced jits) and every in-memory
+    executable."""
+    _mmap_counter[0] += 1
+    if _mmap_counter[0] % _MMAP_CHECK_EVERY:
+        return
+    if mmap_pressure():
+        session._plan_cache.clear()
+        release_compiled_programs()
 
 
 class TpuSession:

@@ -2,12 +2,10 @@
 
 Rebuild of Plugin.scala (SURVEY §2.1: RapidsDriverPlugin :282 /
 RapidsExecutorPlugin :348): one idempotent initialization that
-a) verifies the software stack (jax version gate — the reference's
-   checkCudfVersion, Plugin.scala:444),
-b) acquires the device and sizes the HBM batch budget from conf
+a) acquires the device and sizes the HBM batch budget from conf
    (GpuDeviceManager.initializeGpuAndMemory, :150),
-c) initializes the concurrency semaphore,
-d) installs the fatal-error contract: an unrecoverable device error
+b) initializes the concurrency semaphore,
+c) installs the fatal-error contract: an unrecoverable device error
    logs diagnostics and (configurably) exits the process so an external
    supervisor replaces the worker (Plugin.scala:518-541 exit-code
    behavior).
@@ -26,8 +24,6 @@ from .conf import (CONCURRENT_TASKS, DEVICE_MEMORY_FRACTION,
 
 log = logging.getLogger("spark_rapids_tpu")
 
-MIN_JAX_VERSION = (0, 4, 30)
-
 # exit codes mirroring the reference's fatal-error contract
 EXIT_FATAL_DEVICE_ERROR = 20
 
@@ -37,34 +33,11 @@ class DeviceInfo:
     platform: str
     device_kind: str
     num_local_devices: int
-    hbm_bytes: Optional[int]
+    hbm_bytes: int
 
 
 _STATE = {"initialized": False, "info": None}
 _LOCK = threading.Lock()
-
-
-class TpuVersionError(RuntimeError):
-    pass
-
-
-def _check_versions() -> None:
-    import jax
-    ver = tuple(int(x) for x in jax.__version__.split(".")[:3])
-    if ver < MIN_JAX_VERSION:
-        raise TpuVersionError(
-            f"jax {jax.__version__} < required "
-            f"{'.'.join(map(str, MIN_JAX_VERSION))}")
-
-
-def _device_memory_bytes(device) -> Optional[int]:
-    try:
-        stats = device.memory_stats()
-        if stats and "bytes_limit" in stats:
-            return int(stats["bytes_limit"])
-    except Exception:
-        pass
-    return None
 
 
 def initialize(conf_obj: Optional[SrtConf] = None) -> DeviceInfo:
@@ -73,23 +46,21 @@ def initialize(conf_obj: Optional[SrtConf] = None) -> DeviceInfo:
         if _STATE["initialized"]:
             return _STATE["info"]
         c = conf_obj or active_conf()
-        _check_versions()
         import jax
         devices = jax.devices()
         dev = devices[0]
-        hbm = _device_memory_bytes(dev)
         # HBM budget: explicit poolSize, else allocFraction of device
-        from .memory.budget import reset_device_budget
+        from .memory.budget import device_hbm_bytes, reset_device_budget
+        hbm = device_hbm_bytes(dev)
         limit = c.get(DEVICE_MEMORY_LIMIT)
-        if limit <= 0 and hbm:
+        if limit <= 0:
             limit = int(hbm * c.get(DEVICE_MEMORY_FRACTION))
-        if limit > 0:
-            reset_device_budget(limit)
+        reset_device_budget(limit)
         # concurrency semaphore warms up from conf
         from .exec.base import device_semaphore
         device_semaphore()
         info = DeviceInfo(platform=dev.platform,
-                          device_kind=getattr(dev, "device_kind", "?"),
+                          device_kind=dev.device_kind,
                           num_local_devices=len(devices),
                           hbm_bytes=hbm)
         from .shims import load_extra_plugins

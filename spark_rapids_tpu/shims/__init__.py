@@ -1,17 +1,9 @@
-"""Version shim layer + extra-plugin loader.
+"""Extra-plugin loader.
 
-Reference surface (SURVEY §2.1): ShimLoader.scala + the per-version
-shim source sets (sql-plugin/src/main/spark3xx/...) select
-implementations by Spark version at runtime; RapidsPluginUtils
-loadExtraPlugins instantiates user-supplied plugin classes.
-
-The TPU rebuild targets one engine, so the moving ABI is the JAX API
-itself (symbols migrate between jax.experimental and jax across
-releases — shard_map did exactly this). ``ShimRegistry`` keeps a
-version-ranged provider table per capability; ``resolve`` picks the
-first provider whose range matches the running jax version and whose
-probe succeeds, so the engine loads against multiple jax releases
-without scattering try/except ImportError through operator code.
+Reference surface (SURVEY §2.1): RapidsPluginUtils.loadExtraPlugins
+instantiates user-supplied plugin classes. (The reference's per-version
+ShimLoader has no counterpart here: the engine is written for the one
+installed jax and calls its public API directly.)
 
 ``load_extra_plugins`` applies srt.plugins ("pkg.module:attr" entries,
 comma-separated): each attr is called with the active conf at
@@ -20,87 +12,8 @@ initialize time — the loadExtraPlugins contract for user extensions.
 
 from __future__ import annotations
 
-from typing import Callable, Dict, List, Optional, Tuple
+from typing import List
 
-
-def _version_tuple(v: str) -> Tuple[int, ...]:
-    parts = []
-    for p in v.split("."):
-        digits = ""
-        for ch in p:
-            if not ch.isdigit():
-                break
-            digits += ch
-        if not digits:
-            break
-        parts.append(int(digits))
-    return tuple(parts) or (0,)
-
-
-class ShimRegistry:
-    def __init__(self):
-        # name -> [(min_incl, max_excl, provider)]
-        self._table: Dict[str, List[Tuple[Optional[tuple],
-                                          Optional[tuple],
-                                          Callable]]] = {}
-        self._cache: Dict[str, object] = {}
-
-    def register(self, name: str, provider: Callable,
-                 min_version: Optional[str] = None,
-                 max_version: Optional[str] = None) -> None:
-        lo = _version_tuple(min_version) if min_version else None
-        hi = _version_tuple(max_version) if max_version else None
-        self._table.setdefault(name, []).append((lo, hi, provider))
-
-    def resolve(self, name: str):
-        """First matching provider whose probe doesn't raise."""
-        if name in self._cache:
-            return self._cache[name]
-        import jax
-        cur = _version_tuple(jax.__version__)
-        errors = []
-        for lo, hi, provider in self._table.get(name, []):
-            if lo is not None and cur < lo:
-                continue
-            if hi is not None and cur >= hi:
-                continue
-            try:
-                out = provider()
-            except Exception as e:  # probe failure: try older shim
-                errors.append(f"{provider.__name__}: {e}")
-                continue
-            self._cache[name] = out
-            return out
-        raise ImportError(
-            f"no shim for {name!r} matches jax {jax.__version__}: "
-            f"{'; '.join(errors) or 'no providers registered'}")
-
-
-SHIMS = ShimRegistry()
-
-
-# --- registered shims ------------------------------------------------------
-
-def _shard_map_current():
-    import jax
-    return jax.shard_map  # jax >= 0.6 public API
-
-
-def _shard_map_experimental():
-    from jax.experimental.shard_map import shard_map
-    return shard_map
-
-
-SHIMS.register("shard_map", _shard_map_current, min_version="0.6")
-SHIMS.register("shard_map", _shard_map_experimental)
-
-
-def shard_map():
-    """The shard_map entry point for the running jax release."""
-    return SHIMS.resolve("shard_map")
-
-
-# --- extra plugin loader ---------------------------------------------------
 
 def load_extra_plugins(conf) -> List[object]:
     """srt.plugins = 'pkg.module:attr,pkg2.mod:attr2' — import each and

@@ -544,6 +544,8 @@ def test_speculative_barrier_protocol():
         driver.shutdown()
 
 
+# slow: ~12 s, real worker subprocesses
+@pytest.mark.slow
 def test_cluster_speculation_end_to_end(tmp_path_factory):
     """Real 2-worker cluster: worker 1 stalls 6s at the barrier via
     fault injection, worker 0 speculates its shard, the job finishes
@@ -614,6 +616,8 @@ def test_cluster_speculation_end_to_end(tmp_path_factory):
                 p.kill()
 
 
+# slow: ~13 s, real worker subprocesses
+@pytest.mark.slow
 def test_stage_retry_with_adaptive_replan(tmp_path_factory):
     """Stage-level retry x adaptive: worker 1 crashes at the final
     (range-exchange) barrier AFTER the hash exchange completed, with
@@ -701,7 +705,10 @@ def nds_ab_data(tmp_path_factory):
     return str(tmp_path_factory.mktemp("adaptive_nds") / "data")
 
 
-@pytest.mark.parametrize("qid", NDS_AB_QUERIES)
+# tier-1 keeps one NDS shape (~7-11 s each)
+@pytest.mark.parametrize("qid", [
+    q if q == "q42" else pytest.param(q, marks=pytest.mark.slow)
+    for q in NDS_AB_QUERIES])
 def test_nds_adaptive_bit_identical(nds_ab_data, qid):
     """Adaptive on vs off must be BIT-IDENTICAL on NDS queries:
     coalescing only regroups disjoint hash buckets, so every key's

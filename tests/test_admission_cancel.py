@@ -368,6 +368,8 @@ def test_prefetch_producer_observes_cancel_token():
 
 # ------------------------------------------------------- cluster teardown
 
+# slow: ~10 s, real worker subprocesses
+@pytest.mark.slow
 def test_cluster_deadline_and_cancel_propagation(tmp_path):
     """Typed interrupts across the process boundary: a worker-side
     deadline (shipped via the job conf) and a driver-side cancel

@@ -8,6 +8,9 @@ import os
 import numpy as np
 import pytest
 
+# slow: every test here drives real worker subprocesses (~80 s in all)
+pytestmark = pytest.mark.slow
+
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.conf import SrtConf
 from spark_rapids_tpu.expr import col

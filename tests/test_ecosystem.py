@@ -107,18 +107,6 @@ def test_api_validation_no_orphans():
     assert out.returncode == 0, out.stdout + out.stderr
 
 
-def test_shim_registry_resolves_shard_map():
-    from spark_rapids_tpu.shims import SHIMS, shard_map
-    fn = shard_map()
-    assert callable(fn)
-    # resolution is cached
-    assert shard_map() is fn
-    # unknown capability raises with diagnostics
-    import pytest
-    with pytest.raises(ImportError, match="no shim"):
-        SHIMS.resolve("does_not_exist")
-
-
 def test_extra_plugin_loader(tmp_path, monkeypatch):
     import sys
 

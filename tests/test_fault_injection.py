@@ -288,6 +288,8 @@ def crash_dataset(tmp_path_factory):
     return {"fact": fact_dir, "n": n}
 
 
+# slow: ~13 s, crashes a real worker subprocess
+@pytest.mark.slow
 def test_worker_crash_at_stage_boundary_stage_level_rerun(crash_dataset):
     """Flagship acceptance path: logical worker 1 crashes at the final
     (range-exchange) barrier of a two-stage job, AFTER the hash
@@ -343,6 +345,8 @@ def test_worker_crash_at_stage_boundary_stage_level_rerun(crash_dataset):
 
 # ------------------------------------------------------- chaos smoke
 
+# slow: ~140 s of multi-process chaos legs (tools/chaos_check.py --quick)
+@pytest.mark.slow
 def test_chaos_check_quick():
     """tools/chaos_check.py --quick: a seeded fault-plan sweep over a
     real 2-worker cluster must stay oracle-identical and exit 0 within

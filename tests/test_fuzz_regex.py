@@ -97,6 +97,8 @@ def test_pattern_pool_size():
     assert len(_PATTERNS) >= 50  # VERDICT floor: >50 generated cases
 
 
+# slow: a fuzzer — ~100 s for the six chunks
+@pytest.mark.slow
 @pytest.mark.parametrize("chunk", range(6))
 def test_rlike_fuzz_matches_python_re(chunk):
     """10 patterns x 40 subjects per chunk: device NFA simulation must

@@ -14,6 +14,9 @@ import time
 import numpy as np
 import pytest
 
+# slow: every test here drives real worker subprocesses (~70 s in all)
+pytestmark = pytest.mark.slow
+
 from spark_rapids_tpu.conf import SrtConf, set_active_conf
 from spark_rapids_tpu.expr import col
 from spark_rapids_tpu.expr.aggregates import CountStar, Sum

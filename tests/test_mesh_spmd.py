@@ -389,7 +389,10 @@ def nds():
     return s, conf, NDS_QUERIES
 
 
-@pytest.mark.parametrize("qid", ["q3", "q42", "q52"])
+# tier-1 keeps one NDS shape on the virtual mesh (~8-12 s each)
+@pytest.mark.parametrize("qid", [
+    pytest.param("q3", marks=pytest.mark.slow), "q42",
+    pytest.param("q52", marks=pytest.mark.slow)])
 def test_nds_stage_identity(mesh, nds, qid):
     s, conf, queries = nds
     df = s.sql(queries[qid])
@@ -416,6 +419,8 @@ def test_nds_stage_identity(mesh, nds, qid):
     assert _metric_total(ex, phys, "shuffleBytesWritten") == 0
 
 
+# slow: ~20 s for one query on the virtual mesh
+@pytest.mark.slow
 def test_nds_q19_completes_on_virtual_mesh(mesh):
     """Regression: q19's join-heavy shape aborted (rc=-6 rendezvous /
     48GB cap) under the whole-plan grow-and-retry ladder. The staged

@@ -8,7 +8,7 @@ import pyarrow as pa
 import pytest
 from pyarrow import orc
 
-import spark_rapids_tpu  # noqa: F401 (platform setup)
+import spark_rapids_tpu  # noqa: F401 (enables x64)
 from spark_rapids_tpu.columnar import dtypes as dt
 from spark_rapids_tpu.conf import SrtConf
 from spark_rapids_tpu.io.native_orc import read_orc_native

@@ -93,6 +93,8 @@ def test_ooc_sort_survives_injected_retry_oom():
         "the injected OOM must have gone through the retry path"
 
 
+# slow: ~15 s; test_ooc_sort_correct_and_bounded keeps the OOC path in tier 1
+@pytest.mark.slow
 def test_ooc_sort_cascade_many_runs():
     """k runs far above budget/(2*256): the cascade pre-merge keeps
     the residency bound instead of letting carry grow to k*256."""

@@ -481,7 +481,8 @@ def test_history_report_advisor_rules(tmp_path):
     # every rule is evaluated and reported
     assert set(rules) == {"shuffle-partition-skew",
                           "prefetch-starvation", "spill-pressure",
-                          "fetch-instability", "worker-straggler"}
+                          "fetch-instability", "worker-straggler",
+                          "adaptive-coverage"}
     assert rules["shuffle-partition-skew"]["triggered"]
     assert "srt.shuffle.partitions" in \
         rules["shuffle-partition-skew"]["suggestion"]
