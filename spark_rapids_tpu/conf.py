@@ -513,16 +513,15 @@ ROOFLINE_ENABLED = conf("srt.obs.roofline.enabled") \
     .boolean(True)
 
 ROOFLINE_SAMPLE_EVERY = conf("srt.obs.roofline.sampleEvery") \
-    .doc("Device-time sampling stride for shared jit programs: every "
-         "Nth launch of each program is timed with a device sync and "
-         "joined with the compile ledger's bytes/flops to produce "
-         "achieved GB/s and FLOP/s (effective_gb_s histograms, "
-         "per-query RooflineSummary). Steady-state cost is one counter "
-         "increment per launch plus one block_until_ready per N "
-         "launches — under 2 percent at the default. 0 disables "
-         "sampling (and "
-         "per-query roofline summaries) entirely.") \
-    .check(_non_negative).integer(32)
+    .doc("Device-time sampling stride for shared jit programs, opt-in: "
+         "with N > 0 every Nth launch of each program is timed with a "
+         "device sync and joined with the compile ledger's bytes/flops "
+         "to produce achieved GB/s and FLOP/s (effective_gb_s "
+         "histograms, per-query RooflineSummary). Each sampled launch "
+         "costs one block_until_ready on the launching thread, which "
+         "stalls the pipeline behind it. 0 (default) makes no sync and "
+         "emits no per-query roofline summaries.") \
+    .check(_non_negative).integer(0)
 
 ROOFLINE_CALIBRATE = conf("srt.obs.roofline.calibrate") \
     .doc("Measure this process's peak copy bandwidth once (a ~64MB "

@@ -28,7 +28,7 @@ from ..columnar.vector import (ColumnVector, ColumnarBatch, choose_capacity,
                                live_mask)
 from ..expr.aggregates import AggregateFunction
 from ..expr.core import Expression, make_result, output_name
-from ..jit_registry import shared_fn_jit, shared_method_jit
+from ..jit_registry import named_jit, shared_fn_jit, shared_method_jit
 from ..ops import kernels as K
 from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
 
@@ -570,8 +570,10 @@ class HashAggregateExec(TpuExec):
         entry = self._pallas_cache.get(key)
         if entry is None:
             plan = pallas_agg.build_plan(self, pred)
-            entry = self._pallas_cache[key] = (plan,
-                                               jax.jit(plan.batch_fn()))
+            # a closure over this exec's plan: private, but named and
+            # launched like a shared program
+            entry = self._pallas_cache[key] = (plan, named_jit(
+                plan.batch_fn(), "HashAggregateExec._pallas_stream"))
         plan, fn = entry
 
         def stream():

@@ -16,18 +16,9 @@ from typing import Dict, Iterator, List, Optional, Sequence
 from ..columnar import dtypes as dt
 from ..columnar.vector import ColumnarBatch
 from ..conf import CONCURRENT_TASKS, SrtConf, active_conf
+from ..obs.trace import annotate
 
 Schema = List  # [(name, DType), ...]
-
-# Resolved once: the profiler annotation class used by the scoped
-# timers. Both timers run on every operator pull, so the per-enter
-# ``import jax.profiler`` + except dance was measurable overhead on
-# the hot path (part of the roofline layer's <=2% sampling budget);
-# a module-level None check is the same cost as the tracer gate.
-try:
-    from jax.profiler import TraceAnnotation as _TraceAnnotation
-except Exception:  # pragma: no cover - jax always present in-tree
-    _TraceAnnotation = None
 
 
 class Metric:
@@ -66,23 +57,17 @@ class NvtxTimer:
         self.metric = metric
         self.name = name
         self._t0 = 0.0
+        self._trace = None
 
     def __enter__(self):
         self._t0 = time.perf_counter_ns()
-        if _TraceAnnotation is not None:
-            try:
-                self._trace = _TraceAnnotation(self.name or "op")
-                self._trace.__enter__()
-            except Exception:
-                self._trace = None
-        else:
-            self._trace = None
+        self._trace = annotate(self.name or "op")
+        self._trace.__enter__()
         return self
 
     def __exit__(self, *exc):
         try:
-            if self._trace is not None:
-                self._trace.__exit__(*exc)
+            self._trace.__exit__(*exc)
         finally:
             self._trace = None
             if self.metric is not None:
@@ -137,18 +122,13 @@ class SelfTimer:
             self._span = self.tracer.begin(self.name or "op",
                                            kind="operator",
                                            parent=parent_id)
-        if _TraceAnnotation is not None:
-            try:
-                self._trace = _TraceAnnotation(self.name or "op")
-                self._trace.__enter__()
-            except Exception:
-                self._trace = None
+        self._trace = annotate(self.name or "op")
+        self._trace.__enter__()
         return self
 
     def __exit__(self, *exc):
         try:
-            if self._trace is not None:
-                self._trace.__exit__(*exc)
+            self._trace.__exit__(*exc)
         finally:
             self._trace = None
             t = time.perf_counter_ns()

@@ -74,19 +74,32 @@ def fusion_stats() -> dict:
 
 
 def _row_stage_fn(spec):
+    """One fused stage as a batch -> batch function. Each stage traces
+    under ``jax.named_scope(<the operator it replaces>)``, so inside a
+    fused program an op's ``op_name`` still says whose it is."""
     kind = spec[0]
     if kind == "filter":
         cond = spec[1]
 
         def filt(batch: ColumnarBatch) -> ColumnarBatch:
-            return K.filter_batch(batch, cond.eval(batch))
+            with jax.named_scope("FilterExec"):
+                return K.filter_batch(batch, cond.eval(batch))
         return filt
     exprs, names = spec[1], spec[2]
 
     def proj(batch: ColumnarBatch) -> ColumnarBatch:
-        return ColumnarBatch([e.eval(batch) for e in exprs],
-                             list(names), batch.num_rows)
+        with jax.named_scope("ProjectExec"):
+            return ColumnarBatch([e.eval(batch) for e in exprs],
+                                 list(names), batch.num_rows)
     return proj
+
+
+def _agg_stage(shell, use_pallas: bool, batch, row_offset):
+    """The aggregate that ends a fused chain: (packed, pallas_used)."""
+    with jax.named_scope("HashAggregateExec"):
+        if use_pallas:
+            return shell._update_pallas(batch, row_offset)
+        return shell._update(batch, row_offset), jnp.bool_(False)
 
 
 def _agg_shell(spec):
@@ -126,11 +139,7 @@ def _fused_program_builder(specs):
         for f in stage_fns:
             batch = f(batch)
         rows_in = batch.num_rows
-        if use_pallas:
-            packed, used = shell._update_pallas(batch, row_offset)
-        else:
-            packed = shell._update(batch, row_offset)
-            used = jnp.bool_(False)
+        packed, used = _agg_stage(shell, use_pallas, batch, row_offset)
         return packed, rows_in, used
     return run_agg
 
@@ -165,10 +174,14 @@ def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
         names = out.names[reorder_n:] + out.names[:reorder_n]
         return ColumnarBatch(cols, names, out.num_rows)
 
+    def join(probe, build):
+        with jax.named_scope("HashJoinExec"):
+            out, total = base(probe, build)
+            return reorder(out), total
+
     if not has_agg:
         def run(probe, build):
-            out, total = base(probe, build)
-            out = reorder(out)
+            out, total = join(probe, build)
             for f in stage_fns:
                 out = f(out)
             return out, total
@@ -177,16 +190,11 @@ def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
     use_pallas = bool(specs[-1][1])
 
     def run_agg(probe, build, row_offset):
-        out, total = base(probe, build)
-        out = reorder(out)
+        out, total = join(probe, build)
         for f in stage_fns:
             out = f(out)
         rows_in = out.num_rows
-        if use_pallas:
-            packed, used = shell._update_pallas(out, row_offset)
-        else:
-            packed = shell._update(out, row_offset)
-            used = jnp.bool_(False)
+        packed, used = _agg_stage(shell, use_pallas, out, row_offset)
         return packed, rows_in, used, total
     return run_agg
 
@@ -207,7 +215,8 @@ def _fused_merge_builder(prefix_specs, agg_spec, cap):
             else K.concat_batches(list(batches), cap)
         for f in stage_fns:
             b = f(b)
-        return shell._merge_finalize(b)
+        with jax.named_scope("HashAggregateExec"):
+            return shell._merge_finalize(b)
     return run
 
 

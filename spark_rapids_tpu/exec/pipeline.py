@@ -59,6 +59,7 @@ from typing import Callable, Iterable, Iterator, Optional
 
 from ..conf import (PIPELINE_DEPTH, PIPELINE_ENABLED, PIPELINE_MAX_BYTES,
                     SrtConf, set_active_conf)
+from ..obs.trace import annotate
 from .base import ExecContext, Metric, Schema, TpuExec
 
 __all__ = ["PrefetchIterator", "PrefetchExec", "prefetch_batches",
@@ -311,13 +312,18 @@ class PrefetchIterator:
                     self._flush_peaks()
                     raise StopIteration
                 t0 = time.perf_counter_ns()
-                if self._query is not None:
-                    # typed teardown even when the producer is wedged
-                    # in a hung source: poll the token while waiting
-                    self._query.check()
-                    self._cv.wait(timeout=0.25)
-                else:
-                    self._cv.wait()
+                # the consumer stands blocked on the producer: the same
+                # interval prefetchWaitTime counts, on the profiler's
+                # clock (one range per wake-up)
+                with annotate("prefetch.wait"):
+                    if self._query is not None:
+                        # typed teardown even when the producer is
+                        # wedged in a hung source: poll the token
+                        # while waiting
+                        self._query.check()
+                        self._cv.wait(timeout=0.25)
+                    else:
+                        self._cv.wait()
                 waited += time.perf_counter_ns() - t0
 
     def _flush_peaks(self) -> None:

@@ -13,9 +13,8 @@ from __future__ import annotations
 import io
 from typing import Iterator, List, Tuple
 
-import jax
-
 from ..columnar.vector import ColumnarBatch
+from ..jit_registry import named_jit
 from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
 
 
@@ -35,7 +34,8 @@ class ArrowEvalPythonExec(TpuExec):
                     names.append(f"in{i}_{j}")
             return ColumnarBatch(cols, names, batch.num_rows)
 
-        self._jit_inputs = jax.jit(project_inputs)
+        self._jit_inputs = named_jit(
+            project_inputs, "ArrowEvalPythonExec.project_inputs")
 
     @property
     def output_schema(self) -> Schema:

@@ -27,6 +27,7 @@ import glob as globlib
 import logging
 import os
 import struct as structlib
+import time
 from typing import Iterator, List, Optional, Sequence
 
 import numpy as np
@@ -38,6 +39,7 @@ from ..conf import (MAX_READER_BATCH_SIZE_ROWS, READER_THREADS, READER_TYPE)
 from ..exec.base import ExecContext, Metric, Schema, TpuExec
 from ..expr import core as E
 from ..expr import predicates as P
+from ..obs.trace import annotate
 from ..plan.host_table import HostTable, concat_tables, table_to_batch
 from ..plan.logical import LogicalPlan
 from ..robustness.faults import fault_point
@@ -466,6 +468,33 @@ def _is_missing_file_error(e: BaseException) -> bool:
         isinstance(e, OSError) and e.errno == errno.ENOENT)
 
 
+def _timed_decode(tables: Iterator[HostTable], decode_time
+                  ) -> Iterator[HostTable]:
+    """Charge the time spent INSIDE ``tables`` (read + decode +
+    conform of each table it yields, not the time its consumer holds
+    the table) to the scan's ``scanDecodeTime``. It runs on whichever
+    thread decodes — the reader pool's in the MULTITHREADED reader — so
+    the sum over threads may exceed the wall. A counter and no profiler
+    range, on purpose: pool threads are busy most of the time, and a
+    short range there would be taken for the cause of device idle gaps
+    it only overlaps (PERF.md, "decode has no span")."""
+    if decode_time is None:
+        yield from tables
+        return
+    try:
+        while True:
+            t0 = time.perf_counter_ns()
+            try:
+                table = next(tables)
+            except StopIteration:
+                return
+            finally:
+                decode_time.add(time.perf_counter_ns() - t0)
+            yield table
+    finally:
+        tables.close()
+
+
 def iter_file_tables(path: str, fmt: str, schema: Schema,
                      options: dict, arrow_filter,
                      max_rows: int, conf=None,
@@ -488,9 +517,10 @@ def iter_file_tables(path: str, fmt: str, schema: Schema,
     cnf = conf or active_conf()
     try:
         fault_point("scan.file", detail=path)
-        yield from _named_file_tables(path, fmt, schema, options,
-                                      arrow_filter, max_rows, conf,
-                                      partition_values)
+        yield from _timed_decode(
+            _named_file_tables(path, fmt, schema, options, arrow_filter,
+                               max_rows, conf, partition_values),
+            (options or {}).get("_decode_time"))
     except Exception as e:
         if _is_missing_file_error(e):
             if cnf.get(IGNORE_MISSING_FILES):
@@ -820,6 +850,11 @@ class FileSourceScanExec(TpuExec):
         self._decode_stats = {"native_files": 0, "host_files": 0,
                               "host_columns": 0}
         options["_decode_stats"] = self._decode_stats
+        # read + decode + conform of every file, timed where it runs
+        # (iter_file_tables, on the pool's threads): thread time
+        options["_decode_time"] = ctx.metrics_for(self.exec_id).setdefault(
+            "scanDecodeTime",
+            Metric("scanDecodeTime", Metric.MODERATE, "ns"))
         args = (self.scan.fmt, self._schema, options,
                 self._arrow_filter, max_rows, conf)
         scan_paths = self.scan.pruned_paths()
@@ -888,18 +923,32 @@ class FileSourceScanExec(TpuExec):
         m = ctx.metrics_for(self.exec_id)
         scan_time = m.setdefault("scanTime", Metric("scanTime",
                                                     Metric.MODERATE, "ns"))
-        import time
+        scan_wait = m.setdefault("scanWaitTime", Metric("scanWaitTime",
+                                                        Metric.MODERATE,
+                                                        "ns"))
         from ..expr.misc import set_input_file
         empty = True
         sizes = {}
-        for path, table in self._host_tables(ctx):
+        tables = self._host_tables(ctx)
+        while True:
+            # this thread stands waiting for the next decoded table: on
+            # the reader pool's future, or decoding inline where the
+            # reader has no pool
             t0 = time.perf_counter_ns()
+            with annotate("scan.wait"):
+                item = next(tables, None)
+            t1 = time.perf_counter_ns()
+            scan_wait.add(t1 - t0)
+            if item is None:
+                break
+            path, table = item
             if table.num_rows == 0 and not empty:
                 continue
             empty = False
-            with ctx.semaphore:  # held only for the upload
+            with annotate("scan.upload"), ctx.semaphore:
+                # the semaphore is held only for the upload
                 batch = table_to_batch(table)
-            scan_time.add(time.perf_counter_ns() - t0)
+            scan_time.add(time.perf_counter_ns() - t1)
             # file context for input_file_name()/blocks: whole-file
             # reads report (0, file_size); coalesced multi-file batches
             # have no single file (empty name, Spark contract)

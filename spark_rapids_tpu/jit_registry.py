@@ -34,11 +34,29 @@ new input signature through ``trace()/lower()/compile()`` with each
 phase wall-timed, captures XLA ``cost_analysis()`` flops/bytes, and
 keeps the compiled executable for direct dispatch (so the AOT step
 REPLACES jit's internal first-call trace, it does not duplicate it).
-Launches are counted on the ledger entry, and every Nth launch
-(``srt.obs.roofline.sampleEvery``) is timed with a device sync and
-joined with the program's bytes/flops into achieved GB/s. Disable
-just the ledger with ``SRT_JIT_LEDGER=0`` (plain ``jax.jit`` wrappers,
-pre-ledger behavior).
+Launches are counted on the ledger entry, and with
+``srt.obs.roofline.sampleEvery`` = N > 0 (off by default) every Nth
+launch is timed with a device sync and joined with the program's
+bytes/flops into achieved GB/s. Disable just the ledger with
+``SRT_JIT_LEDGER=0`` (plain ``jax.jit`` wrappers, pre-ledger behavior).
+
+Program names. Every program jitted here carries its structural label
+as its name: the function handed to ``jax.jit`` is renamed to the label
+(``FilterExec._filter``, ``_fused_program_builder``, a stage's label)
+and runs under ``jax.named_scope`` of it, so the HLO module — what a
+device trace and a compile log show — is ``jit_FilterExec._filter``
+rather than ``jit__filter`` / ``jit_run`` / ``jit(<lambda>)``. A name
+says which program SHAPE it is and nothing of one instance (no
+fingerprint, capacity, address or plan id): it is the same from run to
+run. Sharing is by registry key, never by name. ``named_jit`` gives the
+few private jits outside the registry the same name and launch range.
+
+Launch ranges. Each dispatch of a program runs inside a host range
+``launch.<label>`` on the profiler's clock (obs/trace.py ``annotate``)
+on the dispatching thread, around the asynchronous dispatch and not
+around a sync; its wall time and a count are charged to the thread's
+current query token (``dispatch_ns`` / ``launches`` of the query
+record's ``phases``).
 
 Reference role: the spark-rapids plugin loads/caches each cuDF kernel
 once per JVM, not once per operator instance
@@ -51,13 +69,18 @@ private ``jax.jit``) when isolating trace-level bugs.
 
 from __future__ import annotations
 
+import functools
 import hashlib
 import os
+import re
 import threading
 import time
 from typing import Callable, Dict, Optional, Sequence
 
 import jax
+
+from .obs.trace import annotate as _host_range
+from .robustness.admission import current_query
 
 _REGISTRY: Dict = {}
 # RLock so the counter helpers may take it even when the caller
@@ -156,6 +179,64 @@ def _signature(args):
     return treedef, tuple(shaped_abstractify(x) for x in leaves)
 
 
+def program_name(label: str) -> str:
+    """``label`` cut to what an HLO module name keeps: letters, digits,
+    ``_`` and ``.``."""
+    return re.sub(r"[^A-Za-z0-9_.]", "_", label)
+
+
+def _named(fn: Callable, label: str) -> Callable:
+    """``fn`` under the name ``label`` for ``jax.jit``: the HLO module
+    becomes ``jit_<label>`` and every op's ``op_name`` starts with it."""
+    name = program_name(label)
+
+    @functools.wraps(fn)
+    def program(*args, **kwargs):
+        with jax.named_scope(name):
+            return fn(*args, **kwargs)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
+def _dispatch(span: str, runner, args, kwargs):
+    """Run one program launch inside its ``launch.<label>`` host range
+    and charge its wall time to the thread's current query. Dispatch is
+    asynchronous: this is the host's cost of a launch, not device
+    time."""
+    t0 = time.perf_counter_ns()
+    with _host_range(span):
+        out = runner(*args, **kwargs)
+    query = current_query()
+    if query is not None:
+        query.count_launch(time.perf_counter_ns() - t0)
+    return out
+
+
+class _NamedProgram:
+    """A private jit (unshared, no ledger entry) that still has a stable
+    program name and a ``launch.<label>`` range around each dispatch."""
+
+    __slots__ = ("fn", "_span")
+
+    def __init__(self, fn, label: str):
+        self.fn = fn
+        self._span = "launch." + program_name(label)
+
+    # attribute pass-through (e.g. .lower on the inner jit wrapper)
+    def __getattr__(self, name):
+        return getattr(self.fn, name)
+
+    def __call__(self, *args, **kwargs):
+        return _dispatch(self._span, self.fn, args, kwargs)
+
+
+def named_jit(fn: Callable, label: str, **jit_kwargs) -> Callable:
+    """``jax.jit(fn)`` for the sites that keep a private jit (a closure
+    over live state no structural key covers): named ``jit_<label>`` and
+    launched inside ``launch.<label>`` like a shared program."""
+    return _NamedProgram(jax.jit(_named(fn, label), **jit_kwargs), label)
+
+
 class _SharedProgram:
     """Callable wrapper around one shared jitted program that owns its
     compile-ledger entry.
@@ -167,9 +248,10 @@ class _SharedProgram:
     C++ cache. Unmatchable calls (kwargs, tracer args, signature-cache
     overflow, any AOT failure) fall back to the inner ``jax.jit``
     wrapper, so behavior never depends on the ledger. Every launch
-    increments the entry's launch counter; every Nth launch
-    (``roofline.sample_every()``) is synced and timed into the
-    achieved-GB/s join.
+    increments the entry's launch counter and runs inside the
+    program's ``launch.<label>`` host range; with sampling on, every
+    Nth launch (``roofline.sample_every()``) is synced and timed into
+    the achieved-GB/s join.
 
     Holds only the jit wrapper, avals, and compiled executables —
     never the exec tree (the shell-detachment contract above stands).
@@ -179,11 +261,12 @@ class _SharedProgram:
     #: (unbounded capacity buckets) calls run through the inner jit
     _SIG_CAP = 16
 
-    __slots__ = ("fn", "entry", "_sigs", "_n", "_lock")
+    __slots__ = ("fn", "entry", "_span", "_sigs", "_n", "_lock")
 
     def __init__(self, fn, entry):
         self.fn = fn
         self.entry = entry
+        self._span = "launch." + program_name(entry.label)
         self._sigs: Dict = {}
         self._n = 0
         self._lock = threading.Lock()
@@ -245,7 +328,7 @@ class _SharedProgram:
         stride = roofline.sample_every()
         if stride > 0 and self._n % stride == 1 % stride:
             t0 = time.perf_counter_ns()
-            out = runner(*args, **kwargs)
+            out = _dispatch(self._span, runner, args, kwargs)
             try:
                 jax.block_until_ready(out)
                 roofline.record_sample(
@@ -253,7 +336,7 @@ class _SharedProgram:
             except Exception:
                 pass
             return out
-        return runner(*args, **kwargs)
+        return _dispatch(self._span, runner, args, kwargs)
 
     def __call__(self, *args, **kwargs):
         if not kwargs:
@@ -353,10 +436,11 @@ def shared_method_jit(obj, method_name: str, fields: Sequence[str],
     the key when the method's builder varies on them.
     """
     cls = type(obj)
+    label = f"{cls.__qualname__}.{method_name}"
     enc = _encode([getattr(obj, f) for f in fields]) if _ENABLED else None
     if enc is None:
         _count(cls.__module__, "uncached")
-        return jax.jit(getattr(obj, method_name), **jit_kwargs)
+        return named_jit(getattr(obj, method_name), label, **jit_kwargs)
     key = (cls.__module__, cls.__qualname__, method_name, tuple(fields),
            enc, tuple(extra),
            tuple(sorted(jit_kwargs.items())) if jit_kwargs else ())
@@ -369,8 +453,8 @@ def shared_method_jit(obj, method_name: str, fields: Sequence[str],
         for f in fields:
             setattr(shell, f, getattr(obj, f))
         fn = _wrap_program(
-            jax.jit(getattr(shell, method_name), **jit_kwargs), key,
-            cls.__module__, f"{cls.__qualname__}.{method_name}")
+            jax.jit(_named(getattr(shell, method_name), label),
+                    **jit_kwargs), key, cls.__module__, label)
         _put(key, fn)
         _count(cls.__module__, "misses")
     return fn
@@ -384,12 +468,12 @@ def shared_fn_jit(builder: Callable, *key_args, **jit_kwargs) -> Callable:
     change). Closures defined inside methods must NOT be passed here —
     refactor them into module-level factories first.
     """
+    label = getattr(builder, "__qualname__", builder.__name__)
     enc = _encode(list(key_args)) if _ENABLED else None
     if enc is None:
         _count(builder.__module__, "uncached")
-        return jax.jit(builder(*key_args), **jit_kwargs)
-    key = (builder.__module__,
-           getattr(builder, "__qualname__", builder.__name__), enc,
+        return named_jit(builder(*key_args), label, **jit_kwargs)
+    key = (builder.__module__, label, enc,
            tuple(sorted(jit_kwargs.items())) if jit_kwargs else ())
     with _LOCK:
         fn = _REGISTRY.get(key)
@@ -397,9 +481,8 @@ def shared_fn_jit(builder: Callable, *key_args, **jit_kwargs) -> Callable:
             _count(builder.__module__, "hits")
             return fn
         fn = _wrap_program(
-            jax.jit(builder(*key_args), **jit_kwargs), key,
-            builder.__module__,
-            getattr(builder, "__qualname__", builder.__name__))
+            jax.jit(_named(builder(*key_args), label), **jit_kwargs),
+            key, builder.__module__, label)
         _put(key, fn)
         _count(builder.__module__, "misses")
     return fn
@@ -423,7 +506,7 @@ def shared_stage_jit(build: Callable[[], Callable], key_parts,
     enc = _encode(list(key_parts)) if _ENABLED else None
     if enc is None:
         _count(module, "uncached")
-        return jax.jit(build(), **jit_kwargs)
+        return named_jit(build(), label, **jit_kwargs)
     key = (module, "stage_program", enc,
            tuple(sorted(jit_kwargs.items())) if jit_kwargs else ())
     with _LOCK:
@@ -431,8 +514,8 @@ def shared_stage_jit(build: Callable[[], Callable], key_parts,
         if fn is not None:
             _count(module, "hits")
             return fn
-        fn = _wrap_program(jax.jit(build(), **jit_kwargs), key, module,
-                           label)
+        fn = _wrap_program(jax.jit(_named(build(), label), **jit_kwargs),
+                           key, module, label)
         _put(key, fn)
         _count(module, "misses")
     return fn

@@ -14,11 +14,36 @@ clock reads beyond what the metrics layer already pays.
 
 from __future__ import annotations
 
+import contextlib
 import json
 import os
 import threading
 import time
 from typing import Dict, List, Optional
+
+# Resolved once: the timers and the launch path enter a range on every
+# operator pull and every program dispatch, so a per-enter import would
+# be measurable there. Importing jax.profiler touches no backend.
+try:
+    from jax.profiler import TraceAnnotation as _TraceAnnotation
+except ImportError:  # pragma: no cover - jax always present in-tree
+    _TraceAnnotation = None
+
+
+_NULL_RANGE = contextlib.nullcontext()
+
+
+def annotate(name: str):
+    """A host range on the profiler's clock (``jax.profiler.
+    TraceAnnotation``): it lands in the same ``.xplane.pb`` as the
+    device's operations, on the calling thread's line, so a device idle
+    gap can be laid against what the host was doing. With no trace
+    running it costs one context manager and a ``TraceMe`` level check;
+    where jax has no profiler it is a null context. The names in use are
+    listed in docs/OBSERVABILITY.md ("Host ranges and query phases")."""
+    if _TraceAnnotation is None:
+        return _NULL_RANGE
+    return _TraceAnnotation(name)
 
 
 class Span:

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
+from ..jit_registry import named_jit
 from ..columnar.vector import (Column, ColumnVector, ColumnarBatch,
                                StringColumn, compaction_indices, live_mask,
                                round_pow2, rows_from_offsets)
@@ -898,8 +899,8 @@ def concat_batches(batches: Sequence[ColumnarBatch],
     """Concatenate batches (same schema) into one batch of out_capacity."""
     fn = _CONCAT_JIT.get(out_capacity)
     if fn is None:
-        fn = jax.jit(lambda bs, cap=out_capacity:
-                     _concat_batches_impl(bs, cap))
+        fn = named_jit(lambda bs, cap=out_capacity:
+                       _concat_batches_impl(bs, cap), "concat_batches")
         _CONCAT_JIT[out_capacity] = fn
     return fn(list(batches))
 
@@ -932,7 +933,8 @@ def repack_to(batch: ColumnarBatch, cap: int) -> ColumnarBatch:
     compaction."""
     fn = _COMPACT_JIT.get(cap)
     if fn is None:
-        fn = jax.jit(lambda b, c=cap: slice_batch(b, 0, b.num_rows, c))
+        fn = named_jit(lambda b, c=cap: slice_batch(b, 0, b.num_rows, c),
+                       "repack_to")
         _COMPACT_JIT[cap] = fn
     return fn(batch)
 

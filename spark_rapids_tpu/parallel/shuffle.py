@@ -45,6 +45,7 @@ from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ..columnar.vector import ColumnVector, ColumnarBatch, StringColumn
+from ..jit_registry import named_jit
 from .mesh import DATA_AXIS
 from .partition import (PartitionedBatch, flatten_partitions,
                         hash_partition_ids, partition_batch,
@@ -193,7 +194,7 @@ def distributed_aggregate(agg_exec, mesh: Mesh,
             out = agg_exec._merge_finalize(exchanged)
         return _expand_shard(out)
 
-    return jax.jit(
+    return named_jit(
         jax.shard_map(shard_step, mesh=mesh,
                       in_specs=P(DATA_AXIS), out_specs=P(DATA_AXIS),
-                      check_vma=False))
+                      check_vma=False), "distributed_aggregate")

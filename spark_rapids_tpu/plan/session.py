@@ -20,6 +20,7 @@ from ..exec.base import ExecContext, TpuExec
 from ..expr.aggregates import (Average, Count, CountStar, First, Last, Max,
                                Min, StddevSamp, Sum)
 from ..expr.core import Alias, ColumnRef, Expression, col, lit, output_name
+from ..obs.trace import annotate
 from . import logical as L
 from . import overrides
 from .host_table import HostTable, batch_to_table, concat_tables, empty_like, to_pydict
@@ -32,6 +33,7 @@ from .transitions import CpuPhysical, DeviceToHostBridge
 #: must stay small.
 import os as _os
 import sys as _sys
+import time as _time
 
 try:
     _MMAP_CHECK_EVERY = max(
@@ -170,54 +172,73 @@ class TpuSession:
     # --- execution ---
     def execute(self, plan: L.LogicalPlan,
                 timeout: Optional[float] = None,
-                query=None) -> HostTable:
+                query=None, parse_ns: int = 0) -> HostTable:
         """Run a logical plan to a host table.
 
         Physical plans are memoized on a structural key (plan_cache.py)
         so repeated collects of an identical query — even through fresh
         DataFrame objects — reuse the exec tree and its traced jits;
         without this every collect re-traced every jaxpr (the dominant
-        warm-query cost)."""
-        _mmap_guard(self)
-        if self.conf.ansi:
-            # srt.sql.ansi.enabled: clone the plan with every Cast /
-            # arithmetic / sum node ansi-marked so overflow and invalid
-            # casts raise (expr/ansi.py; the conf is part of the plan
-            # cache key, so ANSI and non-ANSI plans never alias)
-            from ..expr.ansi import rewrite_plan
-            plan = rewrite_plan(plan)
-        from .plan_cache import plan_cache_key
-        key = plan_cache_key(plan, self.conf)
-        physical, release = (None, None)
-        if key is not None:
-            # execution lease: a cached tree may run on one thread at
-            # a time (its shuffle ids / write flags are instance
-            # state); a busy entry makes this caller plan fresh
-            physical, release = self._plan_cache.lease(key)
-        if physical is None:
-            physical = overrides.apply_overrides(plan, self.conf)
-            # only fully-device plans cache: CPU/bridge nodes hold no
-            # reset protocol for their one-shot state
-            if key is not None and isinstance(physical, TpuExec):
-                release = self._plan_cache.put_leased(key, physical)
-        elif isinstance(physical, TpuExec):
-            physical.reset_for_rerun()
+        warm-query cost). ``parse_ns`` is what building ``plan`` from
+        SQL text cost (``DataFrame.parse_ns``): it is reported with the
+        query's other phases."""
+        return self._execute_recorded(plan, timeout, query, parse_ns)[0]
+
+    def _execute_recorded(self, plan: L.LogicalPlan,
+                          timeout: Optional[float] = None, query=None,
+                          parse_ns: int = 0) -> Tuple[HostTable, dict]:
+        """``execute`` returning the query's registry record beside the
+        table, so the caller that builds rows out of the table can add
+        its share to ``record["phases"]["fetch_ns"]``."""
+        t0 = _time.perf_counter_ns()
+        with annotate("plan.physical"):
+            _mmap_guard(self)
+            if self.conf.ansi:
+                # srt.sql.ansi.enabled: clone the plan with every Cast /
+                # arithmetic / sum node ansi-marked so overflow and
+                # invalid casts raise (expr/ansi.py; the conf is part of
+                # the plan cache key, so ANSI and non-ANSI plans never
+                # alias)
+                from ..expr.ansi import rewrite_plan
+                plan = rewrite_plan(plan)
+            from .plan_cache import plan_cache_key
+            key = plan_cache_key(plan, self.conf)
+            physical, release = (None, None)
+            if key is not None:
+                # execution lease: a cached tree may run on one thread
+                # at a time (its shuffle ids / write flags are instance
+                # state); a busy entry makes this caller plan fresh
+                physical, release = self._plan_cache.lease(key)
+            if physical is None:
+                physical = overrides.apply_overrides(plan, self.conf)
+                # only fully-device plans cache: CPU/bridge nodes hold
+                # no reset protocol for their one-shot state
+                if key is not None and isinstance(physical, TpuExec):
+                    release = self._plan_cache.put_leased(key, physical)
+            elif isinstance(physical, TpuExec):
+                physical.reset_for_rerun()
+        plan_ns = _time.perf_counter_ns() - t0
         try:
             return self._execute_physical(physical, plan,
-                                          timeout=timeout, query=query)
+                                          timeout=timeout, query=query,
+                                          parse_ns=parse_ns,
+                                          plan_ns=plan_ns)
         finally:
             if release is not None:
                 release()
 
     def _execute_physical(self, physical, plan: L.LogicalPlan,
                           timeout: Optional[float] = None,
-                          query=None) -> HostTable:
+                          query=None, parse_ns: int = 0,
+                          plan_ns: int = 0) -> Tuple[HostTable, dict]:
         """Run a planned physical tree with the query-level
         observability wrapper: QueryStart/QueryEnd events, optional
         per-query span tracer (written out as a Chrome trace), and a
-        per-query metrics summary recorded in the process registry.
-        When observability is off this adds one conf check and one
-        per-query summary — nothing per batch.
+        per-query metrics summary recorded in the process registry,
+        with the query's ``phases`` (docs/OBSERVABILITY.md). When
+        observability is off this adds one conf check and one
+        per-query summary — nothing per batch. Returns the result and
+        that record.
 
         Concurrency contract (robustness/admission.py): the query
         first passes admission (``srt.sql.concurrentQueryTasks``
@@ -227,8 +248,6 @@ class TpuSession:
         ``srt.sql.queryTimeout`` — cancellation/deadline surface as
         QueryCancelled / DeadlineExceeded after a clean teardown
         through every producer and fetch thread."""
-        import time as _time
-
         from ..conf import METRICS_LEVEL, QUERY_TIMEOUT_S
         from ..obs import events as _events
         from ..obs import resource as _resource
@@ -287,6 +306,9 @@ class TpuSession:
             raise
         tc = task_context()
         tc0 = (tc.spilled_bytes, tc.retry_count, tc.split_count)
+        # a caller's token may have run queries before this one
+        launch0 = (qctx.dispatch_ns, qctx.launches)
+        fetch_ns = 0
         is_tpu = isinstance(physical, TpuExec)
         # serving identity fields ride on QueryStart/QueryEnd (only
         # when set: single-session logs stay byte-identical)
@@ -322,7 +344,10 @@ class TpuSession:
                         reg.observe("batch_rows", n, "rows")
                         reg.observe("batch_bytes", batch_nbytes(b),
                                     "bytes")
-                        tables.append(batch_to_table(b))
+                        tf = _time.perf_counter_ns()
+                        with annotate("result.fetch"):
+                            tables.append(batch_to_table(b))
+                        fetch_ns += _time.perf_counter_ns() - tf
                     result = concat_tables(tables) if tables \
                         else empty_like(plan.schema)
                 else:
@@ -359,7 +384,12 @@ class TpuSession:
                                         self.conf.get(METRICS_LEVEL))
             extra = {"spilled_bytes": tc.spilled_bytes - tc0[0],
                      "oom_retries": tc.retry_count - tc0[1],
-                     "oom_splits": tc.split_count - tc0[2]}
+                     "oom_splits": tc.split_count - tc0[2],
+                     "phases": _query_phases(
+                         ctx.metrics, parse_ns=parse_ns, plan_ns=plan_ns,
+                         execute_ns=wall_ns, fetch_ns=fetch_ns,
+                         dispatch_ns=qctx.dispatch_ns - launch0[0],
+                         launches=qctx.launches - launch0[1])}
             if rwin is not None:
                 rsum = rwin.finish(qid)  # emits RooflineSummary
                 if rsum is not None:
@@ -368,7 +398,8 @@ class TpuSession:
                                            status, **extra)
             self._last_execution = {"physical": physical, "ctx": ctx,
                                     "query_id": qid, "wall_ns": wall_ns,
-                                    "record": rec}
+                                    "record": rec,
+                                    "phases": rec["phases"]}
             if _events.enabled():
                 end: Dict = {"query_id": qid, "status": status,
                              "wall_ns": wall_ns, "metrics": summary}
@@ -384,7 +415,31 @@ class TpuSession:
                             _events.log_dir(), f"trace-{qid}.json"))
                     except OSError:
                         pass
-        return result
+        return result, rec
+
+
+#: operator Metric (summed over the plan, whatever srt.metrics.level
+#: shows) -> key of the query record's ``phases``
+_PHASE_METRICS = {"scanDecodeTime": "scan_decode_ns",
+                  "scanWaitTime": "scan_wait_ns",
+                  "scanTime": "scan_upload_ns",
+                  "prefetchWaitTime": "prefetch_wait_ns"}
+
+
+def _query_phases(ctx_metrics: Dict, **timed) -> Dict[str, int]:
+    """The ``phases`` of one query's record: where its wall went, each
+    number measured where the work happens (docs/OBSERVABILITY.md,
+    "Host ranges and query phases"). ``timed`` are the phases the
+    session clocks itself; the scan's and the pipeline's come from the
+    operators' metrics."""
+    phases = dict(timed)
+    phases.update((key, 0) for key in _PHASE_METRICS.values())
+    for metrics in ctx_metrics.values():
+        for name, key in _PHASE_METRICS.items():
+            metric = metrics.get(name)
+            if metric is not None:
+                phases[key] += int(metric.value)
+    return phases
 
 
 def _infer_value_type(sample, values=()):
@@ -526,6 +581,26 @@ class DataFrame:
     def __init__(self, session: TpuSession, plan: L.LogicalPlan):
         self.session = session
         self.plan = plan
+        #: ns ``session.sql()`` spent parsing and analyzing the text
+        #: this frame came from; its first execution reports and
+        #: clears it
+        self.parse_ns = 0
+
+    def _run(self, timeout: Optional[float] = None
+             ) -> Tuple[HostTable, dict]:
+        parse_ns, self.parse_ns = self.parse_ns, 0
+        return self.session._execute_recorded(self.plan, timeout,
+                                              parse_ns=parse_ns)
+
+    def _fetch(self, build, timeout: Optional[float] = None):
+        """Run the query and hand its table to ``build``; the time
+        ``build`` takes is the caller's share of ``result.fetch``."""
+        table, rec = self._run(timeout)
+        t0 = _time.perf_counter_ns()
+        with annotate("result.fetch"):
+            out = build(table)
+        rec["phases"]["fetch_ns"] += _time.perf_counter_ns() - t0
+        return out
 
     # --- transformations ---
     def select(self, *cols) -> "DataFrame":
@@ -641,21 +716,22 @@ class DataFrame:
         """Run the query and return rows. ``timeout`` (seconds) arms a
         per-call deadline — the query tears down cleanly and raises
         DeadlineExceeded on expiry; overrides ``srt.sql.queryTimeout``."""
-        table = self.session.execute(self.plan, timeout=timeout)
-        data = to_pydict(table)
-        names = list(data.keys())
-        n = table.num_rows
-        return [{k: data[k][i] for k in names} for i in range(n)]
+        def rows(table: HostTable) -> List[dict]:
+            data = to_pydict(table)
+            names = list(data.keys())
+            return [{k: data[k][i] for k in names}
+                    for i in range(table.num_rows)]
+        return self._fetch(rows, timeout)
 
     def to_pydict(self) -> dict:
-        return to_pydict(self.session.execute(self.plan))
+        return self._fetch(to_pydict)
 
     def to_pandas(self):
         import pandas as pd
         return pd.DataFrame(self.to_pydict())
 
     def count(self) -> int:
-        return self.session.execute(self.plan).num_rows
+        return self._run()[0].num_rows
 
     @property
     def write(self):
@@ -722,7 +798,7 @@ class DataFrame:
         shuffle bytes; the reference SQL-UI annotation role) plus a
         query-level footer with wall time and spill totals."""
         from ..conf import METRICS_LEVEL
-        self.session.execute(self.plan)
+        self._run()
         last = self.session._last_execution
         physical, ctx = last["physical"], last["ctx"]
         level = self.session.conf.get(METRICS_LEVEL)

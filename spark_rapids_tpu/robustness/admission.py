@@ -80,7 +80,8 @@ class QueryContext:
     waits on ``_cancelled`` directly (``sleep``)."""
 
     __slots__ = ("query_id", "deadline", "cancel_reason", "_cancelled",
-                 "admission_wait_ns")
+                 "admission_wait_ns", "dispatch_ns", "launches",
+                 "_launch_lock")
 
     def __init__(self, query_id: str = "",
                  deadline: Optional[float] = None):
@@ -94,6 +95,17 @@ class QueryContext:
         #: on the fast path, >0 = waited in the FIFO. The serving tier
         #: reads this to bucket latency per admission tier.
         self.admission_wait_ns: Optional[int] = None
+        #: host ns spent dispatching device programs on this query's
+        #: behalf, and how many dispatches, summed over its threads
+        #: (jit_registry ``launch.*`` ranges; ``phases`` of the record)
+        self.dispatch_ns = 0
+        self.launches = 0
+        self._launch_lock = threading.Lock()
+
+    def count_launch(self, ns: int) -> None:
+        with self._launch_lock:
+            self.dispatch_ns += ns
+            self.launches += 1
 
     @property
     def admission_tier(self) -> str:

@@ -260,7 +260,8 @@ class SqlServer:
                         "wall_ns": time.perf_counter_ns() - t0,
                     }, lock=send_lock)
                     return
-            table = sess.execute(plan, query=qctx)
+            table = sess.execute(plan, query=qctx,
+                                 parse_ns=df.parse_ns)
             payloads = self._serialize_result(table)
             for payload in payloads:
                 P.send_frame(sock, P.OP_BATCH, sid, rid, payload,

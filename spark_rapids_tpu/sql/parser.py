@@ -16,6 +16,7 @@ analyzed physical plan):
 from __future__ import annotations
 
 import datetime
+import time
 from typing import List, Optional, Sequence, Tuple
 
 from ..columnar import dtypes as dt
@@ -30,6 +31,7 @@ from ..expr import strings as S
 from ..expr.cast import Cast
 from ..expr.core import Alias, ColumnRef, Expression, Literal, col, lit, \
     output_name
+from ..obs.trace import annotate
 from .lexer import Token, tokenize
 
 
@@ -2323,6 +2325,12 @@ class Analyzer:
 
 
 def parse_sql(session, text: str):
-    """Parse + analyze SQL text into a DataFrame on ``session``."""
-    stmt = Parser(text).parse_statement()
-    return Analyzer(session).analyze(stmt)
+    """Parse + analyze SQL text into a DataFrame on ``session``. The
+    frame carries what that cost (``parse_ns``) to its first
+    execution, whose record reports it among the query's phases."""
+    t0 = time.perf_counter_ns()
+    with annotate("plan.parse"):
+        stmt = Parser(text).parse_statement()
+        df = Analyzer(session).analyze(stmt)
+    df.parse_ns = time.perf_counter_ns() - t0
+    return df

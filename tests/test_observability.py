@@ -11,7 +11,12 @@
   with no double-charged parent time;
 - NDS profile smoke: one NDS query end-to-end with the event log on,
   profiled offline — summed exclusive ESSENTIAL op-times must fit
-  inside the measured wall clock.
+  inside the measured wall clock;
+- program names, host ranges and query phases: every jitted program's
+  HLO module is ``jit_<registry label>``; a parquet query's record
+  carries its ``phases``; the ranges land in a ``jax.profiler`` trace on
+  the threads that feed the device, decode does not; the roofline
+  sampler is off unless asked for.
 """
 
 import json
@@ -464,3 +469,140 @@ def test_nds_q3_profile_smoke(tmp_path):
     assert "Exec" in names
     text = profile_report.render(rep)
     assert "critical path" in text
+
+
+# ---------------------------------------------------------------------------
+# program names, host ranges on the profiler's clock, query phases
+# ---------------------------------------------------------------------------
+
+def test_program_names_are_registry_labels():
+    import jax.numpy as jnp
+
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.vector import (ColumnVector,
+                                                  ColumnarBatch, live_mask)
+    from spark_rapids_tpu.exec import (BatchScanExec, FilterExec,
+                                       LocalLimitExec)
+    from spark_rapids_tpu.expr.core import col, lit
+    from spark_rapids_tpu.ops import kernels as K
+    n = 64
+    b = ColumnarBatch([ColumnVector(jnp.arange(n, dtype=jnp.int64),
+                                    live_mask(n, n), dt.INT64)], ["x"], n)
+    scan = BatchScanExec([b], [("x", dt.INT64)])
+
+    def module(program, *args):
+        return program.lower(*args).as_text().split()[1]
+
+    f1 = FilterExec(scan, col("x") < lit(31_337))
+    f2 = FilterExec(scan, col("x") < lit(31_337))
+    assert f1._jit is f2._jit  # shared by key, whatever the name
+    assert f1._jit is not FilterExec(scan, col("x") < lit(31_338))._jit
+    # shared_method_jit, shared_fn_jit, and the two private jits of
+    # ops/kernels.py: no _filter / run / <lambda> left in a name
+    assert module(f1._jit, b) == "@jit_FilterExec._filter"
+    assert module(LocalLimitExec(scan, 5)._jit, b, jnp.int64(3)) \
+        == "@jit__local_limit_builder"
+    K.concat_batches([b, b], 128)
+    K.repack_to(b, 64)
+    assert module(K._CONCAT_JIT[128], [b, b]) == "@jit_concat_batches"
+    assert module(K._COMPACT_JIT[64], b) == "@jit_repack_to"
+
+
+PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
+              "scan_decode_ns", "scan_wait_ns", "scan_upload_ns",
+              "prefetch_wait_ns", "dispatch_ns", "launches"}
+
+
+@pytest.fixture(scope="module")
+def traced_parquet_query(tmp_path_factory):
+    """One tiny SQL query over three parquet files, run once to compile
+    and once under a CPU ``jax.profiler`` trace: (its registry record,
+    its metrics, {host range name: thread lines it appeared on})."""
+    import glob
+
+    import jax
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    from jax.profiler import ProfileData
+
+    from spark_rapids_tpu.obs import roofline
+    from spark_rapids_tpu.obs.registry import registry
+    data = tmp_path_factory.mktemp("phases_data")
+    for i in range(3):
+        pq.write_table(
+            pa.table({"a": np.arange(1000, dtype=np.int64) + 1000 * i,
+                      "b": np.linspace(0.0, 1.0, 1000)}),
+            str(data / f"part-{i}.parquet"))
+    session = TpuSession(SrtConf({}))
+    session.create_or_replace_temp_view(
+        "t", session.read.parquet(str(data)))
+    sql = "SELECT sum(a) AS r FROM t WHERE b < 0.5"
+    assert session.sql(sql).collect() == [{"r": 1874250}]
+    trace_dir = tmp_path_factory.mktemp("phases_trace")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=options)
+    try:
+        session.sql(sql).collect()
+        window = roofline.window()  # as a default session leaves it
+    finally:
+        jax.profiler.stop_trace()
+    [path] = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
+                           / "*.xplane.pb"))
+    ranges = {}
+    for plane in ProfileData.from_file(path).planes:
+        for i, line in enumerate(plane.lines):
+            for event in line.events:
+                ranges.setdefault(event.name, set()).add((plane.name, i))
+    last = session._last_execution
+    assert last["record"] is registry().queries()[-1]
+    return last["record"], last["ctx"].metrics, ranges, window
+
+
+def test_query_record_carries_phases(traced_parquet_query):
+    record, metrics, _, _ = traced_parquet_query
+    phases = record["phases"]
+    assert set(phases) == PHASE_KEYS
+    assert all(isinstance(v, int) and v >= 0 for v in phases.values())
+    assert phases["launches"] > 0 and phases["dispatch_ns"] > 0
+    assert phases["parse_ns"] > 0 and phases["plan_ns"] > 0
+    assert phases["fetch_ns"] > 0
+    assert phases["execute_ns"] == record["wall_ns"]
+    assert phases["execute_ns"] >= phases["scan_upload_ns"] > 0
+    totals = {}
+    for per_exec in metrics.values():
+        for name, metric in per_exec.items():
+            totals[name] = totals.get(name, 0) + metric.value
+    assert totals["scanDecodeTime"] == phases["scan_decode_ns"] > 0
+    assert totals["scanWaitTime"] == phases["scan_wait_ns"] > 0
+    assert totals["scanTime"] == phases["scan_upload_ns"]
+    assert totals["prefetchWaitTime"] == phases["prefetch_wait_ns"]
+
+
+def test_host_ranges_are_in_the_profilers_trace(traced_parquet_query):
+    _, _, ranges, _ = traced_parquet_query
+    for name in ("plan.parse", "plan.physical", "result.fetch",
+                 "scan.wait", "scan.upload", "prefetch.wait"):
+        assert name in ranges, f"no {name} range in the trace"
+    launches = [n for n in ranges if n.startswith("launch.")]
+    assert launches and all(" " not in n for n in launches)
+    # the scan's ranges are on the prefetch producer's thread, the
+    # consumer's wait and the planner's on the caller's
+    assert ranges["scan.wait"] == ranges["scan.upload"]
+    assert ranges["plan.physical"] == ranges["prefetch.wait"]
+    assert ranges["scan.wait"].isdisjoint(ranges["plan.physical"])
+    # decode is a counter only: a range on a reader-pool thread would
+    # be taken for the cause of idle gaps it merely overlaps
+    assert not [n for n in ranges if "decode" in n.lower()]
+
+
+def test_roofline_sampler_is_off_by_default(traced_parquet_query):
+    from spark_rapids_tpu.conf import ROOFLINE_SAMPLE_EVERY
+    from spark_rapids_tpu.obs import roofline
+    record, _, _, window = traced_parquet_query
+    assert SrtConf({}).get(ROOFLINE_SAMPLE_EVERY) == 0
+    # after a default session's query: no sampling stride, so no launch
+    # was synced on the sampler's behalf and no window was opened
+    assert window is None and "roofline" not in record
+    assert roofline.sample_every() == 0 and not roofline.active()
