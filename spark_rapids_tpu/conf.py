@@ -925,8 +925,13 @@ PYTHON_UDF_TIMEOUT = conf("srt.python.udf.timeoutSec") \
     .check(lambda v: v >= 0).integer(600)
 
 PALLAS_ENABLED = conf("srt.sql.pallas.enabled") \
-    .doc("Execute eligible global filter+aggregate pipelines as fused "
-         "pallas TPU kernels (one HBM pass, no filtered intermediate). "
+    .doc("Execute eligible global (no grouping keys) aggregates through "
+         "the pallas tile_reduce kernel, one device program per batch. A "
+         "Filter under the aggregate runs inside that program, so no "
+         "filtered intermediate is built: in the kernel when the kernel "
+         "can evaluate the predicate exactly, otherwise (a FLOAT64 "
+         "comparison on TPU, Divide, numeric IN) as a mask XLA computes "
+         "in front of the kernel at the columns' own types. "
          "On TPU the fused kernel computes float sums in float32 with "
          "float64 cross-tile combination — the same corner-case "
          "deviation class as spark.rapids.sql.variableFloatAgg.enabled; "

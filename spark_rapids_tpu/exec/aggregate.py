@@ -549,27 +549,45 @@ class HashAggregateExec(TpuExec):
             ctx, self._partial_stream(ctx, agg_time), agg_time)
 
     # --- fused pallas path (global aggregates over simple numerics) ---
+    def _pallas_filter(self):
+        """(source, kind, predicate): what the global pallas lane
+        streams from, and where a FilterExec child's predicate goes —
+        ``kernel`` when the kernel may evaluate it (``pred_safe``),
+        ``mask`` when XLA evaluates it in front of the kernel in the
+        same program, ``none`` when there is no filter to absorb (no
+        FilterExec child, or one whose predicate must stay outside jit
+        or needs the partition context: that FilterExec runs as ever).
+        Decided from the predicate's nodes and dtypes and the platform."""
+        from ..expr.misc import fusion_blocked
+        from . import pallas_agg
+        from .basic import CoalesceBatchesExec, FilterExec
+        node = self.children[0]
+        while isinstance(node, CoalesceBatchesExec):
+            node = node.children[0]
+        if isinstance(node, FilterExec):
+            if pallas_agg.pred_safe(node.condition, self.input_schema):
+                return node.children[0], "kernel", node.condition
+            if not fusion_blocked([node.condition]):
+                return node.children[0], "mask", node.condition
+        return self.children[0], "none", None
+
     def _pallas_stream_or_none(self, ctx: ExecContext, agg_time: Metric):
         """Fused filter+aggregate via ops/pallas_kernels.tile_reduce —
-        one HBM pass per batch, no filtered intermediate. None keeps the
+        one program per batch, no filtered intermediate. None keeps the
         stock XLA path (static gate miss or conf off); past that the
         kernel runs or its compile error propagates."""
         from ..conf import PALLAS_ENABLED
         from . import pallas_agg
         if not self._pallas_gate or not ctx.conf.get(PALLAS_ENABLED):
             return None
-        from .basic import CoalesceBatchesExec, FilterExec
-        source, pred = self.children[0], None
-        node = source
-        while isinstance(node, CoalesceBatchesExec):
-            node = node.children[0]
-        if isinstance(node, FilterExec) and \
-                pallas_agg.pred_safe(node.condition, self.input_schema):
-            source, pred = node.children[0], node.condition
-        key = id(pred)
+        source, kind, cond = self._pallas_filter()
+        key = id(cond)
         entry = self._pallas_cache.get(key)
         if entry is None:
-            plan = pallas_agg.build_plan(self, pred)
+            plan = pallas_agg.PallasAggPlan(
+                self.agg_exprs, self.input_schema,
+                pred=cond if kind == "kernel" else None,
+                mask_pred=cond if kind == "mask" else None)
             # a closure over this exec's plan: private, but named and
             # launched like a shared program
             entry = self._pallas_cache[key] = (plan, named_jit(
@@ -580,6 +598,9 @@ class HashAggregateExec(TpuExec):
             m = ctx.metrics_for(self.exec_id)
             pb = m.setdefault("pallasBatches",
                               Metric("pallasBatches", Metric.DEBUG))
+            mb = m.setdefault("pallasMaskFilterBatches",
+                              Metric("pallasMaskFilterBatches",
+                                     Metric.DEBUG))
             totals = plan.init_totals()
             saw = False
             for batch in source.execute(ctx):
@@ -590,6 +611,8 @@ class HashAggregateExec(TpuExec):
                     partials = fn(batch)
                 plan.combine(totals, partials)
                 pb.add(1)
+                if kind == "mask":
+                    mb.add(1)
             if not saw:
                 if self.mode == COMPLETE:
                     yield self._empty_global_result()
@@ -643,7 +666,7 @@ class HashAggregateExec(TpuExec):
         from . import pallas_agg
         lane = ""
         if self._pallas_gate:
-            lane = " (pallas-global)"
+            lane = f" (pallas-global, filter={self._pallas_filter()[1]})"
         elif self._pallas_grouped_gate and pallas_agg.grouped_lane_on():
             lane = " (pallas-grouped)"
         return (f"HashAggregate[{self.mode}, keys=({keys}), "

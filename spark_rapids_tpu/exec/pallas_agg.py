@@ -1,13 +1,24 @@
 """Fused filter+aggregate lowering onto the pallas tile_reduce kernel.
 
-A global (no grouping keys) HashAggregateExec whose aggregates — and,
-when its child is a FilterExec, the filter predicate too — are simple
-numeric expressions executes here as ONE pallas pass per input batch:
-predicate, projections, and partial reduction all evaluate in VMEM, so
-each input column crosses HBM exactly once and no filtered intermediate
-batch is ever materialized. This is the TPU counterpart of the
-reference's fused cuDF reduction path for q6-shaped queries
-(GpuAggregateExec.scala AggHelper update pass over a filtered iterator).
+A global (no grouping keys) HashAggregateExec whose aggregates are
+simple numeric expressions executes here as ONE device program per
+input batch, and a FilterExec child is absorbed into that program, so
+no filtered intermediate batch is ever materialized. The predicate
+goes, whole, to one side of the kernel boundary:
+
+- kernel-safe (``pred_safe``): predicate, projections and partial
+  reduction all evaluate in VMEM, each input column crosses HBM once;
+- anything else jit can trace (a FLOAT64 comparison on the chip,
+  Divide, numeric IN, Least/Greatest anywhere): XLA evaluates it in
+  front of the kernel with the engine's ordinary expression code, at
+  the columns' own types, and the result rides into the kernel as its
+  live mask (``mask_pred``). The same rows pass as through FilterExec,
+  without its compaction gather; the kernel reads only the columns the
+  aggregates reference.
+
+This is the TPU counterpart of the reference's fused cuDF reduction
+path for q6-shaped queries (GpuAggregateExec.scala AggHelper update
+pass over a filtered iterator).
 
 Numerics: on TPU the kernel computes in float32 (float64 inputs and
 float64 literals are demoted before tracing — Mosaic has no f64), with
@@ -261,10 +272,15 @@ class _KernelBatch(ColumnarBatch):
 
 
 class PallasAggPlan:
-    """Static lowering of (pred, agg_exprs) onto tile_reduce outputs."""
+    """Static lowering of (pred, agg_exprs) onto tile_reduce outputs.
+    ``pred`` is traced inside the kernel; ``mask_pred`` by XLA in front
+    of it, where its result replaces the batch's live mask."""
 
-    def __init__(self, agg_exprs, input_schema, pred: Optional[E.Expression]):
+    def __init__(self, agg_exprs, input_schema, pred: Optional[E.Expression],
+                 mask_pred: Optional[E.Expression] = None):
+        assert pred is None or mask_pred is None
         self.input_schema = input_schema
+        self.mask_pred = mask_pred
         schema = list(input_schema)
         self.str_names: List[str] = []
         if pred is not None:
@@ -367,6 +383,7 @@ class PallasAggPlan:
         names = self.ref_names
         demote = PK.on_tpu()
         pred = self._prep(self.pred) if self.pred is not None else None
+        mask_pred = self.mask_pred
         builders = self._builders
         kinds = self.kinds
 
@@ -392,7 +409,13 @@ class PallasAggPlan:
                 arrays.append(sc.padded())              # (cap, W) u8
                 arrays.append(sc.lengths().astype(jnp.int32))
                 arrays.append(sc.validity.astype(jnp.uint8))
-            arrays.append(batch.live_mask().astype(jnp.uint8))
+            live = batch.live_mask()
+            if mask_pred is not None:
+                # FilterExec's own tree at the columns' own types
+                # (K.filter_batch's keep), so the same rows pass
+                c = mask_pred.eval(batch)
+                live = live & c.data & c.validity
+            arrays.append(live.astype(jnp.uint8))
 
             def row_fn(blocks):
                 cols = []
@@ -519,8 +542,8 @@ def grouped_lane_on() -> bool:
 
 def pallas_eligible(agg_exec) -> bool:
     """The static gate; False keeps the stock XLA path. (The actual
-    PallasAggPlan is built lazily at execute time via build_plan, once
-    the fused-or-not predicate is resolved.)"""
+    PallasAggPlan is built lazily at execute time, once
+    ``HashAggregateExec._pallas_filter`` has placed the predicate.)"""
     if agg_exec.group_exprs:
         return False
     schema = list(agg_exec.input_schema)
@@ -543,16 +566,13 @@ def pallas_eligible(agg_exec) -> bool:
     return True
 
 
-def build_plan(agg_exec, pred: Optional[E.Expression]) -> PallasAggPlan:
-    return PallasAggPlan(agg_exec.agg_exprs, agg_exec.input_schema, pred)
-
-
 def pred_safe(pred: E.Expression, input_schema) -> bool:
-    """Filter predicates must keep exact row selection: on TPU (where
-    the kernel would demote f64 to f32) any float64 subexpression keeps
-    the filter un-fused — the aggregate still runs in pallas over the
-    FilterExec's output. String predicate subtrees are judged AFTER
-    their byte-lane rewrite (the string-predicate kernel family)."""
+    """May the kernel itself evaluate this filter predicate? Row
+    selection must stay exact: on TPU (where the kernel would demote
+    f64 to f32) any float64 subexpression keeps the predicate out of
+    the kernel — it becomes the XLA-evaluated mask in front of it
+    (``PallasAggPlan.mask_pred``). String predicate subtrees are judged
+    AFTER their byte-lane rewrite (the string-predicate kernel family)."""
     rewritten, _ = _rewrite_string_preds(pred, list(input_schema))
     return _expr_safe(rewritten, list(input_schema),
                       no_f64=PK.on_tpu())

@@ -242,6 +242,25 @@ def contains_input_file(exprs) -> bool:
     return any(walk(e) for e in exprs)
 
 
+def fusion_blocked(exprs) -> bool:
+    """Expressions a program fused from several operators cannot
+    reproduce: eager trees (must evaluate un-jitted so data-dependent
+    raises reach the caller) and partition-context expressions (read
+    ``ctx.partition_id`` / the input-file TLS through
+    ``traced_context``, which a fused program does not thread)."""
+    if contains_eager(exprs):
+        return True
+    ctx_types = (InputFileName, _InputFileBlock, SparkPartitionID,
+                 MonotonicallyIncreasingID)
+
+    def walk(e) -> bool:
+        if isinstance(e, ctx_types):
+            return True
+        return any(walk(c) for c in e.children)
+
+    return any(walk(e) for e in exprs)
+
+
 # --- user-facing constructors ---------------------------------------------
 
 def monotonically_increasing_id() -> MonotonicallyIncreasingID:

@@ -1104,28 +1104,6 @@ def apply_overrides(plan: LogicalPlan, conf: Optional[SrtConf] = None):
     return root
 
 
-def _fusion_blocked_exprs(exprs) -> bool:
-    """Expressions a fused program cannot reproduce: eager trees (must
-    evaluate un-jitted so data-dependent raises reach the caller) and
-    partition-context expressions (read ``ctx.partition_id`` / the
-    input-file TLS through ``traced_context``, which the fused program
-    does not thread)."""
-    from ..expr.misc import (InputFileName, MonotonicallyIncreasingID,
-                             SparkPartitionID, _InputFileBlock,
-                             contains_eager)
-    if contains_eager(exprs):
-        return True
-    ctx_types = (InputFileName, _InputFileBlock, SparkPartitionID,
-                 MonotonicallyIncreasingID)
-
-    def walk(e) -> bool:
-        if isinstance(e, ctx_types):
-            return True
-        return any(walk(c) for c in e.children)
-
-    return any(walk(e) for e in exprs)
-
-
 def _insert_fusion(root, conf: SrtConf):
     """Operator-fusion pass (exec/fused.py): collapse linear
     scan -> filter -> project -> partial-aggregate chains (and their
@@ -1147,9 +1125,10 @@ def _insert_fusion(root, conf: SrtConf):
     Opt-outs: ``srt.exec.fusion.enabled`` kills the pass;
     ``srt.exec.fusion.excludeExecs`` breaks chains at the named
     classes; stages with eager or partition-context expressions never
-    fuse (``_fusion_blocked_exprs``); a terminal aggregate eligible for
-    the global-agg pallas lane stays unfused so
-    ``_pallas_stream_or_none`` keeps its direct Filter-child peek.
+    fuse (``expr/misc.py::fusion_blocked``); a terminal aggregate eligible for
+    the global-agg pallas lane stays unfused: that lane absorbs its
+    direct Filter child itself, predicate into the kernel or as a mask
+    in front of it (``HashAggregateExec._pallas_filter``).
     When the grouped pallas lane is fully enabled the fused program
     uses ``_update_pallas`` as its terminal stage instead of the stock
     update — pallas_agg as a fusable terminal.
@@ -1179,6 +1158,7 @@ def _insert_fusion(root, conf: SrtConf):
     from ..exec.aggregate import FINAL, PARTIAL
     from ..exec.fused import FusedHashJoinExec, FusedPipelineExec
     from ..exec.join import _HashJoinBase
+    from ..expr.misc import fusion_blocked
     from ..io.scan import FileSourceScanExec
     excludes = {s.strip() for s in
                 conf.get(FUSION_EXCLUDE_EXECS).split(",") if s.strip()}
@@ -1193,19 +1173,19 @@ def _insert_fusion(root, conf: SrtConf):
         if type(n).__name__ in excludes:
             return False
         if isinstance(n, FilterExec):
-            return not _fusion_blocked_exprs([n.condition])
+            return not fusion_blocked([n.condition])
         if isinstance(n, ProjectExec):
-            return not _fusion_blocked_exprs(n.exprs)
+            return not fusion_blocked(n.exprs)
         return False
 
     def agg_ok(a) -> bool:
         if type(a).__name__ in excludes or a.mode != PARTIAL or a._eager:
             return False
-        if _fusion_blocked_exprs(list(a.group_exprs) +
-                                 [fn for fn, _ in a.agg_exprs]):
+        if fusion_blocked(list(a.group_exprs) +
+                          [fn for fn, _ in a.agg_exprs]):
             return False
-        # the global-aggregate pallas lane peeks at the agg's direct
-        # Filter child (_pallas_stream_or_none); fusing would steal it
+        # the global-aggregate pallas lane absorbs the agg's direct
+        # Filter child (_pallas_filter); fusing would steal it
         if a._pallas_gate and pallas_on:
             return False
         return True
@@ -1270,8 +1250,8 @@ def _insert_fusion(root, conf: SrtConf):
         if not final_conf or type(a).__name__ in excludes or a._eager \
                 or a._merge_fusion is not None:
             return
-        if _fusion_blocked_exprs(list(a.group_exprs) +
-                                 [fn for fn, _ in a.agg_exprs]):
+        if fusion_blocked(list(a.group_exprs) +
+                          [fn for fn, _ in a.agg_exprs]):
             return
         from ..exec.exchange import ShuffleExchangeExec
         projs = []

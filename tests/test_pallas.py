@@ -14,6 +14,7 @@ from spark_rapids_tpu.expr import col
 from spark_rapids_tpu.expr.aggregates import (Average, Count, CountStar,
                                               Max, Min, Sum)
 from spark_rapids_tpu.expr.core import Alias
+from spark_rapids_tpu.expr.predicates import InSet
 from spark_rapids_tpu.ops.pallas_kernels import MAX, MIN, SUM, tile_reduce
 from spark_rapids_tpu.plan import overrides
 from spark_rapids_tpu.plan.session import TpuSession
@@ -57,17 +58,18 @@ def _metric(ctx: ExecContext, name: str) -> int:
     return total
 
 
-def _run(plan, conf):
-    physical = overrides.apply_overrides(plan, conf)
-    ctx = ExecContext(conf)
+def _collect(node, conf):
     from spark_rapids_tpu.columnar.vector import batch_to_pydict
+    ctx = ExecContext(conf)
     rows = []
-    for b in physical.execute(ctx):
+    for b in node.execute(ctx):
         d = batch_to_pydict(b)
-        keys = list(d)
-        for i in range(len(d[keys[0]]) if keys else 0):
-            rows.append({k: d[k][i] for k in keys})
+        rows.extend(dict(zip(d, vals)) for vals in zip(*d.values()))
     return rows, ctx
+
+
+def _run(plan, conf):
+    return _collect(overrides.apply_overrides(plan, conf), conf)
 
 
 @pytest.fixture
@@ -156,7 +158,7 @@ def test_string_predicate_fuses(tmp_path):
                                    Alias(CountStar(), "n"))
 
     from spark_rapids_tpu.expr import lit
-    from spark_rapids_tpu.expr.predicates import InSet, IsNotNull
+    from spark_rapids_tpu.expr.predicates import IsNotNull
     from spark_rapids_tpu.expr.strings import StartsWith
     preds = [
         col("c") == lit("alpha"),
@@ -254,3 +256,134 @@ def test_fused_minmax_nan_ordering():
         rows, _ = _run(make_nan(conf).plan, conf)
         (r,) = rows
         assert math.isnan(r["mn"]) and math.isnan(r["mx"]), r
+
+
+# --- the mask lane: a predicate the kernel refuses rides into it as
+# its live mask, evaluated by XLA in the aggregate's own program ---
+
+def _mask_data(n=700):
+    rng = np.random.default_rng(11)
+    data = {"v": rng.uniform(-50, 100, n).tolist(),
+            "w": rng.uniform(0, 1, n).tolist(),
+            "d": rng.integers(0, 9, n).tolist()}
+    for i in range(0, n, 7):
+        data["v"][i] = None
+    for i in range(3, n, 13):
+        data["w"][i] = None  # a null predicate value drops the row
+    for i in range(5, n, 17):
+        data["d"][i] = None
+    return data
+
+
+def _ansi_divide():
+    e = col("w") / 2.0
+    e.ansi = True  # what expr/ansi.enable_ansi sets: guards raise eagerly
+    return e > 0.2
+
+
+def _partition_context():
+    from spark_rapids_tpu.expr.misc import SparkPartitionID
+    return (SparkPartitionID() >= 0) & (col("w") / 2.0 > 0.2)
+
+
+_PREDS = {
+    # Divide, numeric IN: refused by the kernel on every platform
+    "divide": lambda: (col("w") / 2.0 > 0.2) & (col("d") < 7),
+    "numeric_in": lambda: InSet(col("d"), [1, 3, 5, 8]),
+    "none_pass": lambda: col("w") / 2.0 > 5.0,
+    "kernel_safe": lambda: (col("w") > 0.4) & (col("v") < 80.0),
+    "ansi": _ansi_divide,
+    "partition_context": _partition_context,
+}
+
+_MASK_AGGS = [(Sum(col("v") * col("w")), "rev"), (Average(col("v")), "av"),
+              (Min(col("v")), "mn"), (Max(col("v")), "mx"),
+              (Count(col("v")), "cv"), (CountStar(), "cnt")]
+
+
+def _agg_tree(data, pred, mode, nbatches=3):
+    """scan -> Filter -> global aggregate, built by hand so that the
+    mode is the test's: COMPLETE, or PARTIAL under FINAL. Returns the
+    root, the aggregate that sits on the filter, and the filter."""
+    from spark_rapids_tpu.columnar.vector import batch_from_pydict
+    from spark_rapids_tpu.exec import (BatchScanExec, FilterExec,
+                                       HashAggregateExec)
+    from spark_rapids_tpu.exec.aggregate import COMPLETE, FINAL, PARTIAL
+    n = len(data["v"])
+    per = -(-n // nbatches) if n else 1
+    batches = [batch_from_pydict({k: v[i:i + per] for k, v in data.items()})
+               for i in range(0, n, per)]
+    schema = batch_from_pydict(_mask_data(8)).schema()
+    filt = FilterExec(BatchScanExec(batches, schema), pred)
+    if mode == "complete":
+        agg = HashAggregateExec(filt, [], _MASK_AGGS, mode=COMPLETE)
+        return agg, agg, filt
+    agg = HashAggregateExec(filt, [], _MASK_AGGS, mode=PARTIAL)
+    return HashAggregateExec(agg, [], _MASK_AGGS, mode=FINAL,
+                             input_schema=filt.output_schema), agg, filt
+
+
+def _pallas_against_xla(kind, mode, rows=700):
+    """Run one predicate through the pallas lane and through FilterExec
+    + the stock XLA aggregate; the answers must agree. Returns the
+    pallas side's (aggregate, filter, context)."""
+    data = _mask_data(rows)
+    root, agg, filt = _agg_tree(data, _PREDS[kind](), mode)
+    ref_root, _, ref_filt = _agg_tree(data, _PREDS[kind](), mode)
+    rows_on, ctx = _collect(root, SrtConf({"srt.sql.pallas.enabled": True}))
+    rows_off, ctx_off = _collect(
+        ref_root, SrtConf({"srt.sql.pallas.enabled": False}))
+    assert _metric(ctx_off, "pallasBatches") == 0
+    assert ref_filt.exec_id in ctx_off.metrics
+    (a,), (b,) = rows_on, rows_off
+    assert a["cnt"] == b["cnt"] and a["cv"] == b["cv"]
+    for k in ("rev", "av", "mn", "mx"):
+        assert a[k] == (None if b[k] is None
+                        else pytest.approx(b[k], rel=1e-12)), k
+    return agg, filt, ctx, a
+
+
+@pytest.mark.parametrize("mode", ["complete", "partial_final"])
+@pytest.mark.parametrize("kind", ["divide", "numeric_in", "none_pass",
+                                  "empty_input"])
+def test_mask_lane_matches_xla_path(kind, mode):
+    """One pallas program a batch with the predicate as its live mask:
+    the stock path's answer, and the FilterExec never runs."""
+    if kind == "empty_input":
+        agg, filt, ctx, row = _pallas_against_xla("divide", mode, rows=0)
+        nbatches = 0
+    else:
+        agg, filt, ctx, row = _pallas_against_xla(kind, mode)
+        nbatches = 3
+    if kind in ("none_pass", "empty_input"):
+        assert row["cnt"] == 0 and row["rev"] is None
+    else:
+        assert row["cnt"] > 0
+    assert "pallas-global, filter=mask" in agg.node_description()
+    assert _metric(ctx, "pallasBatches") == nbatches
+    assert _metric(ctx, "pallasMaskFilterBatches") == nbatches
+    # the lane streams from the filter's child: FilterExec.execute is
+    # never entered, so no jit_FilterExec._filter launch
+    assert filt.exec_id not in ctx.metrics
+
+
+def test_kernel_safe_predicate_stays_in_the_kernel():
+    agg, filt, ctx, row = _pallas_against_xla("kernel_safe", "complete")
+    assert "pallas-global, filter=kernel" in agg.node_description()
+    assert row["cnt"] > 0
+    assert _metric(ctx, "pallasBatches") == 3
+    assert _metric(ctx, "pallasMaskFilterBatches") == 0
+    assert filt.exec_id not in ctx.metrics
+
+
+@pytest.mark.parametrize("kind", ["ansi", "partition_context"])
+def test_unabsorbable_predicate_keeps_its_filter_exec(kind):
+    """ANSI guards must raise outside jit, and the lane's program does
+    not thread the partition context: both keep their FilterExec, and
+    the kernel aggregates its (compacted) output."""
+    agg, filt, ctx, row = _pallas_against_xla(kind, "complete")
+    assert "pallas-global, filter=none" in agg.node_description()
+    assert row["cnt"] > 0
+    assert _metric(ctx, "pallasBatches") == 3
+    assert _metric(ctx, "pallasMaskFilterBatches") == 0
+    assert ctx.metrics[filt.exec_id]["numOutputBatches"].value == 3

@@ -14,6 +14,7 @@ section 2). All such tests live in this one file for the same reason.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -180,3 +181,57 @@ def test_group_aggregate_int64_key(one_chip):
              _shape(one_chip, (cap,), jnp.float64),
              _shape(one_chip, (cap,), jnp.bool_),
              _shape(one_chip, (), jnp.int32))
+
+
+def test_q6_mask_filter_in_the_aggregates_program(one_chip, as_on_chip):
+    """Q6 as tpch_sf1 holds it: FLOAT64 money and quantity, so on the
+    chip the kernel refuses the predicate and it becomes the mask XLA
+    evaluates in front of tile_reduce — one program, and nothing in it
+    compacts: no scatter, and no gather but tile_reduce's own pick of
+    one partial row a tile (``out[::8]``, tiles x 128 lanes)."""
+    import datetime
+
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.vector import ColumnVector, ColumnarBatch
+    from spark_rapids_tpu.exec import pallas_agg
+    from spark_rapids_tpu.expr import aggregates as Agg
+    from spark_rapids_tpu.expr import col, lit
+
+    cap = 1 << 20
+    schema = [("l_shipdate", dt.DATE), ("l_discount", dt.FLOAT64),
+              ("l_quantity", dt.FLOAT64), ("l_extendedprice", dt.FLOAT64)]
+    pred = ((col("l_shipdate") >= lit(datetime.date(1994, 1, 1)))
+            & (col("l_shipdate") < lit(datetime.date(1995, 1, 1)))
+            & (col("l_discount") >= 0.05) & (col("l_discount") <= 0.07)
+            & (col("l_quantity") < 24.0))
+    assert not pallas_agg.pred_safe(pred, schema)
+    plan = pallas_agg.PallasAggPlan(
+        [(Agg.Sum(col("l_extendedprice") * col("l_discount")), "revenue")],
+        schema, pred=None, mask_pred=pred)
+    assert plan.ref_names == ["l_discount", "l_extendedprice"]
+    run = plan.batch_fn()
+
+    def f(date, disc, qty, price, valid, num_rows):
+        batch = ColumnarBatch(
+            [ColumnVector(date, valid, dt.DATE),
+             ColumnVector(disc, valid, dt.FLOAT64),
+             ColumnVector(qty, valid, dt.FLOAT64),
+             ColumnVector(price, valid, dt.FLOAT64)],
+            [n for n, _ in schema], num_rows)
+        return run(batch)
+
+    text = _compile(f,
+                    _shape(one_chip, (cap,), jnp.int32),
+                    _shape(one_chip, (cap,), jnp.float64),
+                    _shape(one_chip, (cap,), jnp.float64),
+                    _shape(one_chip, (cap,), jnp.float64),
+                    _shape(one_chip, (cap,), jnp.bool_),
+                    _shape(one_chip, (), jnp.int32))
+    assert "tpu_custom_call" in text
+    assert " scatter(" not in text
+    tiles = cap // PK.TILE_ROWS
+    for line in text.splitlines():
+        if " gather(" in line:
+            dims = re.search(r"= \w+\[([\d,]+)\]", line).group(1)
+            assert np.prod([int(d) for d in dims.split(",")]) \
+                <= tiles * PK.LANES, line
