@@ -249,6 +249,10 @@ class ExecContext:
         #: per-query span tracer (obs/trace.py) when
         #: srt.eventLog.trace.enabled; None = no span allocation
         self.tracer = None
+        #: join exec_id -> (build batch, what the join computed from it:
+        #: exec/join.py BuildSide): built once per build batch, dropped
+        #: with the query's context
+        self.join_builds: Dict[str, tuple] = {}
 
     def dump_crash(self, failing_exec, error: BaseException,
                    dump_dir: str) -> Optional[str]:

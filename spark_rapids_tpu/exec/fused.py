@@ -145,21 +145,24 @@ def _fused_program_builder(specs):
 
 
 def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
-                        reorder_n, suffix_specs):
+                        reorder_n, suffix_specs, mode, table_size=0):
     """MODULE-LEVEL builder for shared_fn_jit: one program running the
-    build+probe gather-map join, the left/right column reorder, and the
-    probe-side suffix chain (filter/project/partial-agg), so the joined
-    batch never materializes in HBM between operators.
+    per-pair join (a lookup or the gather-map join: ``mode``, exec/
+    join.py), the left/right column reorder, and the probe-side suffix
+    chain (filter/project/partial-agg), so the joined batch never
+    materializes in HBM between operators. ``aux`` is what the join
+    computed from its build side ahead (``BuildSide.aux``).
 
-    Non-aggregate suffixes: ``run(probe, build) -> (batch, total)``.
-    Aggregate-terminated: ``run(probe, build, row_offset) ->
-    (packed, rows_in, pallas_used, total)``. ``total`` is the join
-    kernel's true required output size — the host only trusts the
+    Non-aggregate suffixes: ``run(probe, build, *aux) -> (batch,
+    total)``. Aggregate-terminated: ``run(probe, build, row_offset,
+    *aux) -> (packed, rows_in, pallas_used, total)``. ``total`` is the
+    join kernel's true required output size — the host only trusts the
     suffix output when ``total <= out_capacity`` (the capacity-growth
     contract of exec/join.py, unchanged by fusion)."""
     from .join import _join_run_builder
     base = _join_run_builder(join_type, list(probe_keys),
-                             list(build_keys), out_capacity)
+                             list(build_keys), out_capacity, mode,
+                             table_size)
     specs = tuple(suffix_specs)
     has_agg = bool(specs) and specs[-1][0] == "agg"
     stage_fns = [_row_stage_fn(s) for s in
@@ -174,14 +177,14 @@ def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
         names = out.names[reorder_n:] + out.names[:reorder_n]
         return ColumnarBatch(cols, names, out.num_rows)
 
-    def join(probe, build):
+    def join(probe, build, aux):
         with jax.named_scope("HashJoinExec"):
-            out, total = base(probe, build)
+            out, total = base(probe, build, *aux)
             return reorder(out), total
 
     if not has_agg:
-        def run(probe, build):
-            out, total = join(probe, build)
+        def run(probe, build, *aux):
+            out, total = join(probe, build, aux)
             for f in stage_fns:
                 out = f(out)
             return out, total
@@ -189,8 +192,8 @@ def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
     shell = _agg_shell(specs[-1])
     use_pallas = bool(specs[-1][1])
 
-    def run_agg(probe, build, row_offset):
-        out, total = join(probe, build)
+    def run_agg(probe, build, row_offset, *aux):
+        out, total = join(probe, build, aux)
         for f in stage_fns:
             out = f(out)
         rows_in = out.num_rows
@@ -556,8 +559,8 @@ class FusedHashJoinExec(TpuExec):
                 + " -> ".join(type(s).__name__ for s in self.suffix)
                 + f"]{tag}")
 
-    def _fused_fn(self, out_cap: int, donate: bool):
-        key = (out_cap, donate)
+    def _fused_fn(self, out_cap: int, donate: bool, side):
+        key = (out_cap, donate, side.mode, side.table_size)
         fn = self._fn_cache.get(key)
         if fn is None:
             jit_kwargs = {"donate_argnums": (0,)} if donate else {}
@@ -565,8 +568,8 @@ class FusedHashJoinExec(TpuExec):
                 _fused_join_builder, self.join.join_type,
                 tuple(self.join._probe_key_exprs),
                 tuple(self.join._build_key_exprs),
-                out_cap, self._reorder_n, self._suffix_specs,
-                **jit_kwargs)
+                out_cap, self._reorder_n, self._suffix_specs, side.mode,
+                side.table_size, **jit_kwargs)
             _annotate(fn, self._label)
             self._fn_cache[key] = fn
         return fn
@@ -599,28 +602,34 @@ class FusedHashJoinExec(TpuExec):
 
     def _run_pair(self, ctx: ExecContext, probe: ColumnarBatch,
                   build: ColumnarBatch, retries: Metric, st):
+        """The join's ``_join_pair`` with the suffix behind it: the same
+        build side, first capacity and overflow contract (all the
+        join's), one host read a launch."""
         from ..columnar.vector import choose_capacity
         from ..conf import JOIN_GROWTH_STEPS
-        n_probe = int(probe.num_rows)
+        join = self.join
         max_steps = ctx.conf.get(JOIN_GROWTH_STEPS)
-        out_cap = choose_capacity(max(n_probe, 16))
+        side = join._build_side(ctx, build)
+        out_cap = join._pair_capacity(ctx, probe, side)
         measured = False
         total = 0
         for _ in range(max_steps + 1):
             donate = self.donate and measured
-            fn = self._fused_fn(out_cap, donate)
+            fn = self._fused_fn(out_cap, donate, side)
             with ctx.semaphore, NvtxTimer(st["fuse_time"], "fused-join"):
                 if self._agg is not None:
                     out, rows_in, used, total = fn(
-                        probe, build, jnp.int64(st["offset"]))
+                        probe, build, jnp.int64(st["offset"]), *side.aux)
                 else:
-                    out, total = fn(probe, build)
-            total = int(total)
+                    out, total = fn(probe, build, *side.aux)
+                    rows_in = out.num_rows
+            total, n_in = join._read(ctx, total, rows_in)
             if total <= out_cap:
+                join._note_total(side, total)
+                join._count_pair(ctx, side, out_cap)
                 st["saved"].add(self._saved_bytes_per_slot * out_cap)
                 if self._agg is None:
-                    return out
-                n_in = int(rows_in)
+                    return ColumnarBatch(out.columns, out.names, n_in)
                 st["offset"] += n_in
                 if n_in == 0:
                     # mirror the unfused partial aggregate: no partial
@@ -636,6 +645,7 @@ class FusedHashJoinExec(TpuExec):
                     "fused join under-reported its output size on a "
                     "donated relaunch")
             retries.add(1)
+            join._counter(ctx, "joinCapacityRelaunches").add(1)
             out_cap = choose_capacity(total)
             measured = True
         raise RuntimeError(

@@ -19,7 +19,8 @@ preservation holds per bucket).
 
 from __future__ import annotations
 
-from typing import Iterator, List, Optional, Sequence, Tuple
+import time
+from typing import Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -29,6 +30,7 @@ from ..columnar.vector import ColumnarBatch, choose_capacity
 from ..expr.core import Expression
 from ..jit_registry import shared_fn_jit
 from ..ops import kernels as K
+from ..obs.trace import annotate
 from .base import ExecContext, Metric, Schema, TpuExec
 
 # Join types (Catalyst names)
@@ -48,20 +50,94 @@ _MAX_GROWTH_STEPS = 4
 # --- module-level jit builders (shared process-wide via jit_registry:
 # every join over the same keys/type/capacity reuses one traced fn) ---
 
-def _join_run_builder(join_type, probe_keys, build_keys, out_capacity):
-    def run(probe, build):
+#: how a pair is joined, decided from the build side's data when it is
+#: built (``_HashJoinBase._build_side``): through a direct-address table
+#: of a unique integer key, or through the sorted hash index of
+#: ``K.join_gather_maps``
+LOOKUP = "lookup"
+HASH = "hash"
+
+#: operator metrics of the join execs (docs/OBSERVABILITY.md, "Joins"):
+#: name -> (level, unit, key of the query record's ``phases`` that
+#: ``plan/session.py`` sums it into; None for a gauge)
+JOIN_COUNTERS = {
+    "joinBuildTime": (Metric.MODERATE, "ns", "join_build_ns"),
+    "lookupJoinBatches": (Metric.MODERATE, "", "lookup_join_batches"),
+    "hashJoinBatches": (Metric.MODERATE, "", "hash_join_batches"),
+    "joinOutCapacity": (Metric.DEBUG, "", None),
+    "joinCapacityRelaunches": (Metric.MODERATE, "",
+                               "join_capacity_relaunches"),
+    "joinReadbacks": (Metric.MODERATE, "", "join_readbacks"),
+}
+
+_LOOKUP_KINDS = {INNER: "inner", LEFT_OUTER: "left", RIGHT_OUTER: "left",
+                 LEFT_SEMI: "semi", LEFT_ANTI: "anti"}
+
+
+class BuildSide(NamedTuple):
+    """What a join computed from its build batch, once: ``mode`` and the
+    arrays every pair's program takes after ``(probe, build)`` —
+    ``(table, kmin)`` for LOOKUP, ``(hashes_sorted, order)`` for HASH.
+    ``table_size``: the leading slots of ``table`` that span the keys (a
+    capacity bucket; the table is built once at the largest span a
+    lookup accepts, and a pair's program gathers from its head)."""
+    mode: str
+    aux: tuple
+    table_size: int = 0
+
+
+def _join_run_builder(join_type, probe_keys, build_keys, out_capacity,
+                      mode, table_size=0):
+    """``run(probe, build, *aux) -> (out, total)``; ``aux`` is the
+    ``BuildSide.aux`` of ``mode``."""
+    if mode == LOOKUP:
+        kind = _LOOKUP_KINDS[join_type]
+
+        def run_lookup(probe, build, table, kmin):
+            return K.lookup_join(probe, build, probe_keys[0].eval(probe),
+                                 table[:table_size], kmin, out_capacity,
+                                 kind)
+        return run_lookup
+
+    def run(probe, build, *index):
         pk = [e.eval(probe) for e in probe_keys]
         bk = [e.eval(build) for e in build_keys]
+        index = index or None
         if join_type in (LEFT_SEMI, LEFT_ANTI):
             out, total = K.semi_anti_join(
                 probe, bk, pk, build.live_mask(),
                 anti=(join_type == LEFT_ANTI),
-                scratch_capacity=out_capacity)
+                scratch_capacity=out_capacity, build_index=index)
         elif join_type == INNER:
-            out, total = K.inner_join(probe, build, pk, bk, out_capacity)
+            out, total = K.inner_join(probe, build, pk, bk, out_capacity,
+                                      index)
         else:  # LEFT_OUTER / RIGHT_OUTER: probe is preserved side
-            out, total = K.left_join(probe, build, pk, bk, out_capacity)
+            out, total = K.left_join(probe, build, pk, bk, out_capacity,
+                                     index)
         return out, total
+    return run
+
+
+def _hash_index_builder(build_keys):
+    def run(build):
+        return K.build_hash_index([e.eval(build) for e in build_keys],
+                                  build.live_mask())
+    return run
+
+
+def _lookup_table_builder(build_keys, size):
+    def run(build):
+        return K.build_lookup_table(build_keys[0].eval(build),
+                                    build.live_mask(), size)
+    return run
+
+
+def _lookup_count_builder(join_type, probe_keys, table_size):
+    kind = _LOOKUP_KINDS[join_type]
+
+    def run(probe, table, kmin):
+        return K.lookup_count(probe, probe_keys[0].eval(probe),
+                              table[:table_size], kmin, kind)
     return run
 
 
@@ -137,6 +213,11 @@ class _HashJoinBase(TpuExec):
                 f"join type {join_type!r} not supported on TPU yet "
                 "(planner must fall back)")
         self._jit_cache = {}
+        #: output-capacity bucket the pairs of this join have needed so
+        #: far (``_pair_capacity``): measured, never configured. Kept
+        #: across re-runs of a cached plan — it is only where a pair's
+        #: first launch starts; the overflow contract still decides.
+        self._cap_hint = 0
 
     @property
     def output_schema(self) -> Schema:
@@ -149,14 +230,17 @@ class _HashJoinBase(TpuExec):
     # --- build side ---
     def _concat_build(self, ctx: ExecContext,
                       stream) -> Optional[ColumnarBatch]:
-        batches = [b for b in stream if int(b.num_rows) > 0]
+        sized = [(b, self._read(ctx, b.num_rows)[0]) for b in stream]
+        batches = [b for b, n in sized if n > 0]
         if not batches:
             return None
-        total = sum(int(b.num_rows) for b in batches)
-        cap = choose_capacity(total)
+        total = sum(n for _, n in sized)
+        if len(batches) == 1:
+            # the row count it was just read for, on the host from here
+            b = batches[0]
+            return ColumnarBatch(b.columns, b.names, total)
         with ctx.semaphore:
-            return (batches[0] if len(batches) == 1
-                    else K.concat_batches(batches, cap))
+            return K.concat_batches(batches, choose_capacity(total))
 
     def _key_cols(self, batch: ColumnarBatch, exprs):
         return [e.eval(batch) for e in exprs]
@@ -166,22 +250,128 @@ class _HashJoinBase(TpuExec):
         return contains_eager(list(self._probe_key_exprs)
                               + list(self._build_key_exprs))
 
-    def _join_fn(self, out_capacity: int):
-        """jit per output capacity; cached per instance, shared
-        process-wide (registry) across joins with equal keys/type.
-        Eager keys (ANSI guards) evaluate un-jitted."""
-        key = out_capacity
+    def _shared(self, key, builder, *args):
+        """``builder(*args)`` jitted once per instance under ``key``,
+        shared process-wide (registry) across joins with equal keys /
+        type. Eager keys (ANSI guards) evaluate un-jitted."""
         if key not in self._jit_cache:
-            if self._eager_keys():
-                self._jit_cache[key] = _join_run_builder(
-                    self.join_type, self._probe_key_exprs,
-                    self._build_key_exprs, out_capacity)
-            else:
-                self._jit_cache[key] = shared_fn_jit(
-                    _join_run_builder, self.join_type,
-                    self._probe_key_exprs, self._build_key_exprs,
-                    out_capacity)
+            self._jit_cache[key] = builder(*args) if self._eager_keys() \
+                else shared_fn_jit(builder, *args)
         return self._jit_cache[key]
+
+    def _join_fn(self, out_capacity: int, side: BuildSide):
+        """The per-pair program: jit per output capacity and the build
+        side's mode (and table size)."""
+        return self._shared(
+            (out_capacity, side.mode, side.table_size), _join_run_builder,
+            self.join_type, self._probe_key_exprs, self._build_key_exprs,
+            out_capacity, side.mode, side.table_size)
+
+    # --- counters, host reads ---
+    def _counter(self, ctx: ExecContext, name: str) -> Metric:
+        level, unit, _ = JOIN_COUNTERS[name]
+        return ctx.metrics_for(self.exec_id).setdefault(
+            name, Metric(name, level, unit))
+
+    def _read(self, ctx: ExecContext, *scalars):
+        """Host values of device scalars, read together: ONE round trip,
+        counted in ``joinReadbacks``. Values already on the host cost
+        nothing and count nothing."""
+        if all(isinstance(x, int) for x in scalars):
+            return scalars
+        self._counter(ctx, "joinReadbacks").add(1)
+        return tuple(int(x) for x in jax.device_get(scalars))
+
+    # --- build side: computed once per build batch ---
+    def _lookup_side(self, ctx: ExecContext, build: ColumnarBatch
+                     ) -> Optional[BuildSide]:
+        """A LOOKUP build side where ``build``'s data allows one: a
+        single integer key on both sides whose live values are unique
+        and span no more than the largest batch capacity bucket this
+        query runs at (or the build's own). Read from the data: one
+        program (key range, table and slots taken together) and one
+        host read, once per build."""
+        from ..conf import BATCH_SIZE_ROWS
+        if len(self._build_key_exprs) != 1 or self._eager_keys():
+            return None
+        probe_child, build_child = (self.children if self.build_side ==
+                                    "right" else self.children[::-1])
+        probe_type = self._probe_key_exprs[0].data_type(
+            probe_child.output_schema)
+        build_type = self._build_key_exprs[0].data_type(
+            build_child.output_schema)
+        if probe_type != build_type or not build_type.is_integral:
+            return None
+        bound = max(choose_capacity(ctx.conf.get(BATCH_SIZE_ROWS)),
+                    build.capacity)
+        make = self._shared(("lookup_table", bound), _lookup_table_builder,
+                            self._build_key_exprs, bound)
+        with ctx.semaphore:
+            table, kmin, *stats = make(build)
+            lo, hi, n_keys, n_distinct = self._read(ctx, kmin, *stats)
+        span = hi - lo + 1  # exact: the host's integers
+        if span > bound or n_distinct != n_keys:
+            return None  # too sparse, or a key value held by two rows
+        return BuildSide(LOOKUP, (table, kmin),
+                         choose_capacity(max(span, 1)))
+
+    def _build_side(self, ctx: ExecContext, build: ColumnarBatch,
+                    lookup: bool = True) -> BuildSide:
+        """The lookup table (where ``lookup`` lets it be tried), or else
+        the sorted hash index, of ``build``. Kept on the query's context
+        under this join while the same batch is probed: every probe
+        batch of a broadcast join, every probe bucket of a sub-partition,
+        finds it there."""
+        held = ctx.join_builds.get(self.exec_id)
+        if held is not None and held[0] is build:
+            return held[1]
+        t0 = time.perf_counter_ns()
+        with annotate("join.build"):
+            side = self._lookup_side(ctx, build) if lookup else None
+            if side is None:
+                if self._build_key_exprs:
+                    index = self._shared("hash_index", _hash_index_builder,
+                                         self._build_key_exprs)
+                    with ctx.semaphore:
+                        side = BuildSide(HASH, tuple(index(build)))
+                else:
+                    side = BuildSide(HASH, ())
+        self._counter(ctx, "joinBuildTime").add(
+            time.perf_counter_ns() - t0)
+        ctx.join_builds[self.exec_id] = (build, side)
+        return side
+
+    # --- output capacity: follows the matches, not the probe ---
+    def _pair_capacity(self, ctx: ExecContext, probe: ColumnarBatch,
+                       side: BuildSide) -> int:
+        """Capacity of a pair's first launch. A lookup's: the bucket its
+        earlier pairs needed and, before any has run, the pair's own
+        match count. The hash join's, as ever: the probe's rows (every
+        probe row matching about one build row)."""
+        if side.mode == LOOKUP:
+            if not self._cap_hint:
+                count = self._shared(
+                    ("lookup_count", side.table_size),
+                    _lookup_count_builder, self.join_type,
+                    self._probe_key_exprs, side.table_size)
+                with ctx.semaphore:
+                    total = count(probe, *side.aux)
+                self._note_total(side, self._read(ctx, total)[0])
+            # a unique build key: no more rows than the probe holds
+            return min(self._cap_hint, probe.capacity)
+        n_probe, = self._read(ctx, probe.num_rows)
+        return choose_capacity(max(n_probe, 16))
+
+    def _note_total(self, side: BuildSide, total: int) -> None:
+        if side.mode == LOOKUP:
+            self._cap_hint = max(self._cap_hint,
+                                 choose_capacity(max(total, 16)))
+
+    def _count_pair(self, ctx: ExecContext, side: BuildSide,
+                    out_cap: int) -> None:
+        self._counter(ctx, "lookupJoinBatches" if side.mode == LOOKUP
+                      else "hashJoinBatches").add(1)
+        self._counter(ctx, "joinOutCapacity").set(out_cap)
 
     @property
     def _probe_key_exprs(self):
@@ -260,19 +450,25 @@ class _HashJoinBase(TpuExec):
                    build: ColumnarBatch, retries: Metric
                    ) -> ColumnarBatch:
         """One probe batch against one build batch, with capacity
-        growth retry."""
+        growth retry. One host read a launch: the required size and the
+        output's row count together, so the batch that leaves carries
+        its row count on the host."""
         from ..conf import JOIN_GROWTH_STEPS
-        n_probe = int(probe.num_rows)
         max_steps = ctx.conf.get(JOIN_GROWTH_STEPS)
-        # initial guess: every probe row matches ~1 build row
-        out_cap = choose_capacity(max(n_probe, 16))
+        side = self._build_side(ctx, build)
+        out_cap = self._pair_capacity(ctx, probe, side)
         for step in range(max_steps + 1):
             with ctx.semaphore:
-                out, total = self._join_fn(out_cap)(probe, build)
-            total = int(total)
+                out, total = self._join_fn(out_cap, side)(
+                    probe, build, *side.aux)
+            total, n_out = self._read(ctx, total, out.num_rows)
             if total <= out_cap:
-                return self._reorder_columns(out)
+                self._note_total(side, total)
+                self._count_pair(ctx, side, out_cap)
+                return self._reorder_columns(
+                    ColumnarBatch(out.columns, out.names, n_out))
             retries.add(1)
+            self._counter(ctx, "joinCapacityRelaunches").add(1)
             out_cap = choose_capacity(total)
         raise RuntimeError(
             f"join expansion {total} exceeded capacity after "
@@ -405,10 +601,14 @@ class _HashJoinBase(TpuExec):
                         with ctx.semaphore:
                             chunk = self._jit_cache[ck](
                                 bucket_build, jnp.int32(ci * threshold))
+                        self._build_side(ctx, chunk, lookup=False)
                         for psb in probe_buckets[p]:
                             yield from self._join_batches(
                                 ctx, psb.get(), chunk, retries)
                 else:
+                    # a bucket of a build too large for one batch keeps
+                    # the general path: no lookup is tried on it
+                    self._build_side(ctx, bucket_build, lookup=False)
                     for psb in probe_buckets[p]:
                         yield from self._join_batches(
                             ctx, psb.get(), bucket_build, retries)
@@ -418,6 +618,8 @@ class _HashJoinBase(TpuExec):
                 sb.close()
                 sub_builds[p] = None
         finally:
+            # the last bucket's build side: not to outlive its batch
+            ctx.join_builds.pop(self.exec_id, None)
             for sb in sub_builds:
                 if sb is not None:
                     sb.close()
@@ -560,9 +762,8 @@ class _HashJoinBase(TpuExec):
             yield from self._empty_result(probe_stream, ctx)
             return
         self._runtime_partition_prune(ctx, build)
-        probe_stream = self._bloom_prefilter(ctx, probe_stream, build)
         threshold = ctx.conf.get(JOIN_SUB_PARTITION_ROWS)
-        n_rows = int(build.num_rows)
+        n_rows, = self._read(ctx, build.num_rows)
         keyed = bool(self.left_keys or self.right_keys)
         sub = n_rows > threshold and keyed
         if not sub and keyed:
@@ -580,6 +781,10 @@ class _HashJoinBase(TpuExec):
                 if slices:
                     threshold = max(-(-n_rows // slices), 1)
                     sub = True
+        if sub or self._build_side(ctx, build).mode != LOOKUP:
+            # (a lookup is one gather a probe row: nothing there for a
+            # bloom filter, a hash and a compaction a probe row, to save)
+            probe_stream = self._bloom_prefilter(ctx, probe_stream, build)
         if sub:
             holder = [build]
             del build
@@ -587,7 +792,7 @@ class _HashJoinBase(TpuExec):
                                                 threshold)
             return
         for probe in probe_stream:
-            if int(probe.num_rows) == 0:
+            if self._read(ctx, probe.num_rows)[0] == 0:
                 continue
             yield from self._join_batches(ctx, probe, build, retries)
 

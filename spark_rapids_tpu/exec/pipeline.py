@@ -40,6 +40,18 @@ Correctness contract:
     (LocalLimit, error unwind) leaks neither threads nor spill-catalog
     registrations.
 
+Producer threads are kept (``_ProducerPool``): an iterator borrows one
+for its run and hands it back, and an iterator that names an
+``affinity`` gets the thread that served that affinity last. The reason
+is the host allocator, not the cost of starting a thread: a scan makes
+and frees a few hundred MB of host buffers, glibc ties a thread to one
+of its arenas when the thread first allocates, and in a process whose
+runtime threads already hold every arena a NEW thread is dealt the
+next arena of the ring, so a scan run on a fresh thread each query
+walks its buffers through a hundred arenas (cold memory every time,
+a quarter slower end to end) while the same scan on the thread it had
+before finds its heap where it left it.
+
 The SelfTimer disjointness invariant (obs: exclusive op-times on one
 thread never overlap) holds because each thread pulls through its own
 timer stack (ExecContext.timer_stack is thread-local): producer-side
@@ -80,6 +92,102 @@ _THREAD_LEAKS = [0]
 
 def prefetch_thread_leaks() -> int:
     return _THREAD_LEAKS[0]
+
+
+#: a parked producer's thread name; while it runs an iterator it is
+#: ``srt-prefetch-<name>``, so a thread under that name after close()
+#: is a producer still inside its source
+_PARKED = "srt-producer-parked"
+#: parked producers kept per process; the least recently used goes first
+_MAX_PARKED = 64
+
+
+class _Producer(threading.Thread):
+    """One kept producer thread: runs the job it is handed, parks."""
+
+    def __init__(self, pool: "_ProducerPool"):
+        super().__init__(name=_PARKED, daemon=True)
+        self._pool = pool
+        self._cv = threading.Condition()
+        self._job = None
+        self.affinity: Optional[str] = None
+
+    def hand(self, job) -> None:
+        """``job``: (run, thread name, parked event), or None to end."""
+        with self._cv:
+            self._job = (job,)
+            self._cv.notify()
+
+    def run(self) -> None:
+        while True:
+            with self._cv:
+                while self._job is None:
+                    self._cv.wait()
+                (job,) = self._job
+                self._job = None
+            if job is None:
+                return
+            run, name, parked = job
+            self.name = name
+            try:
+                run()
+            except BaseException:  # noqa: BLE001
+                # PrefetchIterator._run relays its errors to the consumer;
+                # whatever else a job raises must not end a thread the pool
+                # still lists
+                pass
+            finally:
+                # this thread's view of conf and query is the job's
+                from ..robustness.admission import set_current_query
+                set_active_conf(None)
+                set_current_query(None)
+                self.name = _PARKED
+                self._pool.park(self)
+                parked.set()
+
+
+class _ProducerPool:
+    """Parked producer threads, by the affinity they served last."""
+
+    def __init__(self):
+        self._lock = threading.Lock()
+        #: parked producers, least recently parked first
+        self._parked: "deque[_Producer]" = deque()
+
+    def start(self, run: Callable[[], None], name: str,
+              affinity: str) -> threading.Event:
+        """Run ``run`` on the parked thread that served ``affinity``
+        last, else on a new one (on the longest parked, once the pool
+        is full). Returns the event set once the thread is parked
+        again."""
+        with self._lock:
+            worker = None
+            for w in reversed(self._parked):
+                if w.affinity == affinity:
+                    worker = w
+                    break
+            if worker is not None:
+                self._parked.remove(worker)
+            elif len(self._parked) >= _MAX_PARKED:
+                worker = self._parked.popleft()
+        if worker is None:
+            worker = _Producer(self)
+            worker.start()
+        worker.affinity = affinity
+        parked = threading.Event()
+        worker.hand((run, name, parked))
+        return parked
+
+    def park(self, worker: _Producer) -> None:
+        with self._lock:
+            self._parked.append(worker)
+            extra = [self._parked.popleft()
+                     for _ in range(len(self._parked) - _MAX_PARKED)]
+        for w in extra:
+            w.hand(None)
+
+
+_PRODUCERS = _ProducerPool()
 
 
 def prefetch_buffer_bytes() -> int:
@@ -141,7 +249,8 @@ class PrefetchIterator:
                  tracer=None,
                  parent_span_id: Optional[int] = None,
                  query=None,
-                 leak_metric: Optional[Metric] = None):
+                 leak_metric: Optional[Metric] = None,
+                 affinity: Optional[str] = None):
         self._factory = source_factory
         #: cancellation token (robustness/admission.py QueryContext):
         #: the producer observes it between items and while blocked on
@@ -175,11 +284,12 @@ class PrefetchIterator:
         self._stopped = False
         self._error: Optional[BaseException] = None
         self._closed = False
-        self._thread = threading.Thread(
-            target=self._run, name=f"srt-prefetch-{name}", daemon=True)
+        self._thread_name = f"srt-prefetch-{name}"
         with _LIVE_LOCK:
             _LIVE.add(self)
-        self._thread.start()
+        #: set once the producer has left the source and parked again
+        self._parked = _PRODUCERS.start(self._run, self._thread_name,
+                                        affinity or name)
 
     # --- producer side ---------------------------------------------------
     def _run(self) -> None:
@@ -337,11 +447,12 @@ class PrefetchIterator:
                 max(self._bytes_peak_metric.value, self._bytes_peak))
 
     def close(self, join_timeout: float = 30.0) -> None:
-        """Stop the producer, join it, and discard queued items.
+        """Stop the producer, wait until it has left the source and
+        parked, and discard queued items.
 
         A producer that outlives the join timeout is wedged inside its
         source (hung socket, stuck decode) — it leaks as a daemon
-        thread. That must fail loudly, not silently: a warning event,
+        thread (the pool starts another when it needs one). That must fail loudly, not silently: a warning event,
         the process-wide ``prefetch_thread_leaks`` counter, and the
         node's ``prefetchThreadLeaks`` metric all record it so chaos
         runs and the serving tier's health checks trip."""
@@ -351,20 +462,19 @@ class PrefetchIterator:
         with self._cv:
             self._stopped = True
             self._cv.notify_all()
-        self._thread.join(timeout=join_timeout)
-        if self._thread.is_alive():
+        if not self._parked.wait(timeout=join_timeout):
             _THREAD_LEAKS[0] += 1
             if self._leak_metric is not None:
                 self._leak_metric.add(1)
             from ..obs import events as _events
             _events.emit("PrefetchThreadLeak",
-                         thread=self._thread.name,
+                         thread=self._thread_name,
                          join_timeout_s=join_timeout,
                          queued=len(self._buf))
             import logging
             logging.getLogger("spark_rapids_tpu.exec").warning(
-                "prefetch producer %s leaked: still alive %.0fs after "
-                "close()", self._thread.name, join_timeout)
+                "prefetch producer %s leaked: still in its source %.0fs "
+                "after close()", self._thread_name, join_timeout)
         with self._cv:
             while self._buf:
                 item, _ = self._buf.popleft()
@@ -417,7 +527,8 @@ class _Unstaged:
 
 def prefetch_batches(ctx: ExecContext, node: TpuExec,
                      source_factory: Callable[[], Iterable],
-                     name: str = "", stage: bool = True) -> Iterator:
+                     name: str = "", stage: bool = True,
+                     affinity: Optional[str] = None) -> Iterator:
     """Pull a ColumnarBatch stream through a background prefetcher.
 
     Each produced batch registers with the spill catalog as an
@@ -427,6 +538,10 @@ def prefetch_batches(ctx: ExecContext, node: TpuExec,
     releases the registration before yielding. Metrics land on
     ``node``: prefetchWaitTime (consumer blocked on an empty queue),
     prefetchQueueDepthPeak, prefetchBytesPeak.
+
+    ``affinity`` names what the producer works on, where that outlives
+    the plan node (a scan's files): the same thread, and so the same
+    host heap, serves it from query to query. Default: the name.
 
     ``stage=False`` skips the SpillableBatch wrap — for streams that
     may hand through ALREADY-owned live batches (the shuffle locality
@@ -477,7 +592,8 @@ def prefetch_batches(ctx: ExecContext, node: TpuExec,
         tracer=ctx.tracer,
         parent_span_id=parent_span_id,
         query=ctx.query,
-        leak_metric=leaks)
+        leak_metric=leaks,
+        affinity=affinity)
 
     def consume() -> Iterator:
         try:
@@ -517,7 +633,10 @@ class PrefetchExec(TpuExec):
         if not pipeline_enabled(ctx, self):
             yield from child.execute(ctx)
             return
-        yield from prefetch_batches(ctx, self, lambda: child.execute(ctx))
+        # the child's description (a scan: format and files), not this
+        # node's id: every plan over the same table shares the producer
+        yield from prefetch_batches(ctx, self, lambda: child.execute(ctx),
+                                    affinity=child.node_description())
 
     def node_description(self) -> str:
         return "Prefetch"

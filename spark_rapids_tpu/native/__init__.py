@@ -77,11 +77,47 @@ def _build_lib() -> str:
     return so
 
 
+#: glibc's mallopt parameters (malloc.h)
+_M_TRIM_THRESHOLD, _M_TOP_PAD, _M_MMAP_THRESHOLD = -1, -2, -3
+#: the largest mmap threshold glibc takes (half a thread arena's heap), and
+#: that whole heap: a pad this large keeps a thread arena's emptied heap mapped
+_MMAP_THRESHOLD_MAX, _ARENA_HEAP = 32 << 20, 64 << 20
+
+
+def _retain_host_heap() -> bool:
+    """Keep the host heap the scan has grown, once per process; says
+    whether the allocator took all three settings.
+
+    A scan makes and frees a few hundred MB of host buffers a query
+    (decoded column chunks, the coalesced batch, the padded upload), 2-8 MB
+    each. Left alone, glibc serves those from fresh ``mmap``s or from heaps
+    it trims and unmaps as they empty, and which of the two a process does
+    follows from the arena its scan thread happened to get: one process
+    page-faults its buffers in again every query (thousands of faults, the
+    mapping lock held against every other thread) and the next does not.
+    Fixed thresholds take that draw away: buffers up to 32 MB come from
+    the heap, and the heap is never trimmed or unmapped, so a process holds
+    its high-water mark of host memory, as a pinned pool would (HostAlloc /
+    PinnedMemoryPool role). A libc without ``mallopt`` is left as it is."""
+    try:
+        mallopt = ctypes.CDLL(None).mallopt
+    except (OSError, AttributeError):
+        return False
+    mallopt.argtypes, mallopt.restype = [ctypes.c_int, ctypes.c_int], ctypes.c_int
+    # all three, whatever the first returns: any one of them ends the
+    # thresholds' drift
+    taken = [mallopt(_M_MMAP_THRESHOLD, _MMAP_THRESHOLD_MAX),
+             mallopt(_M_TRIM_THRESHOLD, 2**31 - 1),
+             mallopt(_M_TOP_PAD, _ARENA_HEAP)]
+    return all(taken)
+
+
 def _lib() -> ctypes.CDLL:
     global _LIB
     with _LIB_LOCK:
         if _LIB is None:
             lib = ctypes.CDLL(_build_lib())
+            _retain_host_heap()
             u8p = ctypes.POINTER(ctypes.c_uint8)
             lib.slz4_max_compressed_size.restype = ctypes.c_int64
             lib.slz4_max_compressed_size.argtypes = [ctypes.c_int64]

@@ -656,20 +656,33 @@ def _keys_equal(a_cols: Sequence[Column], a_idx, b_cols: Sequence[Column],
     return ok
 
 
+def build_hash_index(build_keys: Sequence[Column], build_live):
+    """The build side's half of ``join_gather_maps``: ``(bh_sorted,
+    order)``, the 64-bit key hashes in ascending order and the build row
+    each came from. It depends on the build batch alone, so a join that
+    probes one build with many batches computes it once (exec/join.py)
+    and hands it back through ``build_index``."""
+    imax = jnp.iinfo(jnp.int64).max
+    bh = _join_key_hash(build_keys, imax - 2)
+    bh = jnp.where(build_live, bh, jnp.int64(imax))
+    order = jnp.argsort(bh, stable=True).astype(jnp.int32)
+    return jnp.take(bh, order), order
+
+
 def join_gather_maps(probe_keys: Sequence[Column], build_keys: Sequence[Column],
-                     probe_live, build_live, out_capacity: int):
+                     probe_live, build_live, out_capacity: int,
+                     build_index=None):
     """Compute (probe_idx, build_idx, pair_valid, total_pairs) gather maps
     for matching pairs — the cuDF ``hashJoinGatherMaps`` equivalent.
 
     total_pairs is the true match count; if it exceeds out_capacity the
-    caller must split and retry.
+    caller must split and retry. ``build_index`` is ``build_hash_index``
+    of the same build side where the caller computed it ahead.
     """
     imax = jnp.iinfo(jnp.int64).max
     cap_b = build_keys[0].capacity
-    bh = _join_key_hash(build_keys, imax - 2)
-    bh = jnp.where(build_live, bh, jnp.int64(imax))
-    order = jnp.argsort(bh, stable=True).astype(jnp.int32)
-    bh_sorted = jnp.take(bh, order)
+    bh_sorted, order = build_index if build_index is not None \
+        else build_hash_index(build_keys, build_live)
 
     ph = _join_key_hash(probe_keys, imax - 3)
     ph = jnp.where(probe_live, ph, jnp.int64(imax - 1))
@@ -694,11 +707,13 @@ def join_gather_maps(probe_keys: Sequence[Column], build_keys: Sequence[Column],
 
 def inner_join(probe: ColumnarBatch, build: ColumnarBatch,
                probe_keys: Sequence[Column], build_keys: Sequence[Column],
-               out_capacity: int) -> Tuple[ColumnarBatch, jnp.ndarray]:
+               out_capacity: int, build_index=None
+               ) -> Tuple[ColumnarBatch, jnp.ndarray]:
     """Inner join; returns (joined_batch, candidate_total) — the candidate
     total lets the host detect output-capacity overflow."""
     p_idx, b_idx, pair_valid, total_cand, _ = join_gather_maps(
-        probe_keys, build_keys, probe.live_mask(), build.live_mask(), out_capacity)
+        probe_keys, build_keys, probe.live_mask(), build.live_mask(),
+        out_capacity, build_index)
     compact_idx = compaction_indices(pair_valid)
     n_out = jnp.sum(pair_valid).astype(jnp.int32)
     p_take = jnp.take(p_idx, compact_idx)
@@ -712,7 +727,8 @@ def inner_join(probe: ColumnarBatch, build: ColumnarBatch,
 
 def left_join(probe: ColumnarBatch, build: ColumnarBatch,
               probe_keys: Sequence[Column], build_keys: Sequence[Column],
-              out_capacity: int) -> Tuple[ColumnarBatch, jnp.ndarray]:
+              out_capacity: int, build_index=None
+              ) -> Tuple[ColumnarBatch, jnp.ndarray]:
     """Left outer join with probe as the preserved/stream side.
 
     The returned size scalar is max(candidate window, true output rows
@@ -721,7 +737,8 @@ def left_join(probe: ColumnarBatch, build: ColumnarBatch,
     rows past capacity are dropped, so both bound the retry)."""
     cap_p = probe.capacity
     p_idx, b_idx, pair_valid, total_cand, _ = join_gather_maps(
-        probe_keys, build_keys, probe.live_mask(), build.live_mask(), out_capacity)
+        probe_keys, build_keys, probe.live_mask(), build.live_mask(),
+        out_capacity, build_index)
     # per-probe-row true match count
     match_per_probe = jnp.zeros(cap_p, jnp.int32).at[p_idx].add(
         pair_valid.astype(jnp.int32))
@@ -748,7 +765,8 @@ def left_join(probe: ColumnarBatch, build: ColumnarBatch,
 
 def semi_anti_join(probe: ColumnarBatch, build_keys: Sequence[Column],
                    probe_keys: Sequence[Column], build_live,
-                   anti: bool, scratch_capacity: Optional[int] = None
+                   anti: bool, scratch_capacity: Optional[int] = None,
+                   build_index=None
                    ) -> Tuple[ColumnarBatch, jnp.ndarray]:
     """Left semi / anti join — output rows come only from the probe side
     (no expansion), but the *candidate window* can still overflow when
@@ -757,10 +775,124 @@ def semi_anti_join(probe: ColumnarBatch, build_keys: Sequence[Column],
     cap_p = probe.capacity
     scratch = scratch_capacity or cap_p
     p_idx, b_idx, pair_valid, total_cand, counts = join_gather_maps(
-        probe_keys, build_keys, probe.live_mask(), build_live, scratch)
+        probe_keys, build_keys, probe.live_mask(), build_live, scratch,
+        build_index)
     matched = jnp.zeros(cap_p, jnp.bool_).at[p_idx].max(pair_valid)
     keep = probe.live_mask() & (~matched if anti else matched)
     return compact(probe, keep), total_cand
+
+
+# ---------------------------------------------------------------------------
+# Lookup join (direct-address table over a unique integer build key)
+# ---------------------------------------------------------------------------
+#
+# A dimension's surrogate key is a dense run of whole numbers, each once.
+# For such a build side a join is a lookup: ``table[key - kmin]`` is the
+# build row that holds ``key``, or -1. No hash, no sort, no binary search;
+# the one 64-bit operation on the probe path is the subtraction, and the
+# index is int32. Whether a build side qualifies is read from its data
+# (``build_lookup_table``), never from a name; the
+# exec (exec/join.py) keeps ``join_gather_maps`` for everything else.
+
+
+def build_lookup_table(key: ColumnVector, live, size: int):
+    """``(table, kmin, kmax, n_keys, n_distinct)`` in one pass over the
+    build key: the least and largest live, non-null key (int64; 0 and -1
+    where there is none), how many there are, and ``table[k - kmin]`` =
+    the build row whose key is ``k`` (-1 where no row has it),
+    int32[size], with the count of slots taken. The table is whole only
+    where ``kmax - kmin < size`` — the caller checks that on the host,
+    in exact integers — and the keys are unique exactly when
+    ``n_distinct`` then equals ``n_keys``. NULL build keys take no slot:
+    they match nothing."""
+    ok = live & key.validity
+    k = key.data.astype(jnp.int64)
+    n = jnp.sum(ok).astype(jnp.int32)
+    info = jnp.iinfo(jnp.int64)
+    none = n == 0
+    kmin = jnp.where(none, jnp.int64(0), jnp.min(jnp.where(ok, k, info.max)))
+    kmax = jnp.where(none, jnp.int64(-1),
+                     jnp.max(jnp.where(ok, k, info.min)))
+    delta = k - kmin
+    fits = ok & (delta >= 0) & (delta < size)
+    slot = jnp.where(fits, delta, size).astype(jnp.int32)
+    rows = jnp.arange(key.capacity, dtype=jnp.int32)
+    table = jnp.full((size,), -1, jnp.int32).at[slot].set(rows, mode="drop")
+    return table, kmin, kmax, n, jnp.sum(table >= 0).astype(jnp.int32)
+
+
+def lookup_rows(probe_key: ColumnVector, probe_live, table, kmin, kind: str):
+    """``(build_row, matched, keep)`` for every probe slot: the build row
+    the table holds for the probe key, whether there is one, and whether
+    a join of ``kind`` (``inner``, ``left``, ``semi``, ``anti``) keeps the
+    probe row. A NULL, dead or out-of-range probe key matches nothing."""
+    size = table.shape[0]
+    delta = probe_key.data.astype(jnp.int64) - kmin
+    in_range = (delta >= 0) & (delta < size)
+    slot = jnp.where(in_range, delta, 0).astype(jnp.int32)
+    build_row = jnp.take(table, slot)
+    matched = probe_live & probe_key.validity & in_range & (build_row >= 0)
+    keep = {"left": probe_live,
+            "anti": probe_live & ~matched}.get(kind, matched)
+    return build_row, matched, keep
+
+
+def lookup_count(probe: ColumnarBatch, probe_key: ColumnVector, table,
+                 kmin, kind: str):
+    """Rows ``lookup_join`` of this pair will produce: what sizes a join's
+    first output before any pair has run."""
+    _, _, keep = lookup_rows(probe_key, probe.live_mask(), table, kmin,
+                             kind)
+    return jnp.sum(keep).astype(jnp.int32)
+
+
+def first_kept(keep: jnp.ndarray, out_capacity: int) -> jnp.ndarray:
+    """Positions of the first ``out_capacity`` kept rows, in order: entry
+    j is the position of the j-th kept row (past the last one: the
+    capacity's last row; callers mask). A prefix sum and ``out_capacity``
+    binary searches over it — gathers only, and the cost follows the
+    output's capacity, where ``compaction_indices`` scatters once per
+    input row."""
+    cap = keep.shape[0]
+    csum = jnp.cumsum(keep.astype(jnp.int32))
+    want = jnp.arange(1, out_capacity + 1, dtype=jnp.int32)
+    pos = jnp.searchsorted(csum, want, side="left").astype(jnp.int32)
+    return jnp.clip(pos, 0, cap - 1)
+
+
+def _take_rows(col: Column, idx, valid, unique: bool) -> Column:
+    from ..columnar.nested import ListColumn
+    if isinstance(col, (StringColumn, ListColumn)):
+        return col.gather(idx, valid, unique=unique)
+    return col.gather(idx, valid)
+
+
+def lookup_join(probe: ColumnarBatch, build: ColumnarBatch,
+                probe_key: ColumnVector, table, kmin, out_capacity: int,
+                kind: str) -> Tuple[ColumnarBatch, jnp.ndarray]:
+    """Join ``probe`` to ``build`` through a lookup table of ``build``'s
+    unique key. ``kind``: ``inner``, ``left`` (probe preserved, build
+    columns NULL where nothing matched), ``semi`` or ``anti`` (probe
+    columns only). Returns ``(batch, required)``: ``required`` is the
+    exact row count of the full answer; the batch holds its first
+    ``out_capacity`` rows, in probe order, and the caller relaunches
+    larger when ``required`` exceeds that."""
+    build_row, matched, keep = lookup_rows(probe_key, probe.live_mask(),
+                                           table, kmin, kind)
+    required = jnp.sum(keep).astype(jnp.int32)
+    n_out = jnp.minimum(required, out_capacity)
+    valid = live_mask(out_capacity, n_out)
+    p_take = first_kept(keep, out_capacity)
+    # a probe row is used at most once: the build key is unique
+    out_cols = [_take_rows(c, p_take, valid, True) for c in probe.columns]
+    names = list(probe.names)
+    if kind in ("inner", "left"):
+        b_take = jnp.take(build_row, p_take)
+        b_valid = valid & jnp.take(matched, p_take)
+        out_cols += [_take_rows(c, b_take, b_valid, False)
+                     for c in build.columns]
+        names += list(build.names)
+    return ColumnarBatch(out_cols, names, n_out), required
 
 
 # ---------------------------------------------------------------------------

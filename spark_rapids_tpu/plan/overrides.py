@@ -862,6 +862,12 @@ def _build_join(plan: Join, children: List[TpuExec],
         extended = ProjectExec(anti, exprs)
         return UnionExec(lo, extended)
     build = "left" if plan.join_type == "right_outer" else "right"
+    if plan.join_type == "inner" and \
+            estimate_rows(plan.children[0]) < estimate_rows(plan.children[1]):
+        # an inner join is symmetric: build the side estimated smaller,
+        # whichever way the query names the tables (FROM date_dim JOIN
+        # store_sales builds the dimension, not the fact table)
+        build = "left"
     cls = _join_cls(plan, build, conf)
     joined = cls(left, right, left_keys, right_keys,
                  join_type=plan.join_type, build_side=build)

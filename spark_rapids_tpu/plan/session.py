@@ -17,6 +17,7 @@ import numpy as np
 from ..columnar import dtypes as dt
 from ..conf import SrtConf, active_conf, set_active_conf
 from ..exec.base import ExecContext, TpuExec
+from ..exec.join import JOIN_COUNTERS as _JOIN_COUNTERS
 from ..expr.aggregates import (Average, Count, CountStar, First, Last, Max,
                                Min, StddevSamp, Sum)
 from ..expr.core import Alias, ColumnRef, Expression, col, lit, output_name
@@ -424,6 +425,10 @@ _PHASE_METRICS = {"scanDecodeTime": "scan_decode_ns",
                   "scanWaitTime": "scan_wait_ns",
                   "scanTime": "scan_upload_ns",
                   "prefetchWaitTime": "prefetch_wait_ns"}
+# the join execs' counters (exec/join.py JOIN_COUNTERS): build time, which
+# path answered each pair, capacity relaunches, host reads of device scalars
+_PHASE_METRICS.update((name, key) for name, (_, _, key)
+                      in _JOIN_COUNTERS.items() if key)
 
 
 def _query_phases(ctx_metrics: Dict, **timed) -> Dict[str, int]:

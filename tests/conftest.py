@@ -22,6 +22,15 @@ if "xla_backend_optimization_level" not in flags:
     flags += (" --xla_backend_optimization_level=0"
               " --xla_llvm_disable_expensive_passes=true")
 os.environ["XLA_FLAGS"] = flags.strip()
+# A program over the 8 virtual devices needs 8 of the CPU client's pool
+# threads at once (its participants meet in a rendezvous), and the pool has
+# max(cores, devices) of them: on an 8-core host two such programs in
+# flight can each hold part of the pool and wait for the rest until XLA
+# aborts the process ("expected 8 threads to join the rendezvous"; seen in
+# test_mesh_spmd.py::test_nds_stage_identity, where unstack_shards slices
+# every leaf of a sharded batch at once: PR 26). XLA sizes the pool from
+# NPROC where it is set: room for four programs at a time.
+os.environ.setdefault("NPROC", "32")
 
 # XLA compile cache: the package's one rule (spark_rapids_tpu/__init__.py)
 # — an already-set JAX_COMPILATION_CACHE_DIR is left alone; otherwise the

@@ -59,7 +59,11 @@ def _join_counts(conf):
     n = 20_000
     probe = {"k": rng.integers(0, 100_000, n).tolist(),
              "v": rng.uniform(0, 1, n).tolist()}
-    build = {"k": list(range(50)), "name": [f"x{i}" for i in range(50)]}
+    # key 7 twice: a build side with a duplicate key stays on the hash
+    # path (a unique integer key would be a lookup table, which has no
+    # use for a bloom filter in front of it: exec/join.py)
+    build = {"k": list(range(50)) + [7],
+             "name": [f"x{i}" for i in range(51)]}
     left = session.create_dataframe(probe)
     right = session.create_dataframe(build)
     q = left.join(right, "k")

@@ -97,3 +97,35 @@ def test_lz4_shuffle_codec_end_to_end():
     assert len(data) < len(plain)
     back = deserialize_batch(data)
     assert batch_to_pydict(back) == batch_to_pydict(b)
+
+
+def test_host_heap_settings_are_taken_by_glibc():
+    """The scan's host buffers stay in the heap: glibc takes the mmap
+    threshold, the trim threshold and the pad, and a second call is as
+    good as the first."""
+    import ctypes
+
+    from spark_rapids_tpu import native
+    libc = ctypes.CDLL(None)
+    if not hasattr(libc, "mallopt"):
+        pytest.skip("this libc has no mallopt")
+    assert native._retain_host_heap() is True
+    assert native._retain_host_heap() is True
+
+
+def test_host_heap_is_left_alone_without_mallopt(monkeypatch):
+    """A libc without ``mallopt`` (musl, macOS) loads the library all the
+    same."""
+    import ctypes
+
+    from spark_rapids_tpu import native
+
+    class NoMallopt:
+        def __getattr__(self, name):
+            raise AttributeError(name)
+    real = ctypes.CDLL
+    monkeypatch.setattr(ctypes, "CDLL",
+                        lambda name, *a, **k: NoMallopt() if name is None
+                        else real(name, *a, **k))
+    assert native._retain_host_heap() is False
+    native.load()

@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from spark_rapids_tpu.conf import SrtConf
+from spark_rapids_tpu.exec import pipeline
 from spark_rapids_tpu.exec.pipeline import PrefetchExec, PrefetchIterator
 from spark_rapids_tpu.plan import overrides
 from spark_rapids_tpu.plan.session import TpuSession
@@ -407,3 +408,103 @@ def test_coalesce_fast_path_passes_full_batch_through():
     assert len(out) == 2
     assert out[1] is big
     assert "coalesceWaitTime" in ctx.metrics_for(node.exec_id)
+
+
+# ---------------------------------------------------------------------------
+# kept producer threads (_ProducerPool)
+# ---------------------------------------------------------------------------
+
+def _drain_on(affinity, name="pooled", conf=None, seen=None):
+    """Run one iterator to its end; returns (producer thread ident, its
+    name while producing)."""
+    seen = {} if seen is None else seen
+
+    def source():
+        from spark_rapids_tpu.conf import active_conf
+        t = threading.current_thread()
+        seen.update(ident=t.ident, name=t.name, conf=active_conf())
+        yield from range(3)
+    pf = PrefetchIterator(source, name=name, affinity=affinity, conf=conf)
+    try:
+        assert list(pf) == [0, 1, 2]
+    finally:
+        pf.close()
+    return seen["ident"], seen["name"]
+
+
+def test_same_affinity_is_served_by_the_same_thread():
+    """A scan's host buffers live in the heap glibc tied to the thread
+    that made them: the producer of a table is the same thread from query
+    to query, whatever plan node asks, and parks under another name."""
+    first, name = _drain_on("scan:/tmp/t1", name="PrefetchExec#1")
+    assert name == "srt-prefetch-PrefetchExec#1"
+    for node in ("PrefetchExec#7", "PrefetchExec#9", "PrefetchExec#1"):
+        again, name = _drain_on("scan:/tmp/t1", name=node)
+        assert again == first and name == f"srt-prefetch-{node}"
+    other, _ = _drain_on("scan:/tmp/t2")
+    assert other != first
+    assert not [t for t in _prefetch_threads() if t.is_alive()]
+    parked = [t for t in threading.enumerate()
+              if t.name == pipeline._PARKED and t.ident in (first, other)]
+    assert len(parked) == 2
+
+
+def test_two_live_iterators_of_one_affinity_get_two_threads():
+    gate = threading.Event()
+    idents = []
+
+    def source():
+        idents.append(threading.get_ident())
+        gate.wait(10)
+        yield 1
+    a = PrefetchIterator(source, affinity="scan:/tmp/shared")
+    b = PrefetchIterator(source, affinity="scan:/tmp/shared")
+    try:
+        deadline = time.monotonic() + 5.0
+        while len(idents) < 2 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        gate.set()
+        assert list(a) == [1] and list(b) == [1]
+    finally:
+        gate.set()
+        a.close()
+        b.close()
+    assert len(set(idents)) == 2
+
+
+def test_parked_thread_forgets_the_last_jobs_conf():
+    from spark_rapids_tpu.conf import SrtConf
+    mine = SrtConf({"srt.exec.pipeline.depth": "7"})
+    seen = {}
+    ident, _ = _drain_on("scan:/tmp/conf", conf=mine, seen=seen)
+    assert seen["conf"] is mine
+    again, _ = _drain_on("scan:/tmp/conf", conf=None, seen=seen)
+    assert again == ident and seen["conf"] is not mine
+
+
+def test_wedged_producer_is_replaced_not_waited_for():
+    release = threading.Event()
+
+    def stuck():
+        yield 1
+        release.wait(30)
+
+    it = PrefetchIterator(stuck, depth=1, affinity="scan:/tmp/wedged")
+    assert next(it) == 1
+    it.close(join_timeout=0.05)  # counted as a leak: still in its source
+    try:
+        ident, _ = _drain_on("scan:/tmp/wedged")  # a new thread serves it
+        assert ident is not None
+    finally:
+        release.set()
+
+
+def test_pool_keeps_a_bounded_number_of_parked_threads(monkeypatch):
+    monkeypatch.setattr(pipeline, "_MAX_PARKED", 4)
+    for i in range(12):
+        _drain_on(f"scan:/tmp/many-{i}")
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline and \
+            len(pipeline._PRODUCERS._parked) > 4:
+        time.sleep(0.01)
+    assert len(pipeline._PRODUCERS._parked) <= 4

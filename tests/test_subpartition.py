@@ -111,9 +111,10 @@ def test_thresholds_off_by_default():
 
 def test_inner_join_hot_key_skew_chunking(session):
     # one key dominates the build: hash bucketing can't split it, so
-    # the inner-join path row-chunks the hot bucket instead
+    # the inner-join path row-chunks the hot bucket instead (the probe
+    # side is the larger: an inner join builds its smaller side)
     left = make_df(session, {"k": IntGen(lo=0, hi=3),
-                             "v": IntGen(lo=-50, hi=50)}, 64, seed=9)
+                             "v": IntGen(lo=-50, hi=50)}, 320, seed=9)
     right_data = {"k": [1] * 300, "w": list(range(300))}
     right = session.create_dataframe(right_data)
     df = left.join(right, ([col("k")], [col("k")]), how="inner")
