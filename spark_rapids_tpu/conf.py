@@ -213,9 +213,14 @@ READER_TYPE = conf("srt.sql.format.parquet.reader.type") \
     .string("COALESCING")
 
 READER_THREADS = conf("srt.sql.multiThreadedRead.numThreads") \
-    .doc("Host threads for the multithreaded reader pool. "
+    .doc("Most host threads a scan of several files decodes on, ahead of "
+         "the thread that assembles and uploads its batches (fewer where "
+         "the host has fewer cores or the scan fewer files). Decoding "
+         "local files is bound by the host's memory bandwidth: four keep "
+         "ahead of the scan's own thread, eight slow its copies down; "
+         "raise it where the pool hides storage latency. "
          "(spark.rapids.sql.multiThreadedRead.numThreads)") \
-    .check(_positive).integer(8)
+    .check(_positive).integer(4)
 
 MAX_READER_BATCH_SIZE_ROWS = conf("srt.sql.reader.batchSizeRows") \
     .doc("Soft cap on rows per scan batch. "

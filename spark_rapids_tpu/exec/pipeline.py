@@ -50,7 +50,9 @@ runtime threads already hold every arena a NEW thread is dealt the
 next arena of the ring, so a scan run on a fresh thread each query
 walks its buffers through a hundred arenas (cold memory every time,
 a quarter slower end to end) while the same scan on the thread it had
-before finds its heap where it left it.
+before finds its heap where it left it. ``RunAhead`` borrows from the
+same pool: a multi-file scan's decode threads are kept threads too, by
+the table and their index among its workers.
 
 The SelfTimer disjointness invariant (obs: exclusive op-times on one
 thread never overlap) holds because each thread pulls through its own
@@ -63,6 +65,7 @@ sum(op-time) > wall as pipeline overlap, not double-charging.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 import time
 import weakref
@@ -74,8 +77,8 @@ from ..conf import (PIPELINE_DEPTH, PIPELINE_ENABLED, PIPELINE_MAX_BYTES,
 from ..obs.trace import annotate
 from .base import ExecContext, Metric, Schema, TpuExec
 
-__all__ = ["PrefetchIterator", "PrefetchExec", "prefetch_batches",
-           "pipeline_enabled", "prefetch_buffer_bytes",
+__all__ = ["PrefetchIterator", "PrefetchExec", "RunAhead",
+           "prefetch_batches", "pipeline_enabled", "prefetch_buffer_bytes",
            "prefetch_thread_leaks", "close_live_iterators"]
 
 # Live iterators, for the resource sampler's prefetch-occupancy gauge.
@@ -92,6 +95,30 @@ _THREAD_LEAKS = [0]
 
 def prefetch_thread_leaks() -> int:
     return _THREAD_LEAKS[0]
+
+
+def _await_parked(parked: threading.Event, thread_name: str,
+                  join_timeout: float, queued: int,
+                  leak_metric: Optional[Metric] = None) -> None:
+    """Wait until a borrowed producer has left its job and parked. One
+    that outlives the timeout is wedged inside its source (hung socket,
+    stuck decode): it leaks as a daemon thread (the pool starts another
+    when it needs one), and that must fail loudly, not silently: a
+    warning event, the process-wide ``prefetch_thread_leaks`` counter
+    and the node's ``prefetchThreadLeaks`` metric all record it, so
+    chaos runs and the serving tier's health checks trip."""
+    if parked.wait(timeout=join_timeout):
+        return
+    _THREAD_LEAKS[0] += 1
+    if leak_metric is not None:
+        leak_metric.add(1)
+    from ..obs import events as _events
+    _events.emit("PrefetchThreadLeak", thread=thread_name,
+                 join_timeout_s=join_timeout, queued=queued)
+    import logging
+    logging.getLogger("spark_rapids_tpu.exec").warning(
+        "prefetch producer %s leaked: still in its source %.0fs "
+        "after close()", thread_name, join_timeout)
 
 
 #: a parked producer's thread name; while it runs an iterator it is
@@ -448,33 +475,16 @@ class PrefetchIterator:
 
     def close(self, join_timeout: float = 30.0) -> None:
         """Stop the producer, wait until it has left the source and
-        parked, and discard queued items.
-
-        A producer that outlives the join timeout is wedged inside its
-        source (hung socket, stuck decode) — it leaks as a daemon
-        thread (the pool starts another when it needs one). That must fail loudly, not silently: a warning event,
-        the process-wide ``prefetch_thread_leaks`` counter, and the
-        node's ``prefetchThreadLeaks`` metric all record it so chaos
-        runs and the serving tier's health checks trip."""
+        parked (a wedged one is counted as a leak: ``_await_parked``),
+        and discard queued items."""
         if self._closed:
             return
         self._closed = True
         with self._cv:
             self._stopped = True
             self._cv.notify_all()
-        if not self._parked.wait(timeout=join_timeout):
-            _THREAD_LEAKS[0] += 1
-            if self._leak_metric is not None:
-                self._leak_metric.add(1)
-            from ..obs import events as _events
-            _events.emit("PrefetchThreadLeak",
-                         thread=self._thread_name,
-                         join_timeout_s=join_timeout,
-                         queued=len(self._buf))
-            import logging
-            logging.getLogger("spark_rapids_tpu.exec").warning(
-                "prefetch producer %s leaked: still in its source %.0fs "
-                "after close()", self._thread_name, join_timeout)
+        _await_parked(self._parked, self._thread_name, join_timeout,
+                      len(self._buf), self._leak_metric)
         with self._cv:
             while self._buf:
                 item, _ = self._buf.popleft()
@@ -488,6 +498,136 @@ class PrefetchIterator:
     def __exit__(self, *exc) -> bool:
         self.close()
         return False
+
+
+class RunAhead:
+    """Ordered results of ``tasks``, computed ahead of the consumer on
+    kept threads.
+
+    ``tasks`` is a list of ``(cost, make)``: ``make()`` returns a
+    generator, ``cost`` is an estimate of the bytes its items will hold.
+    Up to ``threads`` producers borrowed from the pool drain tasks into
+    lists, in submission order, while the cost of what is queued, running
+    or done and not yet taken stays within ``max_bytes``. Iterating
+    yields ``(task index, item)`` in submission order. A task that is
+    over the budget alone never goes to the pool: the consumer runs its
+    generator itself, item by item, when it gets there, so such a task
+    is never materialised whole.
+
+    A task's error is re-raised on the consuming thread (the original
+    exception object) after the items the task produced before it. The
+    borrowed threads carry ``conf``, ``query`` and, when a fault plan is
+    armed, the fault scope of the thread that built the stream. Worker
+    ``k`` names the affinity ``<affinity>#k``, so a table is decoded by
+    the threads, and into the heaps, that decoded it last. ``close()``
+    drops what is queued, waits for what runs, and leaves every
+    borrowed thread parked. ``pooled`` counts the tasks taken from the
+    pool, ``ahead`` those that were done when the consumer asked.
+    """
+
+    def __init__(self, tasks: "list[tuple[int, Callable[[], Iterator]]]",
+                 threads: int, max_bytes: int,
+                 conf: Optional[SrtConf] = None, query=None,
+                 name: str = "ahead", affinity: Optional[str] = None):
+        from ..robustness import faults
+        self._tasks = tasks
+        self._max_bytes = max(int(max_bytes), 0)
+        self._conf = conf
+        self._query = query
+        self._fault_tag = faults.current_op() if faults.armed() else ""
+        self._cv = threading.Condition()
+        self._next = 0  # first task not admitted yet
+        self._queue: deque = deque()  # admitted, not yet claimed
+        self._done: dict = {}  # task index -> (items, error)
+        self._bytes = 0
+        self._bytes_peak = 0
+        self._stopped = False
+        self.pooled = 0
+        self.ahead = 0
+        self._thread_name = f"srt-prefetch-{name}"
+        n = min(max(int(threads), 1), sum(
+            1 for cost, _ in tasks if cost <= self._max_bytes))
+        with self._cv:
+            self._admit()
+        self._parked = [
+            _PRODUCERS.start(self._work, f"{self._thread_name}-{k}",
+                             f"{affinity or name}#{k}")
+            for k in range(n)]
+
+    def _admit(self) -> None:
+        """Hand the pool every next task the budget has room for; stop
+        at one the consumer has to run itself. Callers hold ``_cv``."""
+        while self._next < len(self._tasks) and not self._stopped:
+            cost = self._tasks[self._next][0]
+            if self._bytes + cost > self._max_bytes:
+                break
+            self._queue.append(self._next)
+            self._bytes += cost
+            self._bytes_peak = max(self._bytes_peak, self._bytes)
+            self._next += 1
+        self._cv.notify_all()
+
+    def _work(self) -> None:
+        from ..robustness import faults
+        from ..robustness.admission import set_current_query
+        set_active_conf(self._conf)
+        set_current_query(self._query)
+        scope = (faults.op_scope(self._fault_tag) if self._fault_tag
+                 else contextlib.nullcontext())
+        with scope:
+            while True:
+                with self._cv:
+                    while not (self._queue or self._stopped
+                               or self._next >= len(self._tasks)):
+                        self._cv.wait()
+                    if self._stopped or not self._queue:
+                        return
+                    i = self._queue.popleft()
+                    make = self._tasks[i][1]
+                items, error = [], None
+                try:
+                    items.extend(make())
+                except BaseException as e:  # noqa: BLE001 — relayed
+                    error = e
+                with self._cv:
+                    if not self._stopped:
+                        self._done[i] = (items, error)
+                    self._cv.notify_all()
+
+    def __iter__(self) -> Iterator:
+        for i, (cost, make) in enumerate(self._tasks):
+            if cost > self._max_bytes:
+                with contextlib.closing(make()) as items:
+                    for item in items:
+                        yield i, item
+                with self._cv:
+                    self._next = i + 1
+                    self._admit()
+                continue
+            with self._cv:
+                self.pooled += 1
+                if i in self._done:
+                    self.ahead += 1
+                while i not in self._done:
+                    self._cv.wait()
+                items, error = self._done.pop(i)
+                self._bytes -= cost
+                self._admit()
+            for item in items:
+                yield i, item
+            if error is not None:
+                raise error
+
+    def close(self, join_timeout: float = 30.0) -> None:
+        with self._cv:
+            self._stopped = True
+            queued = len(self._queue)
+            self._queue.clear()
+            self._done.clear()
+            self._cv.notify_all()
+        for k, parked in enumerate(self._parked):
+            _await_parked(parked, f"{self._thread_name}-{k}", join_timeout,
+                          queued)
 
 
 def pipeline_enabled(ctx: ExecContext, node=None) -> bool:

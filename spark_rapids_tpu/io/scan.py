@@ -6,12 +6,17 @@ re-architected for TPU: host threads decode (pyarrow) without holding
 the device semaphore; decoded chunks upload to HBM as capacity-bucketed
 ColumnarBatches. The reference's three reader types are kept:
 
-- PERFILE       (GpuParquetPartitionReaderFactory): one file at a time
+- PERFILE       (GpuParquetPartitionReaderFactory): one file at a time,
+                decoded by the thread that uploads
 - COALESCING    (MultiFileParquetPartitionReader:1862): many small
                 files concatenated into target-size batches before upload
-- MULTITHREADED (MultiFileCloudParquetPartitionReader:2057): a thread
-                pool reads+decodes files concurrently, results flow in
-                submission order
+- MULTITHREADED (MultiFileCloudParquetPartitionReader:2057): a batch a
+                file (or a slice of one), never concatenated
+
+COALESCING and MULTITHREADED read a scan of several files through one
+stream (``FileSourceScanExec._decoded_files``): kept pool threads decode
+files ahead of the scan's own thread, in file order, and that thread only
+assembles batches and uploads them.
 
 Predicate pushdown mirrors the reference's ParquetFilters handling:
 supported conjuncts translate to pyarrow dataset filters (row-group /
@@ -21,7 +26,6 @@ purely an I/O reduction, never a semantics change.
 
 from __future__ import annotations
 
-import concurrent.futures as cf
 import errno
 import glob as globlib
 import logging
@@ -35,12 +39,15 @@ import pyarrow as pa
 
 from ..columnar import dtypes as dt
 from ..columnar.vector import ColumnarBatch
-from ..conf import (MAX_READER_BATCH_SIZE_ROWS, READER_THREADS, READER_TYPE)
+from ..conf import (MAX_READER_BATCH_SIZE_ROWS, PIPELINE_MAX_BYTES,
+                    READER_THREADS, READER_TYPE)
 from ..exec.base import ExecContext, Metric, Schema, TpuExec
+from ..exec.pipeline import RunAhead
 from ..expr import core as E
 from ..expr import predicates as P
 from ..obs.trace import annotate
-from ..plan.host_table import HostTable, concat_tables, table_to_batch
+from ..plan.host_table import (HostTable, concat_tables, empty_like,
+                               table_to_batch)
 from ..plan.logical import LogicalPlan
 from ..robustness.faults import fault_point
 from ..robustness.integrity import DataCorruption
@@ -473,7 +480,7 @@ def _timed_decode(tables: Iterator[HostTable], decode_time
     """Charge the time spent INSIDE ``tables`` (read + decode +
     conform of each table it yields, not the time its consumer holds
     the table) to the scan's ``scanDecodeTime``. It runs on whichever
-    thread decodes — the reader pool's in the MULTITHREADED reader — so
+    thread decodes — the reader pool's for a scan of several files — so
     the sum over threads may exceed the wall. A counter and no profiler
     range, on purpose: pool threads are busy most of the time, and a
     short range there would be taken for the cause of device idle gaps
@@ -738,8 +745,7 @@ def read_file_to_tables(path: str, fmt: str, schema: Schema,
                         max_rows: int, conf=None,
                         partition_values: Optional[dict] = None
                         ) -> List[HostTable]:
-    """Materialized form of iter_file_tables — the thread-pool reader
-    needs whole-file futures."""
+    """Materialized form of iter_file_tables."""
     return list(iter_file_tables(path, fmt, schema, options,
                                  arrow_filter, max_rows, conf,
                                  partition_values))
@@ -803,6 +809,35 @@ def _conform(table: "pa.Table", schema: Schema) -> "pa.Table":
     return table
 
 
+def _decoded_bytes(path: str, fmt: str, schema: Schema) -> int:
+    """What a file's tables will hold once decoded, for the reader
+    pool's byte budget: rows (from the footer, where the format has one)
+    times the schema's fixed widths, plus the file's uncompressed size
+    when a column is of variable width. Formats without a footer count
+    their size on disk. A file that cannot be sized counts 0: decoding
+    it will say what is wrong with it, in file order."""
+    try:
+        size = os.path.getsize(path)
+        if fmt == "parquet":
+            import pyarrow.parquet as pq
+            md = pq.read_metadata(path)
+            rows = md.num_rows
+            size = sum(md.row_group(i).total_byte_size
+                       for i in range(md.num_row_groups))
+        elif fmt == "orc":
+            import pyarrow.orc as orc
+            rows = orc.ORCFile(path).nrows
+        else:
+            return size
+    except _CORRUPT_ERRORS:
+        return 0
+    # the host representation of each column: fixed width, or objects
+    kinds = [c.values.dtype for c in empty_like(schema).columns]
+    fixed = sum(k.itemsize + 1 for k in kinds if k != object)
+    var = sum(k == object for k in kinds)
+    return rows * fixed + (size + rows * 9 * var if var else 0)
+
+
 class FileSourceScanExec(TpuExec):
     """Leaf exec: host-decode files, upload to device.
 
@@ -847,9 +882,10 @@ class FileSourceScanExec(TpuExec):
         # decode-path visibility: format branches bump these counters
         # (thread-safe enough: int += under the GIL) and do_execute
         # flushes them into scan metrics
-        self._decode_stats = {"native_files": 0, "host_files": 0,
-                              "host_columns": 0}
-        options["_decode_stats"] = self._decode_stats
+        stats = self._decode_stats = {
+            "native_files": 0, "host_files": 0, "host_columns": 0,
+            "pooled_files": 0, "ahead_files": 0}
+        options["_decode_stats"] = stats
         # read + decode + conform of every file, timed where it runs
         # (iter_file_tables, on the pool's threads): thread time
         options["_decode_time"] = ctx.metrics_for(self.exec_id).setdefault(
@@ -878,46 +914,54 @@ class FileSourceScanExec(TpuExec):
 
         def pv(p):
             return self.scan.partition_values_for(p)
-        if reader == "MULTITHREADED" and len(scan_paths) > 1:
-            threads = conf.get(READER_THREADS)
-            with cf.ThreadPoolExecutor(max_workers=threads) as pool:
-                # bounded in-flight window (2x threads) so decoded tables
-                # don't accumulate unboundedly ahead of the consumer
-                from collections import deque
-                window = threads * 2
-                pending = deque()
-                paths = iter(scan_paths)
-                for p in paths:
-                    pending.append((p, pool.submit(read_file_to_tables,
-                                                   p, *args, pv(p))))
-                    if len(pending) >= window:
-                        break
-                while pending:
-                    fp, fut = pending.popleft()
-                    for t in fut.result():  # submission order
-                        yield fp, t
-                    nxt = next(paths, None)
-                    if nxt is not None:
-                        pending.append((nxt,
-                                        pool.submit(read_file_to_tables,
-                                                    nxt, *args,
-                                                    pv(nxt))))
-        elif reader == "COALESCING" and len(scan_paths) > 1:
-            pending: List[HostTable] = []
-            rows = 0
-            for p in scan_paths:
-                for t in iter_file_tables(p, *args, pv(p)):
+        if reader in ("COALESCING", "MULTITHREADED") and len(scan_paths) > 1:
+            files = self._decoded_files(ctx, scan_paths, args, pv)
+            try:
+                if reader == "MULTITHREADED":
+                    for i, t in files:
+                        yield scan_paths[i], t
+                    return
+                pending: List[HostTable] = []
+                rows = 0
+                for _, t in files:
                     pending.append(t)
                     rows += t.num_rows
                     if rows >= max_rows:
                         yield None, concat_tables(pending)
                         pending, rows = [], 0
-            if pending:
-                yield None, concat_tables(pending)
+                if pending:
+                    yield None, concat_tables(pending)
+            finally:
+                files.close()
+                stats["pooled_files"] += files.pooled
+                stats["ahead_files"] += files.ahead
         else:
             for p in scan_paths:
                 for t in iter_file_tables(p, *args, pv(p)):
                     yield p, t
+
+    def _decoded_files(self, ctx: ExecContext, scan_paths: List[str],
+                       args: tuple, pv) -> RunAhead:
+        """The tables of ``scan_paths`` in file order, as ``(index of the
+        file, HostTable)``, decoded ahead of this thread on the kept
+        reader threads (exec/pipeline.py ``RunAhead``): at most
+        srt.sql.multiThreadedRead.numThreads of them, no more than the
+        host has cores, with no more decoded and not yet taken than
+        srt.exec.pipeline.maxBytesInFlight, each file sized from its
+        footer. A file over that budget alone streams through this
+        thread row group by row group, as every file of a PERFILE scan
+        does, so it never materialises whole."""
+        conf = ctx.conf
+
+        def task(p):
+            return (_decoded_bytes(p, self.scan.fmt, self._schema),
+                    lambda: iter_file_tables(p, *args, pv(p)))
+        return RunAhead(
+            [task(p) for p in scan_paths],
+            threads=min(conf.get(READER_THREADS), os.cpu_count() or 1),
+            max_bytes=conf.get(PIPELINE_MAX_BYTES), conf=conf,
+            query=ctx.query, name=f"decode-{self.exec_id}",
+            affinity=f"decode:{scan_paths[0]}")
 
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         m = ctx.metrics_for(self.exec_id)
@@ -930,44 +974,52 @@ class FileSourceScanExec(TpuExec):
         empty = True
         sizes = {}
         tables = self._host_tables(ctx)
-        while True:
-            # this thread stands waiting for the next decoded table: on
-            # the reader pool's future, or decoding inline where the
-            # reader has no pool
-            t0 = time.perf_counter_ns()
-            with annotate("scan.wait"):
-                item = next(tables, None)
-            t1 = time.perf_counter_ns()
-            scan_wait.add(t1 - t0)
-            if item is None:
-                break
-            path, table = item
-            if table.num_rows == 0 and not empty:
-                continue
-            empty = False
-            with annotate("scan.upload"), ctx.semaphore:
-                # the semaphore is held only for the upload
-                batch = table_to_batch(table)
-            scan_time.add(time.perf_counter_ns() - t1)
-            # file context for input_file_name()/blocks: whole-file
-            # reads report (0, file_size); coalesced multi-file batches
-            # have no single file (empty name, Spark contract)
-            if path is not None:
-                if path not in sizes:
-                    try:
-                        sizes[path] = os.path.getsize(path)
-                    except OSError:
-                        sizes[path] = 0
-                set_input_file(path, 0, sizes[path])
-            else:
-                set_input_file(None)
-            yield batch
+        try:
+            while True:
+                # this thread stands waiting for the next decoded table:
+                # on the reader pool's next file (and the concatenation
+                # of a batch's files), or decoding inline where the scan
+                # has no pool (one file, PERFILE, a file over the budget)
+                t0 = time.perf_counter_ns()
+                with annotate("scan.wait"):
+                    item = next(tables, None)
+                t1 = time.perf_counter_ns()
+                scan_wait.add(t1 - t0)
+                if item is None:
+                    break
+                path, table = item
+                if table.num_rows == 0 and not empty:
+                    continue
+                empty = False
+                with annotate("scan.upload"), ctx.semaphore:
+                    # the semaphore is held only for the upload
+                    batch = table_to_batch(table)
+                scan_time.add(time.perf_counter_ns() - t1)
+                # file context for input_file_name()/blocks: whole-file
+                # reads report (0, file_size); coalesced multi-file
+                # batches have no single file (empty name, Spark contract)
+                if path is not None:
+                    if path not in sizes:
+                        try:
+                            sizes[path] = os.path.getsize(path)
+                        except OSError:
+                            sizes[path] = 0
+                    set_input_file(path, 0, sizes[path])
+                else:
+                    set_input_file(None)
+                yield batch
+        finally:
+            # an abandoned scan (LocalLimit, error unwind) parks its
+            # reader threads here, on the thread that borrowed them
+            tables.close()
         stats = getattr(self, "_decode_stats", None)
         if stats and (stats["native_files"] or stats["host_files"]):
             for key, mname in (("native_files", "scanNativeDecodedFiles"),
                                ("host_files", "scanHostDecodedFiles"),
                                ("host_columns",
-                                "scanHostDecodedColumns")):
+                                "scanHostDecodedColumns"),
+                               ("pooled_files", "scanPooledFiles"),
+                               ("ahead_files", "scanDecodeAheadFiles")):
                 if stats[key]:
                     m.setdefault(mname, Metric(mname, Metric.MODERATE)) \
                         .add(stats[key])

@@ -422,6 +422,8 @@ class TpuSession:
 #: operator Metric (summed over the plan, whatever srt.metrics.level
 #: shows) -> key of the query record's ``phases``
 _PHASE_METRICS = {"scanDecodeTime": "scan_decode_ns",
+                  "scanPooledFiles": "scan_pooled_files",
+                  "scanDecodeAheadFiles": "scan_ahead_files",
                   "scanWaitTime": "scan_wait_ns",
                   "scanTime": "scan_upload_ns",
                   "prefetchWaitTime": "prefetch_wait_ns"}

@@ -4,6 +4,7 @@ writers, partitioned writes, round trips (SURVEY §2.6 equivalents)."""
 import datetime
 import decimal
 import os
+import time
 
 import numpy as np
 import pytest
@@ -282,3 +283,214 @@ def test_avro_unknown_logical_type_raises(tmp_path):
                                "precision": 10, "scale": 2}}]}
     with pytest.raises(AvroUnsupported):
         schema_from_avro(sch)
+
+
+# ---------------------------------------------------------------------------
+# multi-file scans decode on the kept reader threads, ahead of the scan's
+# own thread (FileSourceScanExec._decoded_files, exec/pipeline.py RunAhead)
+# ---------------------------------------------------------------------------
+
+#: a budget no file with a row fits: they decode inline, on the scan's thread
+INLINE = {"srt.exec.pipeline.maxBytesInFlight": "1"}
+UNEVEN_ROWS = (700, 0, 130, 1024, 5, 333)
+
+
+@pytest.fixture(scope="module")
+def uneven_dir(tmp_path_factory):
+    """Six parquet files of unequal row counts, one of them empty."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    d = tmp_path_factory.mktemp("uneven")
+    start = 0
+    for i, n in enumerate(UNEVEN_ROWS):
+        pq.write_table(
+            pa.table({"a": np.arange(start, start + n, dtype=np.int64),
+                      "b": np.arange(start, start + n) / 7.0}),
+            str(d / f"part-{i:05d}.parquet"))
+        start += n
+    return str(d)
+
+
+def _host_tables(path, settings, reader="COALESCING", doomed=None):
+    """What the scan's thread is handed, batch by batch: (file name or
+    None, column a, column b), the decode counters, and the error that
+    ended the scan. ``doomed`` is removed between planning and reading."""
+    from spark_rapids_tpu.exec.base import ExecContext
+    from spark_rapids_tpu.io.scan import FileScan, FileSourceScanExec
+    conf = SrtConf({READER_TYPE.key: reader,
+                    "srt.sql.reader.batchSizeRows": "512", **settings})
+    node = FileSourceScanExec(FileScan(path, "parquet"))
+    if doomed:
+        os.remove(doomed)
+    out, error = [], None
+    try:
+        for p, t in node._host_tables(ExecContext(conf)):
+            out.append((p and os.path.basename(p),
+                        t.column("a").values.tolist(),
+                        t.column("b").values.tolist()))
+    except Exception as e:
+        error = e
+    return out, node._decode_stats, error
+
+
+def _rows(batches):
+    return [v for _, a, _ in batches for v in a]
+
+
+@pytest.mark.parametrize("reader", ["COALESCING", "MULTITHREADED"])
+def test_pooled_scan_yields_the_inline_scans_batches(uneven_dir, reader):
+    pooled, stats, _ = _host_tables(uneven_dir, {}, reader)
+    inline, inline_stats, _ = _host_tables(uneven_dir, INLINE, reader)
+    assert pooled == inline  # same files in the same batch, same order
+    assert _rows(pooled) == list(range(sum(UNEVEN_ROWS)))
+    assert stats["pooled_files"] == len(UNEVEN_ROWS)
+    assert 0 <= stats["ahead_files"] <= stats["pooled_files"]
+    # the empty file sizes 0 and fits any budget
+    assert inline_stats["pooled_files"] == UNEVEN_ROWS.count(0)
+    if reader == "MULTITHREADED":
+        assert pooled[0][0] == "part-00000.parquet"
+    else:
+        assert {p for p, _, _ in pooled} == {None}
+
+
+@pytest.mark.parametrize("reader", ["COALESCING", "MULTITHREADED"])
+@pytest.mark.parametrize("lenient", [False, True])
+def test_pooled_scan_corrupt_file_k_of_n(uneven_dir, tmp_path, reader,
+                                         lenient):
+    """File 3 of 6 is garbage: what the files before it gave arrives as
+    it does from the inline scan, then the decoder's own exception with
+    the path in its message; with ignoreCorruptFiles the file is
+    skipped."""
+    import shutil
+    d = tmp_path / "mix"
+    shutil.copytree(uneven_dir, d)
+    victim = d / "part-00003.parquet"
+    victim.write_bytes(b"PAR1 this is not a parquet file PAR1")
+    before = sum(UNEVEN_ROWS[:3])
+    settings = {"srt.sql.ignoreCorruptFiles": lenient,
+                "srt.sql.reader.batchSizeRows": "100"}
+    got, stats, error = _host_tables(str(d), settings, reader)
+    inline, _, inline_error = _host_tables(str(d), {**settings, **INLINE},
+                                           reader)
+    assert got == inline and type(error) is type(inline_error)
+    if lenient:
+        assert error is None and _rows(got) == [
+            v for v in range(sum(UNEVEN_ROWS))
+            if not before <= v < before + UNEVEN_ROWS[3]]
+        assert stats["pooled_files"] == len(UNEVEN_ROWS)
+        return
+    assert str(victim) in str(error) and str(error) == str(inline_error)
+    # a batch a table: every row in front of the file; the coalescing
+    # reader loses the batch it was filling, as it does inline
+    assert _rows(got) == list(range(
+        before if reader == "MULTITHREADED" else before - before % 100))
+
+
+@pytest.mark.parametrize("lenient", [False, True])
+def test_pooled_scan_missing_file(uneven_dir, tmp_path, lenient):
+    import shutil
+    d = tmp_path / "vanish"
+    shutil.copytree(uneven_dir, d)
+    gone = str(d / "part-00002.parquet")
+    got, _, error = _host_tables(
+        str(d), {"srt.sql.ignoreMissingFiles": lenient}, doomed=gone)
+    if lenient:
+        lo = sum(UNEVEN_ROWS[:2])
+        assert error is None and _rows(got) == [
+            v for v in range(sum(UNEVEN_ROWS))
+            if not lo <= v < lo + UNEVEN_ROWS[2]]
+    else:
+        assert isinstance(error, FileNotFoundError) and gone in str(error)
+
+
+def test_scan_file_fault_fires_on_a_reader_thread_in_scope(uneven_dir,
+                                                          monkeypatch):
+    """An armed ``scan.file`` clause matches the file it names wherever
+    the file decodes, and the reader thread carries the scan operator's
+    fault scope as the scan's own thread does."""
+    import threading
+
+    from spark_rapids_tpu.io import scan as scan_mod
+    from spark_rapids_tpu.robustness import faults
+    from spark_rapids_tpu.robustness.integrity import DataCorruption
+    hits = []
+    real = scan_mod.fault_point
+
+    def spy(site, detail=None):
+        hits.append((threading.current_thread().name, faults.current_op(),
+                     os.path.basename(detail)))
+        return real(site, detail=detail)
+    monkeypatch.setattr(scan_mod, "fault_point", spy)
+    plan = faults.arm_fault_plan(
+        "seed=3|scan.file:corrupt@1~part-00004")
+    try:
+        from spark_rapids_tpu.exec.base import ExecContext
+        from spark_rapids_tpu.io.scan import FileScan, FileSourceScanExec
+        node = FileSourceScanExec(FileScan(uneven_dir, "parquet"))
+        with pytest.raises(DataCorruption):
+            list(node.execute(ExecContext(SrtConf({}))))
+        [fired] = plan.fired("scan.file")
+        assert fired.detail.endswith("part-00004.parquet")
+    finally:
+        faults.disarm_fault_plan()
+    assert hits and all(
+        name.startswith("srt-prefetch-decode-FileSourceScanExec")
+        and op.startswith("FileSourceScanExec") for name, op, _ in hits)
+    assert "part-00004.parquet" in [f for _, _, f in hits]
+
+
+def test_limit_mid_scan_parks_the_reader_threads(uneven_dir):
+    import threading
+
+    from spark_rapids_tpu.exec import pipeline
+    before = pipeline.prefetch_thread_leaks()
+    s = TpuSession(SrtConf({"srt.sql.reader.batchSizeRows": "64"}))
+    assert len(s.read.parquet(uneven_dir).limit(3).collect()) == 3
+    assert pipeline.prefetch_thread_leaks() == before
+    assert not [t for t in threading.enumerate()
+                if t.name.startswith("srt-prefetch") and t.is_alive()]
+
+
+def test_two_queries_over_one_table_share_their_reader_threads(
+        uneven_dir, monkeypatch):
+    import threading
+
+    from spark_rapids_tpu.io import scan as scan_mod
+    real = scan_mod.iter_file_tables
+    idents = []
+
+    def spy(path, *args):
+        idents[-1].add(threading.get_ident())
+        time.sleep(0.005)  # so that no one thread takes every file
+        return real(path, *args)
+    monkeypatch.setattr(scan_mod, "iter_file_tables", spy)
+    s = TpuSession()
+    for query in (lambda df: df.count(),
+                  lambda df: df.filter(col("a") > 5).collect(),
+                  lambda df: df.count()):
+        idents.append(set())
+        query(s.read.parquet(uneven_dir))
+    me = threading.get_ident()
+    assert len(idents[0]) > 1 and me not in idents[0]
+    assert idents[0] >= idents[1] and idents[0] >= idents[2]
+
+
+def test_scan_counts_pooled_and_decoded_ahead_files(uneven_dir, tmp_path):
+    def counters(df):
+        df.collect()
+        totals = {}
+        for per_exec in df.session._last_execution["ctx"].metrics.values():
+            for name, metric in per_exec.items():
+                totals[name] = totals.get(name, 0) + metric.value
+        phases = df.session._last_execution["record"]["phases"]
+        return (totals.get("scanPooledFiles", 0),
+                totals.get("scanDecodeAheadFiles", 0),
+                phases["scan_pooled_files"], phases["scan_ahead_files"])
+    s = TpuSession()
+    pooled, ahead, p_pooled, p_ahead = counters(s.read.parquet(uneven_dir))
+    assert pooled == p_pooled == len(UNEVEN_ROWS)
+    assert 0 <= ahead == p_ahead <= pooled
+    one = str(tmp_path / "one")
+    s.create_dataframe({"a": [1, 2, 3]}).write.parquet(one)
+    assert len(os.listdir(one)) == 1
+    assert counters(s.read.parquet(one)) == (0, 0, 0, 0)

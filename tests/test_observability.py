@@ -509,8 +509,8 @@ def test_program_names_are_registry_labels():
 
 
 PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
-              "scan_decode_ns", "scan_wait_ns", "scan_upload_ns",
-              "prefetch_wait_ns", "dispatch_ns", "launches",
+              "scan_decode_ns", "scan_pooled_files", "scan_ahead_files",
+              "scan_wait_ns", "scan_upload_ns", "prefetch_wait_ns", "dispatch_ns", "launches",
               # the join execs' counters (exec/join.py JOIN_COUNTERS)
               "join_build_ns", "lookup_join_batches", "hash_join_batches",
               "join_capacity_relaunches", "join_readbacks"}

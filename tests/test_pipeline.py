@@ -508,3 +508,157 @@ def test_pool_keeps_a_bounded_number_of_parked_threads(monkeypatch):
             len(pipeline._PRODUCERS._parked) > 4:
         time.sleep(0.01)
     assert len(pipeline._PRODUCERS._parked) <= 4
+
+
+# ---------------------------------------------------------------------------
+# RunAhead: ordered results computed ahead on kept threads
+# ---------------------------------------------------------------------------
+
+def _parked_idents():
+    return {t.ident for t in threading.enumerate()
+            if t.name == pipeline._PARKED}
+
+
+def _gen_task(cost, items, seen=None, delay=0.0, error=None):
+    def make():
+        if seen is not None:
+            seen.append(threading.get_ident())
+        for item in items:
+            time.sleep(delay)
+            yield item
+        if error is not None:
+            raise error
+    return cost, make
+
+
+def test_run_ahead_results_keep_submission_order():
+    # later tasks finish first: the first sleeps longest
+    tasks = [_gen_task(1, [(i, j) for j in range(3)],
+                       delay=0.004 * (8 - i) / 8) for i in range(8)]
+    ahead = pipeline.RunAhead(tasks, threads=4, max_bytes=100)
+    try:
+        got = list(ahead)
+    finally:
+        ahead.close()
+    assert got == [(i, (i, j)) for i in range(8) for j in range(3)]
+    assert ahead.pooled == 8 and 0 < ahead.ahead <= 8
+    assert not [t for t in _prefetch_threads() if t.is_alive()]
+
+
+def test_run_ahead_error_surfaces_in_order_with_its_partial_items():
+    boom = DataCorruption("file 2 is garbage")
+    tasks = [_gen_task(1, ["a0", "a1"], delay=0.01), _gen_task(1, ["b0"]),
+             _gen_task(1, ["c0"], error=boom), _gen_task(1, ["d0"])]
+    leaks = pipeline.prefetch_thread_leaks()
+    ahead = pipeline.RunAhead(tasks, threads=4, max_bytes=100)
+    got = []
+    try:
+        with pytest.raises(DataCorruption) as ei:
+            for _, item in ahead:
+                got.append(item)
+    finally:
+        ahead.close()
+    assert ei.value is boom
+    assert got == ["a0", "a1", "b0", "c0"]
+    assert pipeline.prefetch_thread_leaks() == leaks
+
+
+def test_run_ahead_budget_bounds_what_is_decoded_ahead():
+    """Cost admitted and not yet taken never passes the budget, and a
+    task over the budget alone runs on the consumer, item by item."""
+    me = threading.get_ident()
+    seen, produced = [], []
+
+    def big():
+        seen.append(threading.get_ident())
+        for j in range(3):
+            produced.append(j)
+            yield f"big{j}"
+    tasks = [_gen_task(40, [f"s{i}"], seen) for i in range(6)]
+    tasks.insert(3, (1000, big))
+    ahead = pipeline.RunAhead(tasks, threads=4, max_bytes=100)
+    try:
+        it = iter(ahead)
+        got = [next(it) for _ in range(4)]
+        # the over-budget task streams: one item made, one item taken
+        assert got[-1] == (3, "big0") and produced == [0]
+        assert seen.count(me) == 1 and len(seen) == 4
+        got += list(it)
+    finally:
+        ahead.close()
+    assert [item for _, item in got] == [
+        "s0", "s1", "s2", "big0", "big1", "big2", "s3", "s4", "s5"]
+    assert ahead._bytes_peak == 80 and ahead.pooled == 6
+    assert ahead.ahead <= ahead.pooled
+
+
+def test_run_ahead_close_mid_stream_parks_every_thread():
+    gate = threading.Event()
+
+    def slow():
+        gate.wait(10)
+        yield "late"
+    tasks = [_gen_task(1, ["first"])] + [(1, slow) for _ in range(9)]
+    before = pipeline.prefetch_thread_leaks()
+    ahead = pipeline.RunAhead(tasks, threads=3, max_bytes=100)
+    assert next(iter(ahead)) == (0, "first")
+    threading.Timer(0.05, gate.set).start()
+    ahead.close()  # drops the queue, waits for the three that run
+    assert all(p.is_set() for p in ahead._parked)
+    assert not ahead._queue and not ahead._done
+    assert pipeline.prefetch_thread_leaks() == before
+    assert not [t for t in _prefetch_threads() if t.is_alive()]
+
+
+def test_run_ahead_same_table_same_threads_conf_and_fault_scope():
+    from spark_rapids_tpu.conf import active_conf
+    from spark_rapids_tpu.robustness import faults
+    mine = SrtConf({"srt.exec.pipeline.depth": "7"})
+    arm_fault_plan("seed=1|memory.reserve:retry_oom@999")
+
+    def run(conf):
+        seen = []
+
+        def make():
+            seen.append((threading.get_ident(), active_conf(),
+                         faults.current_op()))
+            time.sleep(0.01)  # every worker takes a task
+            yield 1
+        with faults.op_scope("FileSourceScanExec#4"):
+            ahead = pipeline.RunAhead([(1, make)] * 3, threads=3,
+                                      max_bytes=100, conf=conf,
+                                      affinity="decode:/tmp/t1/part-0")
+        try:
+            assert [item for _, item in ahead] == [1, 1, 1]
+        finally:
+            ahead.close()
+        return seen
+    first = run(mine)
+    assert {c for _, c, _ in first} == {mine}
+    assert {op for _, _, op in first} == {"FileSourceScanExec#4"}
+    idents = {i for i, _, _ in first}
+    assert len(idents) == 3 and idents <= _parked_idents()
+    again = run(None)
+    assert {i for i, _, _ in again} == idents
+    assert mine not in {c for _, c, _ in again}
+
+
+def test_run_ahead_stress_more_workers_than_cores():
+    import sys
+    n = 400
+    tasks = [_gen_task(3, [i]) for i in range(n)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        ahead = pipeline.RunAhead(tasks, threads=4 * (os.cpu_count() or 2),
+                                  max_bytes=50)
+        try:
+            got = list(ahead)
+        finally:
+            ahead.close()
+    finally:
+        sys.setswitchinterval(interval)
+    assert got == [(i, i) for i in range(n)]
+    assert ahead.pooled == n and ahead._bytes == 0
+    assert ahead._bytes_peak <= 50
+    assert all(p.is_set() for p in ahead._parked)
