@@ -77,11 +77,11 @@ def emit(**fields) -> None:
 # ---------------------------------------------------------------------------
 
 class CompileCounter:
-    """Counts what the compiler did between two reads: programs the
-    engine's own ledger compiled (obs/roofline.py), every executable jax
-    asked its backend for, and how many of those the persistent cache
-    answered — so a reader can see whether the second pass (and a later
-    process) found the cache. ``requests - cache_hits`` were compiled."""
+    """Counts what the compiler did between two reads: every executable
+    jax asked its backend for, and how many of those the persistent
+    cache answered — so a reader can see whether the second pass (and a
+    later process) found the cache. ``requests - cache_hits`` were
+    compiled."""
 
     def __init__(self):
         import jax.monitoring as mon
@@ -101,10 +101,7 @@ class CompileCounter:
             self.cache_hits += 1
 
     def read(self) -> dict:
-        from spark_rapids_tpu.obs import roofline
-        return {"ledger": roofline.ledger_totals()["compiles"],
-                "requests": self.requests,
-                "cache_hits": self.cache_hits}
+        return {"requests": self.requests, "cache_hits": self.cache_hits}
 
     @staticmethod
     def delta(before: dict, after: dict) -> dict:
@@ -563,7 +560,8 @@ def phase_mesh(counter: CompileCounter, n_devices: int, data_dir: str,
         for got in (first, second):   # unordered row-set comparison
             assert_tables_equal(single, got, approx_float=rtol)
         records = executors[0].stage_records
-        texts = [t for r in records for t in r["program"].hlo_texts()]
+        texts = [r["program"].lower(*r["arg_shapes"]).compile().as_text()
+                 for r in records]
         a2a = sum("all-to-all" in t for t in texts)
         saw_all_to_all |= a2a > 0
         stage_devs = [r["output_devices"] for r in records]

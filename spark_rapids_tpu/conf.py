@@ -506,38 +506,6 @@ RESOURCE_SAMPLE_INTERVAL_MS = conf("srt.obs.resource.intervalMs") \
          "stays a module-global None check.") \
     .check(_non_negative).integer(0)
 
-ROOFLINE_ENABLED = conf("srt.obs.roofline.enabled") \
-    .doc("Master switch for the roofline observability layer: "
-         "ProgramCompiled events on every shared-program compile "
-         "(trace/lower/compile wall time + XLA cost_analysis flops/"
-         "bytes), per-launch device-time sampling, and per-query "
-         "RooflineSummary events. The compile ledger itself (counters "
-         "in obs/roofline.py) always records — it costs one dict "
-         "update per program COMPILE, never per batch — but with this "
-         "off nothing is sampled and no roofline events are emitted.") \
-    .boolean(True)
-
-ROOFLINE_SAMPLE_EVERY = conf("srt.obs.roofline.sampleEvery") \
-    .doc("Device-time sampling stride for shared jit programs, opt-in: "
-         "with N > 0 every Nth launch of each program is timed with a "
-         "device sync and joined with the compile ledger's bytes/flops "
-         "to produce achieved GB/s and FLOP/s (effective_gb_s "
-         "histograms, per-query RooflineSummary). Each sampled launch "
-         "costs one block_until_ready on the launching thread, which "
-         "stalls the pipeline behind it. 0 (default) makes no sync and "
-         "emits no per-query roofline summaries.") \
-    .check(_non_negative).integer(0)
-
-ROOFLINE_CALIBRATE = conf("srt.obs.roofline.calibrate") \
-    .doc("Measure this process's peak copy bandwidth once (a ~64MB "
-         "jitted copy probe, the tools/roofline.py denominator moved "
-         "in-engine) so roofline summaries report utilization — "
-         "achieved GB/s over measured peak — instead of raw rates. "
-         "Off by default: the probe costs a one-time device "
-         "allocation + a few launches, which benchmarks may not "
-         "want.") \
-    .boolean(False)
-
 CPU_ORACLE_STRICT = conf("srt.test.cpuOracle.strict") \
     .doc("Test-only: fail instead of falling back when an operator cannot "
          "run on TPU (assert_tpu_fallback analogue).") \

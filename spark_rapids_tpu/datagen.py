@@ -116,7 +116,7 @@ def generate_table(session, spec: TableSpec, out_dir: str,
     return paths
 
 
-# --- canned benchmark tables (TPC-H shapes; BASELINE.md configs) -----------
+# --- canned benchmark tables (TPC-H shapes) -----------
 
 def lineitem_spec(scale_rows: int) -> TableSpec:
     """The q6/q1 workhorse table."""

@@ -47,7 +47,6 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar.vector import ColumnarBatch
-from ..jit_registry import annotate as _annotate
 from ..jit_registry import shared_fn_jit
 from ..jit_registry import stats as _registry_stats
 from ..ops import kernels as K
@@ -234,10 +233,7 @@ def fused_final_merge_fn(agg, projs, cap: int):
     # (the pallas fields are dead in the merge pass)
     agg_spec = ("agg", False, 0) + tuple(
         tuple(getattr(agg, f)) for f in _AGG_FIELDS)
-    fn = shared_fn_jit(_fused_merge_builder, prefix_specs, agg_spec, cap)
-    _annotate(fn, "fused-final:concat+" + "project+" * len(projs)
-              + "merge[" + ", ".join(agg._key_names) + "]")
-    return fn
+    return shared_fn_jit(_fused_merge_builder, prefix_specs, agg_spec, cap)
 
 
 def _schema_row_bytes(schema: Schema) -> int:
@@ -300,11 +296,6 @@ class FusedPipelineExec(TpuExec):
         jit_kwargs = {"donate_argnums": (0,)} if self.donate else {}
         self._fn = shared_fn_jit(_fused_program_builder, self._specs,
                                  **jit_kwargs)
-        # roofline attribution: name the shared program after the
-        # chain (the structural key already covers the specs, so every
-        # chain of this shape shares both the program and the label)
-        _annotate(self._fn, "Fused[" + " -> ".join(
-            type(s).__name__ for s in self.stages) + "]")
         # bytes an unfused pipeline would materialize per capacity slot
         # at every internal operator boundary (each non-terminal
         # stage's output batch) — the HBM round-trips fusion removes
@@ -519,14 +510,6 @@ class FusedHashJoinExec(TpuExec):
             _schema_row_bytes(join.output_schema) +
             sum(_schema_row_bytes(st.output_schema)
                 for st in self.suffix[:-1]))
-        build_child = join.children[1] if join.build_side == "right" \
-            else join.children[0]
-        probe_child = join.children[0] if join.build_side == "right" \
-            else join.children[1]
-        self._label = ("fused-join:%s⋈%s -> %s [%s]" % (
-            type(build_child).__name__, type(probe_child).__name__,
-            " -> ".join(type(s).__name__ for s in self.suffix),
-            join.join_type))
         self._exec_state = None
         join._fusion = self
         FUSION_STATS["chains"] += 1
@@ -570,7 +553,6 @@ class FusedHashJoinExec(TpuExec):
                 tuple(self.join._build_key_exprs),
                 out_cap, self._reorder_n, self._suffix_specs, side.mode,
                 side.table_size, **jit_kwargs)
-            _annotate(fn, self._label)
             self._fn_cache[key] = fn
         return fn
 

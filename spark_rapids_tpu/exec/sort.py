@@ -140,9 +140,6 @@ class SortExec(TpuExec):
         fn = self._fused_cache.get(key)
         if fn is None:
             fn = shared_fn_jit(_concat_sort_builder, self.order, cap)
-            from ..jit_registry import annotate
-            annotate(fn, "fused-sort:concat+sort[" + ", ".join(
-                repr(o.expr) for o in self.order) + "]")
             from .fused import FUSION_STATS
             FUSION_STATS["sorts"] += 1
             self._fused_cache[key] = fn
@@ -153,8 +150,6 @@ class SortExec(TpuExec):
         fn = self._fused_cache.get(key)
         if fn is None:
             fn = shared_fn_jit(_chunk_head_builder, length, cap)
-            from ..jit_registry import annotate
-            annotate(fn, "fused-sort:chunk+head")
             self._fused_cache[key] = fn
         return fn
 
@@ -163,9 +158,6 @@ class SortExec(TpuExec):
         fn = self._fused_cache.get(key)
         if fn is None:
             fn = shared_fn_jit(_bound_prefix_builder, self.order)
-            from ..jit_registry import annotate
-            annotate(fn, "fused-sort:safe-prefix[" + ", ".join(
-                repr(o.expr) for o in self.order) + "]")
             self._fused_cache[key] = fn
         return fn
 

@@ -28,18 +28,6 @@ Two entry points:
 Anything the structural encoder (plan/plan_cache._enc) cannot encode
 falls back to a private ``jax.jit`` — unshared, never wrong.
 
-Every shared program is wrapped in a :class:`_SharedProgram` — the
-compile-ledger hook (obs/roofline.py): the wrapper AOT-compiles each
-new input signature through ``trace()/lower()/compile()`` with each
-phase wall-timed, captures XLA ``cost_analysis()`` flops/bytes, and
-keeps the compiled executable for direct dispatch (so the AOT step
-REPLACES jit's internal first-call trace, it does not duplicate it).
-Launches are counted on the ledger entry, and with
-``srt.obs.roofline.sampleEvery`` = N > 0 (off by default) every Nth
-launch is timed with a device sync and joined with the program's
-bytes/flops into achieved GB/s. Disable just the ledger with
-``SRT_JIT_LEDGER=0`` (plain ``jax.jit`` wrappers, pre-ledger behavior).
-
 Program names. Every program jitted here carries its structural label
 as its name: the function handed to ``jax.jit`` is renamed to the label
 (``FilterExec._filter``, ``_fused_program_builder``, a stage's label)
@@ -70,7 +58,6 @@ private ``jax.jit``) when isolating trace-level bugs.
 from __future__ import annotations
 
 import functools
-import hashlib
 import os
 import re
 import threading
@@ -88,8 +75,8 @@ _REGISTRY: Dict = {}
 _LOCK = threading.RLock()
 _STATS = {"hits": 0, "misses": 0, "uncached": 0}
 # per defining module (builder's or method class's __module__), so a
-# subsystem can report ITS share — e.g. bench reads the fused-pipeline
-# compile reuse rate from module "spark_rapids_tpu.exec.fused"
+# subsystem can report ITS share (e.g. the fused pipeline's reuse rate
+# from module "spark_rapids_tpu.exec.fused")
 _MODULE_STATS: Dict[str, Dict[str, int]] = {}
 
 
@@ -104,7 +91,6 @@ def _count(module: str, kind: str) -> None:
         m[kind] += 1
 
 _ENABLED = os.environ.get("SRT_JIT_REGISTRY", "1") != "0"
-_LEDGER_ENABLED = os.environ.get("SRT_JIT_LEDGER", "1") != "0"
 
 # Soft cap: parameterized workloads (distinct literals, growing
 # out_capacity buckets) mint unbounded distinct keys; past the cap the
@@ -128,55 +114,6 @@ def _encode(parts):
         return None
     except Exception:
         return None
-
-
-# --- compile ledger / roofline instrumentation (obs/roofline.py) ---
-
-def _key_hash(key) -> str:
-    """Stable short id for a structural key (ledger/event correlation
-    across processes of the same build)."""
-    try:
-        return hashlib.sha1(repr(key).encode()).hexdigest()[:16]
-    except Exception:
-        return hex(id(key))[2:]
-
-
-def _cost_of(compiled):
-    """(flops, bytes_accessed) from ``compiled.cost_analysis()``, each
-    None when the backend/jaxlib does not report it (CPU backends and
-    older jaxlibs return None, a bare dict, or miss keys) — graceful
-    degradation, never an error."""
-    try:
-        ca = compiled.cost_analysis()
-    except Exception:
-        return None, None
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else None
-    if not isinstance(ca, dict):
-        return None, None
-
-    def _num(k):
-        v = ca.get(k)
-        try:
-            v = float(v)
-        except (TypeError, ValueError):
-            return None
-        return v if v >= 0 else None
-    return _num("flops"), _num("bytes accessed")
-
-
-def _signature(args):
-    """Hashable input signature (treedef + per-leaf aval incl. weak
-    type) — the AOT executable cache key. Raises when any leaf has no
-    aval (caller falls back to the plain jit path)."""
-    from jax.api_util import shaped_abstractify
-    leaves, treedef = jax.tree_util.tree_flatten(args)
-    for leaf in leaves:
-        if isinstance(leaf, jax.core.Tracer):
-            # called under an enclosing trace (mesh lowering): jit
-            # inlines fine, an AOT executable cannot run on tracers
-            return None
-    return treedef, tuple(shaped_abstractify(x) for x in leaves)
 
 
 def program_name(label: str) -> str:
@@ -213,8 +150,10 @@ def _dispatch(span: str, runner, args, kwargs):
 
 
 class _NamedProgram:
-    """A private jit (unshared, no ledger entry) that still has a stable
-    program name and a ``launch.<label>`` range around each dispatch."""
+    """Every program the registry hands out, shared or private: the
+    ``jax.jit`` of the function renamed to its label, dispatched inside
+    its ``launch.<label>`` range. jit's own cache keys the compiled
+    executables by input avals; nothing is kept beside it."""
 
     __slots__ = ("fn", "_span")
 
@@ -231,200 +170,26 @@ class _NamedProgram:
 
 
 def named_jit(fn: Callable, label: str, **jit_kwargs) -> Callable:
-    """``jax.jit(fn)`` for the sites that keep a private jit (a closure
-    over live state no structural key covers): named ``jit_<label>`` and
-    launched inside ``launch.<label>`` like a shared program."""
+    """``jax.jit(fn)`` named ``jit_<label>`` and launched inside
+    ``launch.<label>``. Called directly by the sites that keep a private
+    jit (a closure over live state no structural key covers); the shared
+    entry points below put the same object in the registry."""
     return _NamedProgram(jax.jit(_named(fn, label), **jit_kwargs), label)
 
 
-class _SharedProgram:
-    """Callable wrapper around one shared jitted program that owns its
-    compile-ledger entry.
-
-    First call per input signature AOT-compiles (trace -> lower ->
-    compile, each phase wall-timed, ``cost_analysis`` captured) and
-    caches the compiled executable; later matching calls dispatch the
-    executable directly — no re-trace, same steady-state as jit's own
-    C++ cache. Unmatchable calls (kwargs, tracer args, signature-cache
-    overflow, any AOT failure) fall back to the inner ``jax.jit``
-    wrapper, so behavior never depends on the ledger. Every launch
-    increments the entry's launch counter and runs inside the
-    program's ``launch.<label>`` host range; with sampling on, every
-    Nth launch (``roofline.sample_every()``) is synced and timed into
-    the achieved-GB/s join.
-
-    Holds only the jit wrapper, avals, and compiled executables —
-    never the exec tree (the shell-detachment contract above stands).
-    """
-
-    #: distinct input signatures AOT-cached per program; beyond this
-    #: (unbounded capacity buckets) calls run through the inner jit
-    _SIG_CAP = 16
-
-    __slots__ = ("fn", "entry", "_span", "_sigs", "_n", "_lock")
-
-    def __init__(self, fn, entry):
-        self.fn = fn
-        self.entry = entry
-        self._span = "launch." + program_name(entry.label)
-        self._sigs: Dict = {}
-        self._n = 0
-        self._lock = threading.Lock()
-
-    # attribute pass-through (e.g. .lower on the inner jit wrapper)
-    def __getattr__(self, name):
-        return getattr(self.fn, name)
-
-    def hlo_texts(self) -> list:
-        """Optimized (post-partitioning) HLO of every AOT-cached
-        executable — where the collectives the compiler placed, and any
-        ``tpu_custom_call`` kernels, can be read."""
-        with self._lock:
-            recs = [r for r in self._sigs.values() if r is not None]
-        return [compiled.as_text() for compiled, _, _ in recs]
-
-    def drop_executables(self) -> None:
-        """Release AOT executables (mmap-guard / cache hygiene; the
-        next call re-compiles through the ledger, which records it as
-        the recompile it is)."""
-        with self._lock:
-            self._sigs.clear()
-
-    def _aot(self, args):
-        """Timed trace/lower/compile for ``args``; returns
-        (compiled, bytes, flops) or None when AOT is not possible."""
-        from .obs import roofline
-        try:
-            t0 = time.perf_counter_ns()
-            tracer = getattr(self.fn, "trace", None)
-            if tracer is not None:
-                traced = tracer(*args)
-                t1 = time.perf_counter_ns()
-                lowered = traced.lower()
-            else:  # older jax: trace folded into lower
-                traced = None
-                t1 = t0
-                lowered = self.fn.lower(*args)
-            t2 = time.perf_counter_ns()
-            compiled = lowered.compile()
-            t3 = time.perf_counter_ns()
-        except Exception:
-            return None
-        flops, nbytes = _cost_of(compiled)
-        try:
-            roofline.record_compile(self.entry, trace_ns=t1 - t0,
-                                    lower_ns=t2 - t1,
-                                    compile_ns=t3 - t2, flops=flops,
-                                    bytes_accessed=nbytes)
-        except Exception:
-            pass
-        return compiled, nbytes, flops
-
-    def _launch(self, runner, args, kwargs, nbytes, flops):
-        from .obs import roofline
-        entry = self.entry
-        entry.count_launch()
-        self._n += 1
-        stride = roofline.sample_every()
-        if stride > 0 and self._n % stride == 1 % stride:
-            t0 = time.perf_counter_ns()
-            out = _dispatch(self._span, runner, args, kwargs)
-            try:
-                jax.block_until_ready(out)
-                roofline.record_sample(
-                    entry, time.perf_counter_ns() - t0, nbytes, flops)
-            except Exception:
-                pass
-            return out
-        return _dispatch(self._span, runner, args, kwargs)
-
-    def __call__(self, *args, **kwargs):
-        if not kwargs:
-            try:
-                sig = _signature(args)
-            except Exception:
-                sig = None
-            if sig is not None:
-                rec = self._sigs.get(sig)
-                if rec is None and sig not in self._sigs:
-                    with self._lock:
-                        rec = self._sigs.get(sig)
-                        if rec is None and sig not in self._sigs:
-                            if len(self._sigs) < self._SIG_CAP:
-                                rec = self._aot(args)
-                                self._sigs[sig] = rec
-                if rec is not None:
-                    compiled, nbytes, flops = rec
-                    try:
-                        return self._launch(compiled, args, {},
-                                            nbytes, flops)
-                    except (TypeError, ValueError):
-                        # aval/placement mismatch the signature missed:
-                        # the inner jit re-specializes, always right
-                        pass
-        # fallback: kwargs, tracers, unsignable leaves, sig overflow,
-        # or failed AOT — plain shared jit, still launch-counted (no
-        # per-sig cost known, so samples join with bytes=None)
-        return self._launch(self.fn, args, kwargs, None, None)
-
-
-def _wrap_program(fn, key, module: str, label: str):
-    """Attach the compile-ledger wrapper to a fresh shared jit (miss
-    path). With the ledger disabled the raw jit is stored instead."""
-    if not _LEDGER_ENABLED:
-        return fn
-    try:
-        from .obs import roofline
-        entry = roofline.ensure_entry(_key_hash(key), module, label)
-    except Exception:
-        return fn
-    return _SharedProgram(fn, entry)
-
-
-def annotate(fn, display: str) -> None:
-    """Set the operator-facing display label on a shared program's
-    ledger entry (e.g. the fused chain description). No-op for plain
-    jits (uncached fallbacks, ledger disabled)."""
-    entry = getattr(fn, "entry", None)
-    if entry is not None:
-        entry.display = str(display)
-
-
-def rebind_ledger_entries() -> None:
-    """Give every live wrapper a FRESH ledger entry under its original
-    key. ``roofline.reset()`` (tests) calls this after dropping the
-    ledger: without it, wrappers registered before the reset would keep
-    counting into orphaned entries the new ledger never sees."""
+def _shared(key, module: str, label: str, make: Callable[[], Callable],
+            jit_kwargs) -> Callable:
+    """The program registered under ``key``; on a miss ``make()`` is
+    jitted, registered and charged to ``module``."""
     with _LOCK:
-        fns = [f for f in _REGISTRY.values()
-               if isinstance(f, _SharedProgram)]
-    try:
-        from .obs import roofline
-    except Exception:
-        return
-    for f in fns:
-        old = f.entry
-        new = roofline.ensure_entry(old.key, old.module, old.label)
-        if new is not old:
-            new.display = old.display
-            f.entry = new
-
-
-def release_executables() -> None:
-    """Drop every shared program's AOT executables (companion to
-    ``jax.clear_caches()`` in the mmap guard and bench sweeps — the
-    wrappers hold compiled programs jax's own caches do not track).
-    Ledger counters and the registry itself survive; next launches
-    re-compile and are ledgered as recompiles."""
-    with _LOCK:
-        fns = list(_REGISTRY.values())
-    for fn in fns:
-        drop = getattr(fn, "drop_executables", None)
-        if drop is not None:
-            try:
-                drop()
-            except Exception:
-                pass
+        fn = _REGISTRY.get(key)
+        if fn is not None:
+            _count(module, "hits")
+            return fn
+        fn = named_jit(make(), label, **jit_kwargs)
+        _put(key, fn)
+        _count(module, "misses")
+    return fn
 
 
 def shared_method_jit(obj, method_name: str, fields: Sequence[str],
@@ -444,20 +209,13 @@ def shared_method_jit(obj, method_name: str, fields: Sequence[str],
     key = (cls.__module__, cls.__qualname__, method_name, tuple(fields),
            enc, tuple(extra),
            tuple(sorted(jit_kwargs.items())) if jit_kwargs else ())
-    with _LOCK:
-        fn = _REGISTRY.get(key)
-        if fn is not None:
-            _count(cls.__module__, "hits")
-            return fn
+
+    def detached():
         shell = object.__new__(cls)
         for f in fields:
             setattr(shell, f, getattr(obj, f))
-        fn = _wrap_program(
-            jax.jit(_named(getattr(shell, method_name), label),
-                    **jit_kwargs), key, cls.__module__, label)
-        _put(key, fn)
-        _count(cls.__module__, "misses")
-    return fn
+        return getattr(shell, method_name)
+    return _shared(key, cls.__module__, label, detached, jit_kwargs)
 
 
 def shared_fn_jit(builder: Callable, *key_args, **jit_kwargs) -> Callable:
@@ -475,17 +233,8 @@ def shared_fn_jit(builder: Callable, *key_args, **jit_kwargs) -> Callable:
         return named_jit(builder(*key_args), label, **jit_kwargs)
     key = (builder.__module__, label, enc,
            tuple(sorted(jit_kwargs.items())) if jit_kwargs else ())
-    with _LOCK:
-        fn = _REGISTRY.get(key)
-        if fn is not None:
-            _count(builder.__module__, "hits")
-            return fn
-        fn = _wrap_program(
-            jax.jit(_named(builder(*key_args), label), **jit_kwargs),
-            key, builder.__module__, label)
-        _put(key, fn)
-        _count(builder.__module__, "misses")
-    return fn
+    return _shared(key, builder.__module__, label,
+                   lambda: builder(*key_args), jit_kwargs)
 
 
 def shared_stage_jit(build: Callable[[], Callable], key_parts,
@@ -497,11 +246,11 @@ def shared_stage_jit(build: Callable[[], Callable], key_parts,
     cannot apply; instead the CALLER passes ``key_parts`` — the stage's
     structural signature (operator classes, expression reprs, schemas,
     mesh identity, growth factor, donation layout). Two plans whose
-    stages match structurally share ONE jitted wrapper and ONE
-    compile-ledger entry per stage shape — not per device, not per
-    query — and jit's own aval cache handles row-capacity variation
-    beneath that. Unencodable key parts fall back to a private jit
-    (unshared, never wrong). ``build`` is only invoked on a miss.
+    stages match structurally share ONE jitted wrapper per stage
+    shape — not per device, not per query — and jit's own aval cache
+    handles row-capacity variation beneath that. Unencodable key parts
+    fall back to a private jit (unshared, never wrong). ``build`` is
+    only invoked on a miss.
     """
     enc = _encode(list(key_parts)) if _ENABLED else None
     if enc is None:
@@ -509,16 +258,7 @@ def shared_stage_jit(build: Callable[[], Callable], key_parts,
         return named_jit(build(), label, **jit_kwargs)
     key = (module, "stage_program", enc,
            tuple(sorted(jit_kwargs.items())) if jit_kwargs else ())
-    with _LOCK:
-        fn = _REGISTRY.get(key)
-        if fn is not None:
-            _count(module, "hits")
-            return fn
-        fn = _wrap_program(jax.jit(_named(build(), label), **jit_kwargs),
-                           key, module, label)
-        _put(key, fn)
-        _count(module, "misses")
-    return fn
+    return _shared(key, module, label, build, jit_kwargs)
 
 
 def stats(module: Optional[str] = None) -> dict:

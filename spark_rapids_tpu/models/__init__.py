@@ -1,9 +1,9 @@
 """Benchmark workloads: TPC-H-shaped queries + mortgage ETL.
 
 Rebuild of the reference's integration benchmark apps (SURVEY §4 tier
-3: mortgage/MortgageSpark.scala, scaletest/) against BASELINE.md's
-staged configs. Each function takes a session and table DataFrames and
-returns a DataFrame; datagen.py supplies the deterministic inputs.
+3: mortgage/MortgageSpark.scala, scaletest/). Each function takes a
+session and table DataFrames and returns a DataFrame; datagen.py
+supplies the deterministic inputs.
 """
 
 from .tpch import q1, q3, q6, tpch_tables

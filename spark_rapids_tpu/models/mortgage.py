@@ -1,6 +1,6 @@
-"""Mortgage-ETL-shaped pipeline (mortgage/MortgageSpark.scala role,
-BASELINE.md config 5): join performance records to acquisitions,
-derive delinquency features, aggregate per loan — the classic
+"""Mortgage-ETL-shaped pipeline (mortgage/MortgageSpark.scala role):
+join performance records to acquisitions, derive delinquency
+features, aggregate per loan — the classic
 ETL-then-ML-features benchmark, ending in to_device_arrays() for the
 ML hand-off (ColumnarRdd -> XGBoost in the reference)."""
 

@@ -1,5 +1,5 @@
 """NDS (TPC-DS derived) schema + the full 99-query power run as SQL
-text (BASELINE.md config 2 breadth; reference integration_tests run the
+text (operator breadth; reference integration_tests run the
 99-query suite the same way — SQL text against generated tables).
 
 The specs generate the columns the query subset touches, with realistic

@@ -1,4 +1,4 @@
-"""TPC-DS-shaped queries (BASELINE.md config 2 breadth).
+"""TPC-DS-shaped queries (operator breadth).
 
 A representative slice of the NDS suite's operator shapes over
 star-schema data (store_sales fact + date_dim/item/customer dims):

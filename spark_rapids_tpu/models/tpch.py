@@ -1,6 +1,6 @@
-"""TPC-H-shaped queries (BASELINE.md configs 1-2).
+"""TPC-H-shaped queries.
 
-q6: the scan/filter/aggregate smoke (config 1's exit criterion),
+q6: the scan/filter/aggregate smoke,
 q1:  the wide-aggregate pricing summary,
 q3:  the 3-way join shipping-priority query.
 

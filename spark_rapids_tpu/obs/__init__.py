@@ -23,13 +23,6 @@ TPU rebuild's counterpart, split the same way:
 - :mod:`.resource` — an optional background sampler
   (``srt.obs.resource.intervalMs``) recording RSS, device memory,
   spill/fetch/prefetch occupancy as periodic ResourceSample events.
-- :mod:`.roofline` — the compile ledger (per-program trace/lower/
-  compile wall time + XLA cost_analysis flops/bytes, fed by
-  ``jit_registry``), conf-gated per-launch device-time sampling
-  joined into achieved GB/s / GFLOP/s, one-time peak-bandwidth
-  calibration, and per-query RooflineSummary events —
-  ``tools/roofline_report.py`` ranks operators by
-  roofline-gap x time-weight from these.
 
 Design contract (same discipline as the unarmed ``fault_point`` sites):
 **zero overhead when disabled.** Every hook threaded through the hot
@@ -39,4 +32,4 @@ no per-batch work happens. ``tools/profile_report.py`` turns an event
 log back into a per-query report offline.
 """
 
-from . import events, registry, resource, roofline, trace  # noqa: F401
+from . import events, registry, resource, trace  # noqa: F401

@@ -39,7 +39,6 @@ EVENT_TYPES = (
     "FetchFailed", "RetryAttempt",
     "FaultInjected", "CorruptionDetected",
     "WorkerEvicted",
-    "ProgramCompiled", "RooflineSummary",
     "QueryAdmitted", "AdmissionQueued", "AdmissionRejected",
     "AdmissionAbandoned", "QueryCancelled", "DeadlineExceeded",
     "CrossQuerySpill", "PrefetchThreadLeak", "ClusterCancelBroadcast",

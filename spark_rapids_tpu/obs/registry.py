@@ -5,7 +5,8 @@ The exec layer already accumulates leveled ``Metric``s per operator
 aggregates them the way the reference accelerator's SQL UI does —
 filtered by ``srt.metrics.level`` (ESSENTIAL < MODERATE < DEBUG),
 rolled up per query, and kept in a bounded process-wide registry that
-``bench.py`` and tests can snapshot or export as Prometheus text.
+the benchmark's readers and tests can snapshot or export as Prometheus
+text.
 """
 
 from __future__ import annotations

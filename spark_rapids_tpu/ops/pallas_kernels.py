@@ -4,7 +4,7 @@ The XLA operator pipeline materializes intermediates between filter and
 aggregate: ``FilterExec`` compacts passing rows into a fresh batch
 (argsort + gather = several HBM round-trips) before ``HashAggregateExec``
 reduces them. For the hottest reduction shape — scan -> filter -> global
-aggregate, the TPC-H q6 spine of BASELINE.md config 1 — that traffic is
+aggregate, the TPC-H q6 spine — that traffic is
 the whole cost: the aggregate output is a handful of scalars.
 
 ``tile_reduce`` fuses predicate evaluation, projection, and partial

@@ -823,12 +823,8 @@ class ClusterWorker:
         # and srt.obs.resource.intervalMs the resource sampler
         from ..obs import events as _events
         from ..obs import resource as _resource
-        from ..obs import roofline as _roofline
         _events.configure_from_conf(conf)
         _resource.configure_from_conf(conf)
-        # and the roofline layer: worker-side shared-program launches
-        # sample into this process's ledger under the job's stride
-        _roofline.configure_from_conf(conf)
         # cross-process tracing: rebuild a child tracer from the
         # driver's shipped context so this worker's task/operator spans
         # share the driver's trace_id and parent under its job span
@@ -1570,8 +1566,6 @@ class ClusterDriver:
             dconf = SrtConf(dict(conf_settings or {}))
             _events.configure_from_conf(dconf)
             _resource.configure_from_conf(dconf)
-            from ..obs import roofline as _roofline
-            _roofline.configure_from_conf(dconf)
             tracer = maybe_tracer(dconf)
         except Exception:
             pass  # an invalid test conf must not mask the real error

@@ -40,7 +40,7 @@ entire plan and re-executed every leaf per retry, and aborted q19 at
 scale with an rc=-6 rendezvous abort: divergent per-device re-traces of
 an ever-growing monolithic program) is gone. Stage programs are shared
 process-wide by structural shape through jit_registry.shared_stage_jit
-(one compile-ledger entry per stage shape, not per device or query),
+(one program per stage shape, not per device or query),
 and stages that cannot retry donate their single-consumer inputs.
 
 The reference's equivalent is a p2p shuffle (UCX ActiveMessages,
@@ -273,6 +273,10 @@ class MeshQueryExecutor:
                 args = [self._materialize_slot(s, ctx)
                         for s in build.slots]
             program, record = self._stage_program(build, fn, label)
+            # what ``program.lower`` needs to show this stage's HLO
+            record["arg_shapes"] = jax.tree_util.tree_map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                               sharding=x.sharding), args)
             fault_point("mesh.stage.run", label)
             out, ok = program(*args)
             if bool(jnp.all(ok)):

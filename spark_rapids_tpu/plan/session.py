@@ -76,11 +76,7 @@ def release_compiled_programs() -> None:
 
     import jax
 
-    from ..jit_registry import release_executables
     jax.clear_caches()
-    # the ledger wrappers hold AOT executables jax's caches don't
-    # track — release those mappings too, or the guard under-frees
-    release_executables()
     gc.collect()
 
 
@@ -252,7 +248,6 @@ class TpuSession:
         from ..conf import METRICS_LEVEL, QUERY_TIMEOUT_S
         from ..obs import events as _events
         from ..obs import resource as _resource
-        from ..obs import roofline as _roofline
         from ..obs.registry import registry as _registry
         from ..obs.registry import summarize_metrics
         from ..obs.trace import maybe_tracer
@@ -263,7 +258,6 @@ class TpuSession:
                                             query_scope, query_semaphore)
         _events.configure_from_conf(self.conf)
         _resource.configure_from_conf(self.conf)
-        _roofline.configure_from_conf(self.conf)
         if query is not None:
             # externally-supplied cancel token (serve/server.py): the
             # caller holds the handle before admission, so a client
@@ -292,10 +286,6 @@ class TpuSession:
             self._active_query = qctx
             qscope = query_scope(qctx)
             qscope.__enter__()
-            # per-query roofline window: ledger counter baseline,
-            # diffed in the finally into a RooflineSummary (None =
-            # sampling off, and then the whole layer is skipped)
-            rwin = _roofline.window()
             ctx = ExecContext(self.conf, query=qctx)
             ctx.tracer = maybe_tracer(self.conf)
         except BaseException:
@@ -391,10 +381,6 @@ class TpuSession:
                          execute_ns=wall_ns, fetch_ns=fetch_ns,
                          dispatch_ns=qctx.dispatch_ns - launch0[0],
                          launches=qctx.launches - launch0[1])}
-            if rwin is not None:
-                rsum = rwin.finish(qid)  # emits RooflineSummary
-                if rsum is not None:
-                    extra["roofline"] = rsum
             rec = _registry().record_query(qid, summary, wall_ns,
                                            status, **extra)
             self._last_execution = {"physical": physical, "ctx": ctx,

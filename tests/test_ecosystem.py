@@ -82,6 +82,101 @@ def test_docs_are_fresh():
             "docs/supported_ops.md stale: run python tools/gen_docs.py"
 
 
+def test_no_roofline_conf_is_registered_or_accepted():
+    from spark_rapids_tpu import conf
+    assert len(conf._REGISTRY) == 128
+    assert not [k for k in conf._REGISTRY if k.startswith("srt.obs.roofline")]
+    # a removed key is refused as any unknown srt.* key is
+    for key in ("srt.obs.roofline.sampleEvery", "srt.no.such.key"):
+        with pytest.raises(KeyError, match="unknown config"):
+            conf.SrtConf({key: 1})
+
+
+_ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+_SCRIPTS = sorted(
+    os.path.join("tools", f) for f in os.listdir(os.path.join(_ROOT, "tools"))
+    if f.endswith(".py")) + ["chip_smoke.py", "__graft_entry__.py"]
+
+
+def _top_level_names(path):
+    """Names a script binds at module level, read from its source."""
+    import ast
+    names, todo = set(), list(ast.parse(open(path).read()).body)
+    while todo:
+        node = todo.pop()
+        if isinstance(node, (ast.FunctionDef, ast.ClassDef)):
+            names.add(node.name)
+        elif isinstance(node, (ast.Import, ast.ImportFrom)):
+            names.update((a.asname or a.name).split(".")[0]
+                         for a in node.names)
+        elif isinstance(node, (ast.Assign, ast.AnnAssign, ast.AugAssign)):
+            targets = node.targets if isinstance(node, ast.Assign) \
+                else [node.target]
+            names.update(n.id for t in targets for n in ast.walk(t)
+                         if isinstance(n, ast.Name))
+        else:  # if / try / with / for at module level
+            for field in ("body", "orelse", "finalbody", "handlers"):
+                todo.extend(getattr(node, field, []))
+    return names
+
+
+@pytest.mark.parametrize("script", _SCRIPTS)
+def test_script_compiles_and_what_it_imports_exists(script):
+    """A script no tier-1 test runs (aot_cell_programs, serve_bench,
+    chaos_check, mesh_nds, chip_smoke) still breaks when the package
+    drops a name it uses. Nothing of the script is executed: its source
+    is compiled, and every ``spark_rapids_tpu`` or sibling-script name
+    it imports — and every attribute it reads off an imported package
+    module — must exist."""
+    import ast
+    import importlib
+    import types
+    path = os.path.join(_ROOT, script)
+    source = open(path).read()
+    compile(source, path, "exec")
+    siblings = {os.path.splitext(os.path.basename(s))[0]:
+                os.path.join(_ROOT, s) for s in _SCRIPTS}
+    missing, package_modules = [], {}
+
+    def lookup(dotted):
+        """The package module or attribute ``dotted`` names, or None."""
+        try:
+            return importlib.import_module(dotted)
+        except ImportError:
+            module, _, name = dotted.rpartition(".")
+            return getattr(importlib.import_module(module), name, None)
+
+    tree = ast.parse(source)
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Import):
+            names = [(a.name, a.asname or a.name.split(".")[0], a.name
+                      if a.asname else a.name.split(".")[0])
+                     for a in node.names]
+        elif isinstance(node, ast.ImportFrom) and node.level == 0:
+            names = [(f"{node.module}.{a.name}", a.asname or a.name,
+                      f"{node.module}.{a.name}") for a in node.names]
+        else:
+            continue
+        for dotted, bound_as, bound_to in names:
+            top, _, rest = dotted.partition(".")
+            if top == "spark_rapids_tpu":
+                if lookup(dotted) is None:
+                    missing.append(dotted)
+                elif isinstance(lookup(bound_to), types.ModuleType):
+                    package_modules[bound_as] = bound_to
+            elif top in siblings and rest \
+                    and rest not in _top_level_names(siblings[top]):
+                missing.append(dotted)
+    for node in ast.walk(tree):
+        if isinstance(node, ast.Attribute) \
+                and isinstance(node.value, ast.Name) \
+                and node.value.id in package_modules:
+            dotted = f"{package_modules[node.value.id]}.{node.attr}"
+            if lookup(dotted) is None:
+                missing.append(dotted)
+    assert not missing, f"{script} uses names that do not exist: {missing}"
+
+
 def test_ml_export_device_arrays(session):
     import jax
     df = session.create_dataframe({"f1": [1.0, 2.0, 3.0],

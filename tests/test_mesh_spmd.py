@@ -253,7 +253,7 @@ def test_stage_programs_shared_across_runs(mesh):
     assert n_programs >= 2
     assert mid["misses"] - before["misses"] <= n_programs
     # identical plan shape, fresh plan objects and data values: every
-    # stage program is a registry HIT — zero new compile-ledger entries
+    # stage program is a registry HIT — no new program is registered
     df2 = _grouped_agg_df(s, seed=12)
     phys2 = overrides.apply_overrides(df2.plan, conf)
     ex2 = MeshQueryExecutor(mesh, conf)

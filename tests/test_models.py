@@ -1,5 +1,5 @@
 """Benchmark model pipelines run end-to-end, differential vs the CPU
-oracle (SURVEY §4 tier 3; BASELINE.md configs)."""
+oracle (SURVEY §4 tier 3)."""
 
 import pytest
 
@@ -65,7 +65,7 @@ def test_mortgage_etl(session, tmp_path):
 
 class TestTpcds:
     """TPC-DS-shaped breadth (models/tpcds.py) — differential vs the
-    CPU oracle (BASELINE config 2's operator coverage)."""
+    CPU oracle (operator coverage)."""
 
     @pytest.fixture(scope="class")
     def tables(self, tmp_path_factory):

@@ -1,5 +1,5 @@
 """NDS (TPC-DS derived) 99-query suite, end-to-end as SQL text through
-session.sql, differential device-vs-CPU (BASELINE.md config 2; the
+session.sql, differential device-vs-CPU (the
 reference proves breadth the same way with its 99-query
 integration_tests suite).
 

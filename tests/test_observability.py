@@ -15,8 +15,7 @@
 - program names, host ranges and query phases: every jitted program's
   HLO module is ``jit_<registry label>``; a parquet query's record
   carries its ``phases``; the ranges land in a ``jax.profiler`` trace on
-  the threads that feed the device, decode does not; the roofline
-  sampler is off unless asked for.
+  the threads that feed the device, decode does not.
 """
 
 import json
@@ -529,7 +528,6 @@ def traced_parquet_query(tmp_path_factory):
     import pyarrow.parquet as pq
     from jax.profiler import ProfileData
 
-    from spark_rapids_tpu.obs import roofline
     from spark_rapids_tpu.obs.registry import registry
     data = tmp_path_factory.mktemp("phases_data")
     for i in range(3):
@@ -548,7 +546,6 @@ def traced_parquet_query(tmp_path_factory):
     jax.profiler.start_trace(str(trace_dir), profiler_options=options)
     try:
         session.sql(sql).collect()
-        window = roofline.window()  # as a default session leaves it
     finally:
         jax.profiler.stop_trace()
     [path] = glob.glob(str(trace_dir / "plugins" / "profile" / "*"
@@ -560,11 +557,11 @@ def traced_parquet_query(tmp_path_factory):
                 ranges.setdefault(event.name, set()).add((plane.name, i))
     last = session._last_execution
     assert last["record"] is registry().queries()[-1]
-    return last["record"], last["ctx"].metrics, ranges, window
+    return last["record"], last["ctx"].metrics, ranges
 
 
 def test_query_record_carries_phases(traced_parquet_query):
-    record, metrics, _, _ = traced_parquet_query
+    record, metrics, _ = traced_parquet_query
     phases = record["phases"]
     assert set(phases) == PHASE_KEYS
     assert all(isinstance(v, int) and v >= 0 for v in phases.values())
@@ -584,7 +581,7 @@ def test_query_record_carries_phases(traced_parquet_query):
 
 
 def test_host_ranges_are_in_the_profilers_trace(traced_parquet_query):
-    _, _, ranges, _ = traced_parquet_query
+    _, _, ranges = traced_parquet_query
     for name in ("plan.parse", "plan.physical", "result.fetch",
                  "scan.wait", "scan.upload", "prefetch.wait"):
         assert name in ranges, f"no {name} range in the trace"
@@ -598,14 +595,3 @@ def test_host_ranges_are_in_the_profilers_trace(traced_parquet_query):
     # decode is a counter only: a range on a reader-pool thread would
     # be taken for the cause of idle gaps it merely overlaps
     assert not [n for n in ranges if "decode" in n.lower()]
-
-
-def test_roofline_sampler_is_off_by_default(traced_parquet_query):
-    from spark_rapids_tpu.conf import ROOFLINE_SAMPLE_EVERY
-    from spark_rapids_tpu.obs import roofline
-    record, _, _, window = traced_parquet_query
-    assert SrtConf({}).get(ROOFLINE_SAMPLE_EVERY) == 0
-    # after a default session's query: no sampling stride, so no launch
-    # was synced on the sampler's behalf and no window was opened
-    assert window is None and "roofline" not in record
-    assert roofline.sample_every() == 0 and not roofline.active()

@@ -51,19 +51,14 @@ def main() -> int:
             seen.add(sig)
             programs.append((label, fn, leaves, treedef))
 
-    shared_call, named_call = jit_registry._SharedProgram.__call__, jit_registry._NamedProgram.__call__
+    program_call = jit_registry._NamedProgram.__call__
 
-    def shared(self, *a, **k):
-        if not k:
-            note(self.entry.label, self.fn, a)
-        return shared_call(self, *a, **k)
-
-    def named(self, *a, **k):
+    def noted_call(self, *a, **k):
         if not k:
             note(self._span[len("launch."):], self.fn, a)
-        return named_call(self, *a, **k)
+        return program_call(self, *a, **k)
 
-    jit_registry._SharedProgram.__call__, jit_registry._NamedProgram.__call__ = shared, named
+    jit_registry._NamedProgram.__call__ = noted_call
 
     cell = json.load(open(os.path.join(ROOT, "benchmarks", "workloads", f"{args.workload}.json")))
     config = json.load(open(os.path.join(ROOT, "benchmarks", "configs", f"{cell['config']}.json")))

@@ -276,108 +276,6 @@ def _telemetry_check(n_workers: int = 4) -> int:
     return failures
 
 
-def _roofline_check() -> int:
-    """Roofline-observability leg: run a tiny in-process query with
-    sampling forced on (``srt.obs.roofline.sampleEvery=1``) plus peak
-    calibration and require the event log to carry the roofline layer's
-    evidence — at least one ``ProgramCompiled``, a per-query
-    ``RooflineSummary`` whose utilization lands in (0, 1.5] (cache
-    effects push small CPU programs past the measured copy peak, hence
-    the slack above 1.0), and an aggregate ``tools/roofline_report.py``
-    that parses with >= 80% of busy time attributed to ledger programs.
-    A second query with ``srt.obs.roofline.enabled=false`` must append
-    ZERO roofline events — the zero-overhead contract. Returns failure
-    count."""
-    import numpy as np
-
-    from spark_rapids_tpu.conf import SrtConf
-    from spark_rapids_tpu.expr import col
-    from spark_rapids_tpu.expr.aggregates import CountStar, Sum
-    from spark_rapids_tpu.expr.core import Alias
-    from spark_rapids_tpu.obs import events as ev
-    from spark_rapids_tpu.plan import TpuSession
-
-    sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
-    from roofline_report import report as build_roofline
-
-    failures = 0
-    t0 = time.monotonic()
-    with tempfile.TemporaryDirectory(prefix="srt_roofline_") as tmp:
-        events_dir = os.path.join(tmp, "events")
-        rng = np.random.default_rng(7)
-        # big enough that the scan streams from DRAM rather than cache
-        # (cache-resident batches report absurd GB/s and would trip the
-        # utilization ceiling)
-        n = 1_500_000
-        data_dir = os.path.join(tmp, "fact")
-        TpuSession(SrtConf({})).create_dataframe({
-            "g": rng.integers(0, 50, n).tolist(),
-            "x": rng.uniform(0, 10, n).tolist(),
-            "w": rng.uniform(0, 1, n).tolist(),
-        }).write.parquet(data_dir)
-
-        sess = TpuSession(SrtConf({
-            "srt.eventLog.enabled": "true",
-            "srt.eventLog.dir": events_dir,
-            "srt.obs.roofline.sampleEvery": "1",
-            "srt.obs.roofline.calibrate": "true",
-        }))
-        # x*w keeps this program shape distinct from the fault sweep's
-        # oracle query, so the leg always observes a fresh compile
-        sess.read.parquet(data_dir).filter(col("x") < 8.0) \
-            .group_by("g").agg(Alias(Sum(col("x") * col("w")), "s")) \
-            .sort("g").collect()
-
-        recs = ev.read_all_events(events_dir)
-        compiled = [r for r in recs if r.get("event") == "ProgramCompiled"]
-        summaries = [r for r in recs
-                     if r.get("event") == "RooflineSummary"]
-        checks = [("ProgramCompiled events recorded", len(compiled) >= 1),
-                  ("one RooflineSummary per query", len(summaries) == 1)]
-        if summaries:
-            s = summaries[0]
-            checks.append(("summary schema complete",
-                           all(k in s for k in (
-                               "query_id", "device_busy_est_ns", "gb_s",
-                               "peak_gb_s", "utilization", "compiles",
-                               "sample_every", "programs"))))
-            util = s.get("utilization")
-            checks.append(
-                ("utilization in (0, 1.5]",
-                 isinstance(util, (int, float)) and 0 < util <= 1.5))
-        rep = build_roofline(events_dir)
-        frac = rep.get("attributed_frac")
-        checks.append(("report parses with >= 80% busy time attributed",
-                       isinstance(frac, (int, float)) and frac >= 0.8))
-
-        # conf-off leg: a fresh program shape (CountStar) WOULD compile
-        # and summarize, so zero new events proves the gate, not a
-        # cache hit
-        before = len(recs)
-        off = TpuSession(SrtConf({
-            "srt.eventLog.enabled": "true",
-            "srt.eventLog.dir": events_dir,
-            "srt.obs.roofline.enabled": "false",
-        }))
-        off.read.parquet(data_dir).group_by("g") \
-            .agg(Alias(CountStar(), "c")).collect()
-        new = [r for r in ev.read_all_events(events_dir)[before:]
-               if r.get("event") in ("ProgramCompiled",
-                                     "RooflineSummary")]
-        checks.append(("conf off appends zero roofline events",
-                       not new))
-        for what, ok in checks:
-            if not ok:
-                print(f"[chaos] FAIL [roofline]: {what}",
-                      file=sys.stderr, flush=True)
-                failures += 1
-        print(f"[chaos] {'PASS' if not failures else 'FAIL'} "
-              f"[roofline: sampled query -> report] "
-              f"{time.monotonic() - t0:.1f}s ({len(checks)} checks)",
-              flush=True)
-    return failures
-
-
 def _concurrency_check(n_threads: int = 8, queries_per_thread: int = 4,
                        seed: int = 1337) -> int:
     """Concurrent-serving leg: N threads race mixed queries through a
@@ -1874,8 +1772,6 @@ def main() -> int:
     failures += _spill_corruption_check()
     # distributed-telemetry leg: 4-worker run, merged history report
     failures += _telemetry_check()
-    # roofline-observability leg: sampled query -> report, off -> silent
-    failures += _roofline_check()
     # concurrent-serving leg: admission + budget slices + cancellation
     failures += _concurrency_check()
     # adaptive-execution leg: skew/demote/coalesce/speculation sweep

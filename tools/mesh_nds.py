@@ -2,14 +2,14 @@
 shapes through the SPMD mesh executor on a virtual device mesh,
 differential against single-stream execution of the same plans.
 
-The subset covers the plan vocabulary BASELINE config 3 (pod-wide NDS)
-exercises: broadcast + shuffled joins, partial/final staged aggregates,
+The subset covers the plan vocabulary a pod-wide NDS run exercises:
+broadcast + shuffled joins, partial/final staged aggregates,
 ROLLUP expand, window functions over exchanges, INTERSECT/EXCEPT,
 subqueries, CASE aggregates and global sorts.
 
 Usage:
   XLA_FLAGS=--xla_force_host_platform_device_count=8 JAX_PLATFORMS=cpu \
-      python tools/mesh_nds.py [scale_rows] [out.json]
+      python tools/mesh_nds.py [out.json]
 """
 
 from __future__ import annotations
@@ -24,9 +24,8 @@ def _pin_cpu_emulation() -> None:
     """Standalone/subprocess entry ONLY (must run before jax imports):
     embedded callers (__graft_entry__.dryrun_multichip_nds) keep
     whatever platform the driver initialized. This tool is a
-    virtual-mesh rehearsal by definition — and bench.py starts it as a
-    child while the parent holds the chip, so it must never ask for
-    one."""
+    virtual-mesh rehearsal by definition, so it must never ask for a
+    chip."""
     os.environ["JAX_PLATFORMS"] = "cpu"
     flags = os.environ.get("XLA_FLAGS", "")
     if "host_platform_device_count" not in flags:
@@ -42,9 +41,8 @@ _CACHE = os.path.join(_ROOT, ".bench_cache")
 #: q77), windows (q51 q67 q89), set-ops (q38 q87), sort-limit
 #: everywhere. Deep-subquery shapes (q1/q6-class: correlated + scalar
 #: subqueries) lower to SPMD programs whose single-core emulation runs
-#: 20+ minutes per query — they run in the single-stream differential
-#: proof (NDS_100K_PROOF) and are out of this subset's budget, not its
-#: vocabulary.
+#: 20+ minutes per query — they are out of this subset's budget, not
+#: its vocabulary.
 #: cheap-first order: a timeboxed run persists incrementally, so the
 #: record carries maximal coverage even when the heavy tail is cut
 SUBSET = ["q42", "q52", "q55", "q96", "q62", "q3", "q19", "q38",
@@ -128,94 +126,6 @@ def _assert_rows_equal(qid, mesh_rows, single_rows):
 SCALE_SUBSET = ["q42", "q52", "q55", "q96", "q62"]
 
 
-def bench_one(qid: str, scale: int, n_devices: int,
-              ab: bool) -> dict:
-    """Timed A/B for one NDS shape (bench.py mesh lane, one query per
-    subprocess so the XLA device-count flag applies): warm + timed
-    mesh-executor run (stage-DAG SPMD programs), optionally a warm +
-    timed single-stream run of the same plan, plus the stage-boundary
-    byte counters (bypassed = never-serialized device-resident bytes,
-    wire = subset that rode in-program collectives)."""
-    from spark_rapids_tpu import parallel as par
-    from spark_rapids_tpu.conf import SrtConf
-    from spark_rapids_tpu.models.nds import NDS_QUERIES, register_nds
-    from spark_rapids_tpu.plan import overrides
-    from spark_rapids_tpu.plan.host_table import to_pydict
-    from spark_rapids_tpu.plan.mesh_executor import MeshQueryExecutor
-    from spark_rapids_tpu.plan.session import TpuSession
-
-    mesh = par.data_mesh(n_devices)
-    conf = SrtConf({"srt.shuffle.partitions": n_devices})
-    sess = TpuSession(conf)
-    register_nds(sess, os.path.join(_CACHE, f"nds_meshbench_{scale}"),
-                 scale_rows=scale)
-    df = sess.sql(NDS_QUERIES[qid])
-
-    def mesh_run():
-        physical = overrides.apply_overrides(df.plan, conf)
-        ex = MeshQueryExecutor(mesh, conf)
-        t0 = time.time()
-        out = ex.run(physical)
-        return time.time() - t0, ex, sum(
-            int(b.num_rows) for b in out)
-
-    first_s, _, _ = mesh_run()          # compile + warmup
-    mesh_s, ex, rows = mesh_run()       # steady state
-    rec = {"ok": True, "qid": qid, "rows": rows,
-           "mesh_first_s": round(first_s, 3),
-           "mesh_s": round(mesh_s, 3),
-           "bypassed": int(ex.shuffle_bytes_bypassed),
-           "wire": int(ex.shuffle_bytes_wire),
-           "stages": len(ex.stage_records),
-           "retries": ex.stage_retries}
-    if ab:
-        to_pydict(sess.execute(df.plan))  # warm the serialized path
-        t0 = time.time()
-        to_pydict(sess.execute(df.plan))
-        rec["off_s"] = round(time.time() - t0, 3)
-    return rec
-
-
-def bench_one_subprocess(qid: str, scale: int, n_devices: int = 8,
-                         ab: bool = False,
-                         timeout_s: int = 900) -> dict:
-    """bench.py entry: run ``bench_one`` in a subprocess (the XLA
-    virtual-device-count flag must be set before jax initializes, and
-    the calling bench process has long since initialized jax) and
-    return its JSON record. One retry: rendezvous aborts on the 1-core
-    box are scheduler flakes, not plan bugs."""
-    import resource
-    import subprocess
-
-    def _cap_memory():
-        lim = 48 * 2 ** 30
-        resource.setrlimit(resource.RLIMIT_AS, (lim, lim))
-
-    last = None
-    for _attempt in range(2):
-        t0 = time.time()
-        try:
-            p = subprocess.run(
-                [sys.executable, os.path.abspath(__file__), "--bench",
-                 qid, str(scale), str(n_devices),
-                 "ab" if ab else "on"],
-                capture_output=True, timeout=timeout_s,
-                preexec_fn=_cap_memory)
-            for line in reversed(
-                    p.stdout.decode("utf-8", "replace").splitlines()):
-                if line.startswith("{"):
-                    return json.loads(line)
-            last = {"ok": False, "qid": qid,
-                    "s": round(time.time() - t0, 1),
-                    "error": f"rc={p.returncode}: "
-                             f"{p.stderr.decode()[-160:]}"}
-        except subprocess.TimeoutExpired:
-            last = {"ok": False, "qid": qid,
-                    "s": round(time.time() - t0, 1),
-                    "error": f"timeout {timeout_s}s"}
-    return last
-
-
 def _run_one_subprocess(qid: str, scale: int, n_devices: int,
                         timeout_s: int, attempts: int = 2) -> dict:
     """One query per subprocess: an XLA rendezvous deadlock/abort (a
@@ -270,17 +180,9 @@ def main():
         res = run_subset(scale, qids=[qid], n_devices=ndev)[qid]
         print(json.dumps(res))
         return
-    if len(sys.argv) > 1 and sys.argv[1] == "--bench":
-        qid, scale, ndev = sys.argv[2], int(sys.argv[3]), int(sys.argv[4])
-        ab = len(sys.argv) > 5 and sys.argv[5] == "ab"
-        try:
-            res = bench_one(qid, scale, ndev, ab)
-        except Exception as e:
-            res = {"ok": False, "qid": qid,
-                   "error": f"{type(e).__name__}: {e}"[:200]}
-        print(json.dumps(res))
-        return
-    out_path = sys.argv[1] if len(sys.argv) > 1 else "MESH_NDS_r05.json"
+    os.makedirs(_CACHE, exist_ok=True)
+    out_path = sys.argv[1] if len(sys.argv) > 1 else os.path.join(
+        _CACHE, "mesh_nds.json")
     t0 = time.time()
     full = {}
     at_scale = {}

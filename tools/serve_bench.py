@@ -9,8 +9,7 @@ tenant on its own TCP session, paced open-loop at the target aggregate
 QPS; a load-shed (retryable SHED frame) is counted and the slot is
 retried on the next tick rather than silently dropped.
 
-Reported (merged into the bench record by bench.py's
-``SRT_BENCH_SERVE=1`` lane, and gated by tools/perf_gate.py):
+Reported:
 
 - ``serve_p50_ms`` / ``serve_p90_ms`` / ``serve_p99_ms`` — end-to-end
   submit->EOS latency over every completed request (time-like: lower
