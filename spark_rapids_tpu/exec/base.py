@@ -253,6 +253,13 @@ class ExecContext:
         #: exec/join.py BuildSide): built once per build batch, dropped
         #: with the query's context
         self.join_builds: Dict[str, tuple] = {}
+        #: exec_id -> the prefetch producer (exec/pipeline.py
+        #: PrefetchIterator) a join started for that node before its
+        #: first pull (``TpuExec.start_sources``); the node's execution
+        #: takes it from here, the join that started it closes what is
+        #: never taken. On the context, not the node: plans are cached
+        #: and re-run
+        self.early_sources: Dict[str, object] = {}
 
     def dump_crash(self, failing_exec, error: BaseException,
                    dump_dir: str) -> Optional[str]:
@@ -311,6 +318,12 @@ class TpuExec:
     #: node's advertised partitioning without a re-exchange: AQE
     #: transforms that change the partition count must stand down
     preserve_partitioning = False
+
+    #: True on operators that pull their one child batch after batch,
+    #: from their first pull to their last (filter, project, coalesce,
+    #: prefetch, the fused wrappers): ``start_sources`` reaches the
+    #: scans beneath them
+    _streams_child = False
 
     def __init__(self, *children: "TpuExec"):
         self.children: List[TpuExec] = list(children)
@@ -408,6 +421,20 @@ class TpuExec:
 
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         raise NotImplementedError
+
+    def start_sources(self, ctx: ExecContext) -> List[str]:
+        """Start now, ahead of the first pull, the prefetch producers
+        of the sources this operator will stream in this execution; a
+        join calls it on its children when it starts, so the scans
+        beneath it run beside its build (exec/pipeline.py
+        ``sources_started``). Returns the exec_ids whose producers it
+        started and left on ``ctx.early_sources``. Default: none — an
+        operator that may never pull its child, or not in plan order
+        (exchanges' map sides, sort, limit, union, window), starts
+        nothing the lazy order would not have started."""
+        if self._streams_child:
+            return self.children[0].start_sources(ctx)
+        return []
 
     def reset_for_rerun(self) -> None:
         """Clear one-shot per-run state before a cached physical tree is

@@ -57,6 +57,8 @@ class ProjectExec(TpuExec):
     one compiled program for every batch — and eager-only trees
     (input_file_name, uuid, raise_error) evaluate un-jitted."""
 
+    _streams_child = True
+
     def __init__(self, child: TpuExec, exprs: Sequence[Expression]):
         super().__init__(child)
         self.exprs = list(exprs)
@@ -101,6 +103,8 @@ class ProjectExec(TpuExec):
 class FilterExec(TpuExec):
     """WHERE: compacts passing rows to the batch prefix (GpuFilterExec)."""
 
+    _streams_child = True
+
     def __init__(self, child: TpuExec, condition: Expression):
         super().__init__(child)
         self.condition = condition
@@ -120,8 +124,11 @@ class FilterExec(TpuExec):
 
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         for batch in self.children[0].execute(ctx):
+            # (not yielded under the semaphore: a producer thread that
+            # stands in backpressure would keep its permit)
             with ctx.semaphore:
-                yield self._jit(batch)
+                out = self._jit(batch)
+            yield out
 
     def node_description(self) -> str:
         return f"Filter[{self.condition!r}]"
@@ -264,6 +271,8 @@ class CoalesceBatchesExec(TpuExec):
     """Combine small batches up to the target size (GpuCoalesceBatches,
     AbstractGpuCoalesceIterator:250). Registers pending batches as
     spillable while accumulating, like the reference's on-deck storage."""
+
+    _streams_child = True
 
     def __init__(self, child: TpuExec, target_rows: Optional[int] = None):
         super().__init__(child)

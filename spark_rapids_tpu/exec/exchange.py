@@ -983,5 +983,25 @@ class BroadcastExchangeExec(TpuExec):
         if out is not None:
             yield out
 
+    def start_sources(self, ctx: ExecContext) -> List[str]:
+        """The producer ``materialize`` drains, started now, where the
+        build side is streaming operators over one source (a filtered,
+        projected dimension): scan and programs run on that thread
+        beside the join's other side. Any other build side (an
+        aggregate, a join, an exchange beneath) is only handed the
+        call: it runs, as ever, while ``materialize`` drains it, so no
+        two operator subtrees of a query execute side by side."""
+        from .pipeline import pipeline_enabled, start_early
+        if self._materialized is not None:
+            return []
+        source = self.children[0]
+        while source._streams_child:
+            source = source.children[0]
+        if source.children or not pipeline_enabled(ctx, self):
+            return self.children[0].start_sources(ctx)
+        return start_early(ctx, self,
+                           lambda: self.children[0].execute(ctx),
+                           name="broadcast")
+
     def node_description(self) -> str:
         return "BroadcastExchange"

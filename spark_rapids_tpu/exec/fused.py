@@ -264,6 +264,8 @@ class FusedPipelineExec(TpuExec):
     stage nodes still reference their original children.
     """
 
+    _streams_child = True
+
     def __init__(self, source: TpuExec, stages: List[TpuExec],
                  use_pallas: bool = False, pallas_max_cap: int = 1 << 24,
                  donate: bool = False):
@@ -470,6 +472,8 @@ class FusedHashJoinExec(TpuExec):
     provably dead (a first launch may overflow and need the probe
     again).
     """
+
+    _streams_child = True
 
     def __init__(self, join: TpuExec, suffix: List[TpuExec],
                  use_pallas: bool = False, pallas_max_cap: int = 1 << 24,

@@ -715,28 +715,37 @@ class _HashJoinBase(TpuExec):
             return
         # unknown/multi-child operator: don't assume pass-through
 
+    def _dpp_targets(self, ctx: ExecContext):
+        """What runtime partition pruning applies to in this execution:
+        ``(probe key name, build key expression, partitioned probe-side
+        scans of that key)`` per key pair; nothing where the join cannot
+        prune."""
+        from ..conf import DPP_ENABLED
+        from ..expr.core import ColumnRef
+        if not self._dpp_capable or not ctx.conf.get(DPP_ENABLED):
+            return []
+        if self.join_type not in (INNER, LEFT_SEMI):
+            # outer/anti joins PRESERVE unmatched probe rows — pruning
+            # their files would drop them
+            return []
+        probe_child = self.children[0] if self.build_side == "right" \
+            else self.children[1]
+        targets = []
+        for pk, bk in zip(self._probe_key_exprs, self._build_key_exprs):
+            if not isinstance(pk, ColumnRef):
+                continue
+            scans = list(self._dpp_scans(probe_child, pk.name))
+            if scans:
+                targets.append((pk.name, bk, scans))
+        return targets
+
     def _runtime_partition_prune(self, ctx: ExecContext,
                                  build: ColumnarBatch) -> None:
         """Runtime DPP (GpuSubqueryBroadcastExec:1-299 +
         GpuDynamicPruningExpression role): the materialized build
         side's distinct join-key values become a partition-value filter
         on probe-side partitioned scans."""
-        from ..conf import DPP_ENABLED
-        from ..expr.core import ColumnRef
-        if not self._dpp_capable or not ctx.conf.get(DPP_ENABLED):
-            return
-        if self.join_type not in (INNER, LEFT_SEMI):
-            # outer/anti joins PRESERVE unmatched probe rows — pruning
-            # their files would drop them
-            return
-        probe_child = self.children[0] if self.build_side == "right" \
-            else self.children[1]
-        for pk, bk in zip(self._probe_key_exprs, self._build_key_exprs):
-            if not isinstance(pk, ColumnRef):
-                continue
-            scans = list(self._dpp_scans(probe_child, pk.name))
-            if not scans:
-                continue
+        for name, bk, scans in self._dpp_targets(ctx):
             kcol = bk.eval(build)
             vals, mask = kcol.to_numpy(int(build.num_rows))
             keys = {v.item() if hasattr(v, "item") else v
@@ -747,7 +756,7 @@ class _HashJoinBase(TpuExec):
                 len(scans))
             for s in scans:
                 f = dict(s.runtime_part_filter or {})
-                f[pk.name] = keys
+                f[name] = keys
                 s.runtime_part_filter = f
 
     def _join_partition(self, ctx: ExecContext, probe_stream,
@@ -797,8 +806,10 @@ class _HashJoinBase(TpuExec):
             yield from self._join_batches(ctx, probe, build, retries)
 
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
-        yield from self._join_partition(ctx, self._probe_stream(ctx),
-                                        self._build_stream(ctx))
+        from .pipeline import sources_started
+        with sources_started(ctx, self):
+            yield from self._join_partition(ctx, self._probe_stream(ctx),
+                                            self._build_stream(ctx))
 
 
 class ShuffledHashJoinExec(_HashJoinBase):
@@ -956,20 +967,39 @@ class BroadcastHashJoinExec(_HashJoinBase):
 
         The build concats ONCE (each _join_partition then no-ops its
         single-batch concat) and runtime partition pruning runs BEFORE
-        the probe side starts executing — the first pull on a probe
-        exchange drains its scans, after which a prune is too late."""
+        a probe side it can prune starts executing — the first pull on
+        a probe exchange drains its scans, after which a prune is too
+        late. A probe side it cannot prune may have been scanning since
+        the join started (``start_sources``)."""
+        from .pipeline import sources_started
         probe_child = self.children[0] if self.build_side == "right" \
             else self.children[1]
-        build = self._concat_build(ctx, self._build_stream(ctx))
-        if build is not None:
-            self._runtime_partition_prune(ctx, build)
-        for probe in probe_child.execute_partitioned(ctx):
-            if build is None:
-                yield self._measure_stream(
-                    ctx, self._empty_result(probe, ctx))
-            else:
-                yield self._measure_stream(
-                    ctx, self._join_partition(ctx, probe, iter([build])))
+        with sources_started(ctx, self):
+            build = self._concat_build(ctx, self._build_stream(ctx))
+            if build is not None:
+                self._runtime_partition_prune(ctx, build)
+            for probe in probe_child.execute_partitioned(ctx):
+                if build is None:
+                    yield self._measure_stream(
+                        ctx, self._empty_result(probe, ctx))
+                else:
+                    yield self._measure_stream(
+                        ctx, self._join_partition(ctx, probe,
+                                                  iter([build])))
+
+    def start_sources(self, ctx: ExecContext) -> List[str]:
+        """Both children's: the build side is drained first and the
+        probe side pulled only then, so started here they run beside
+        each other. Held back: a probe side whose partitioned scans the
+        build's keys are about to prune (``_runtime_partition_prune``
+        sets their file filter once the build is drained; a scan that
+        has started has listed its files)."""
+        probe_child, build_child = (self.children if self.build_side ==
+                                    "right" else self.children[::-1])
+        started = build_child.start_sources(ctx)
+        if not self._dpp_targets(ctx):
+            started = started + probe_child.start_sources(ctx)
+        return started
 
     def node_description(self) -> str:
         return (f"BroadcastHashJoin[{self.join_type}, "

@@ -24,6 +24,16 @@ Insertion points (see plan/overrides.py ``_insert_pipeline``):
   * ``BroadcastExchangeExec.materialize`` drains its child through a
     prefetcher while concat-staging runs on the consumer.
 
+When a producer starts: at its consumer's first pull, or, under a
+broadcast hash join, when the join starts (``TpuExec.start_sources``,
+``sources_started``): a join drains its build side before it pulls its
+probe side once, so producers that start at the first pull start one
+after another down a chain of joins, each behind the scan, filter and
+table build of the one before. Started with the join they run side by
+side; the producer waits on the execution's context
+(``ExecContext.early_sources``) for the node's own execution to
+continue it, and the join that started it closes it if nothing does.
+
 Correctness contract:
   * items arrive in producer order (single producer, FIFO deque);
   * a producer-side exception is re-raised on the CONSUMING thread —
@@ -78,7 +88,8 @@ from ..obs.trace import annotate
 from .base import ExecContext, Metric, Schema, TpuExec
 
 __all__ = ["PrefetchIterator", "PrefetchExec", "RunAhead",
-           "prefetch_batches", "pipeline_enabled", "prefetch_buffer_bytes",
+           "prefetch_batches", "start_early", "sources_started",
+           "pipeline_enabled", "prefetch_buffer_bytes",
            "prefetch_thread_leaks", "close_live_iterators"]
 
 # Live iterators, for the resource sampler's prefetch-occupancy gauge.
@@ -665,29 +676,12 @@ class _Unstaged:
         pass
 
 
-def prefetch_batches(ctx: ExecContext, node: TpuExec,
-                     source_factory: Callable[[], Iterable],
-                     name: str = "", stage: bool = True,
-                     affinity: Optional[str] = None) -> Iterator:
-    """Pull a ColumnarBatch stream through a background prefetcher.
-
-    Each produced batch registers with the spill catalog as an
-    ACTIVE_ON_DECK SpillableBatch while it waits in the queue (memory
-    pressure can push queued batches to host/disk instead of OOMing);
-    the consumer re-materializes (usually a no-op: still on device) and
-    releases the registration before yielding. Metrics land on
-    ``node``: prefetchWaitTime (consumer blocked on an empty queue),
-    prefetchQueueDepthPeak, prefetchBytesPeak.
-
-    ``affinity`` names what the producer works on, where that outlives
-    the plan node (a scan's files): the same thread, and so the same
-    host heap, serves it from query to query. Default: the name.
-
-    ``stage=False`` skips the SpillableBatch wrap — for streams that
-    may hand through ALREADY-owned live batches (the shuffle locality
-    bypass), where a second registration would double-count memory and
-    discard-on-close would free somebody else's batch.
-    """
+def _start_producer(ctx: ExecContext, node: TpuExec,
+                    source_factory: Callable[[], Iterable],
+                    name: str, stage: bool,
+                    affinity: Optional[str]) -> PrefetchIterator:
+    """The producer half of ``prefetch_batches``: a running
+    PrefetchIterator of staged batches, its metrics on ``node``."""
     from ..memory.spill import SpillableBatch, SpillPriority
     m = ctx.metrics_for(node.exec_id)
     wait = m.setdefault("prefetchWaitTime",
@@ -717,7 +711,7 @@ def prefetch_batches(ctx: ExecContext, node: TpuExec,
         if parent_span_id is None:
             parent_span_id = ctx.tracer.current_id()
 
-    pf = PrefetchIterator(
+    return PrefetchIterator(
         staged,
         depth=ctx.conf.get(PIPELINE_DEPTH),
         max_bytes=ctx.conf.get(PIPELINE_MAX_BYTES),
@@ -735,6 +729,37 @@ def prefetch_batches(ctx: ExecContext, node: TpuExec,
         leak_metric=leaks,
         affinity=affinity)
 
+
+def prefetch_batches(ctx: ExecContext, node: TpuExec,
+                     source_factory: Callable[[], Iterable],
+                     name: str = "", stage: bool = True,
+                     affinity: Optional[str] = None) -> Iterator:
+    """Pull a ColumnarBatch stream through a background prefetcher.
+
+    Each produced batch registers with the spill catalog as an
+    ACTIVE_ON_DECK SpillableBatch while it waits in the queue (memory
+    pressure can push queued batches to host/disk instead of OOMing);
+    the consumer re-materializes (usually a no-op: still on device) and
+    releases the registration before yielding. Metrics land on
+    ``node``: prefetchWaitTime (consumer blocked on an empty queue),
+    prefetchQueueDepthPeak, prefetchBytesPeak.
+
+    The producer starts here, unless a join above ``node`` started it
+    when the join itself started (``start_early``): then the stream
+    continues the producer that waits on ``ctx.early_sources``.
+
+    ``affinity`` names what the producer works on, where that outlives
+    the plan node (a scan's files): the same thread, and so the same
+    host heap, serves it from query to query. Default: the name.
+
+    ``stage=False`` skips the SpillableBatch wrap — for streams that
+    may hand through ALREADY-owned live batches (the shuffle locality
+    bypass), where a second registration would double-count memory and
+    discard-on-close would free somebody else's batch.
+    """
+    pf = ctx.early_sources.pop(node.exec_id, None) or _start_producer(
+        ctx, node, source_factory, name, stage, affinity)
+
     def consume() -> Iterator:
         try:
             for sb in pf:
@@ -748,6 +773,45 @@ def prefetch_batches(ctx: ExecContext, node: TpuExec,
     return consume()
 
 
+def start_early(ctx: ExecContext, node: TpuExec,
+                source_factory: Callable[[], Iterable],
+                name: str = "", affinity: Optional[str] = None
+                ) -> "list[str]":
+    """``TpuExec.start_sources`` of a node that pulls through
+    ``prefetch_batches``: start that producer now and leave it on
+    ``ctx.early_sources`` for the node's own execution to continue.
+    Depth and ``srt.exec.pipeline.maxBytesInFlight`` bound what it
+    queues meanwhile, as in steady state; an error it meets waits for
+    the first pull. Returns ``[node.exec_id]``, or nothing where the
+    node's producer already waits there. Counted in the node's
+    ``prefetchEarlyStarts``."""
+    if node.exec_id in ctx.early_sources:
+        return []
+    ctx.early_sources[node.exec_id] = _start_producer(
+        ctx, node, source_factory, name, True, affinity)
+    ctx.metrics_for(node.exec_id).setdefault(
+        "prefetchEarlyStarts",
+        Metric("prefetchEarlyStarts", Metric.MODERATE)).add(1)
+    return [node.exec_id]
+
+
+@contextlib.contextmanager
+def sources_started(ctx: ExecContext, node: TpuExec):
+    """``node`` (a join) executes inside this: its sources start at
+    entry (``node.start_sources``), and whichever of them its execution
+    never pulled — an inner join over an empty build, an error or a
+    cancel while the build drains, a LIMIT satisfied above — is closed
+    at exit: its thread parks, its queued batches are discarded."""
+    started = node.start_sources(ctx)
+    try:
+        yield
+    finally:
+        for exec_id in started:
+            pf = ctx.early_sources.pop(exec_id, None)
+            if pf is not None:
+                pf.close()
+
+
 class PrefetchExec(TpuExec):
     """Transparent pipelining node: runs its child on a background
     thread (prefetch_batches) and re-yields. Inserted by the planner
@@ -755,6 +819,8 @@ class PrefetchExec(TpuExec):
     partitioning pass through. When ``srt.exec.pipeline.enabled`` is
     off at run time (a cached plan re-run under a different conf) it
     degrades to a synchronous pass-through."""
+
+    _streams_child = True
 
     def __init__(self, child: TpuExec):
         super().__init__(child)
@@ -777,6 +843,13 @@ class PrefetchExec(TpuExec):
         # node's id: every plan over the same table shares the producer
         yield from prefetch_batches(ctx, self, lambda: child.execute(ctx),
                                     affinity=child.node_description())
+
+    def start_sources(self, ctx: ExecContext) -> "list[str]":
+        if not pipeline_enabled(ctx, self):
+            return []
+        child = self.children[0]
+        return start_early(ctx, self, lambda: child.execute(ctx),
+                           affinity=child.node_description())
 
     def node_description(self) -> str:
         return "Prefetch"

@@ -412,7 +412,8 @@ _PHASE_METRICS = {"scanDecodeTime": "scan_decode_ns",
                   "scanDecodeAheadFiles": "scan_ahead_files",
                   "scanWaitTime": "scan_wait_ns",
                   "scanTime": "scan_upload_ns",
-                  "prefetchWaitTime": "prefetch_wait_ns"}
+                  "prefetchWaitTime": "prefetch_wait_ns",
+                  "prefetchEarlyStarts": "prefetch_early_starts"}
 # the join execs' counters (exec/join.py JOIN_COUNTERS): build time, which
 # path answered each pair, capacity relaunches, host reads of device scalars
 _PHASE_METRICS.update((name, key) for name, (_, _, key)

@@ -51,13 +51,19 @@ def _run(star_schema, dpp: bool):
 
 
 def test_dpp_prunes_files_same_results(star_schema):
-    on = {r["k"]: (r["s"], r["c"]) for r in _run(star_schema, True)
-          .collect()}
-    off = {r["k"]: (r["s"], r["c"]) for r in _run(star_schema, False)
-           .collect()}
+    early = {}
+    for dpp in (True, False):
+        df = _run(star_schema, dpp)
+        rows = {r["k"]: (r["s"], r["c"]) for r in df.collect()}
+        early[dpp] = (rows, df.session._last_execution["phases"]
+                      ["prefetch_early_starts"])
+    on, off = early[True][0], early[False][0]
     assert on == off
     assert set(on) == {0, 1}
     assert all(c == 50 for _, c in on.values())
+    # the join starts its build side's producer with itself either way;
+    # the fact scan's only where no runtime filter is on its way to it
+    assert (early[True][1], early[False][1]) == (1, 2)
 
 
 def test_dpp_metric_counts_pruned_files(star_schema):
@@ -84,6 +90,12 @@ def test_dpp_metric_counts_pruned_files(star_schema):
                    if "dppPrunedFiles" in m]
     assert sum(dpp_metrics) == 6, \
         f"expected 6 pruned fact files, metrics: {dpp_metrics}"
+    # the probe side waited for the filter: its producer started at its
+    # first pull, the build side's when the join started
+    early = {exec_id.split("#")[0]: m["prefetchEarlyStarts"].value
+             for exec_id, m in ctx.metrics.items()
+             if "prefetchEarlyStarts" in m}
+    assert early == {"BroadcastExchangeExec": 1}
 
 
 def test_dpp_not_applied_to_outer_join(star_schema):

@@ -509,7 +509,8 @@ def test_program_names_are_registry_labels():
 
 PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
               "scan_decode_ns", "scan_pooled_files", "scan_ahead_files",
-              "scan_wait_ns", "scan_upload_ns", "prefetch_wait_ns", "dispatch_ns", "launches",
+              "scan_wait_ns", "scan_upload_ns", "prefetch_wait_ns", "prefetch_early_starts",
+              "dispatch_ns", "launches",
               # the join execs' counters (exec/join.py JOIN_COUNTERS)
               "join_build_ns", "lookup_join_batches", "hash_join_batches",
               "join_capacity_relaunches", "join_readbacks"}
@@ -578,6 +579,8 @@ def test_query_record_carries_phases(traced_parquet_query):
     assert totals["scanWaitTime"] == phases["scan_wait_ns"] > 0
     assert totals["scanTime"] == phases["scan_upload_ns"]
     assert totals["prefetchWaitTime"] == phases["prefetch_wait_ns"]
+    # no join in the plan: the scan's producer started at its first pull
+    assert phases["prefetch_early_starts"] == 0
 
 
 def test_host_ranges_are_in_the_profilers_trace(traced_parquet_query):

@@ -21,11 +21,14 @@ import socket
 import threading
 import time
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from spark_rapids_tpu.columnar.vector import ColumnarBatch, batch_from_pydict
 from spark_rapids_tpu.conf import SrtConf
 from spark_rapids_tpu.exec import pipeline
+from spark_rapids_tpu.exec.base import ExecContext, TpuExec
 from spark_rapids_tpu.exec.pipeline import PrefetchExec, PrefetchIterator
 from spark_rapids_tpu.plan import overrides
 from spark_rapids_tpu.plan.session import TpuSession
@@ -662,3 +665,207 @@ def test_run_ahead_stress_more_workers_than_cores():
     assert ahead.pooled == n and ahead._bytes == 0
     assert ahead._bytes_peak <= 50
     assert all(p.is_set() for p in ahead._parked)
+
+
+# ---------------------------------------------------------------------------
+# a join starts the producers beneath it when it starts (start_sources)
+# ---------------------------------------------------------------------------
+
+class _GatedSource(TpuExec):
+    """Leaf exec over pre-built batches: records each execution and the
+    thread it ran on, waits for ``gate`` before every batch, and raises
+    ``error`` (if any) in place of its first batch. ``rows``: the live
+    rows every batch claims (0: the schema and no row)."""
+
+    def __init__(self, data, nbatches=1, gate=None, error=None, rows=None):
+        super().__init__()
+        n = len(next(iter(data.values())))
+        per = -(-n // nbatches)
+        self._batches = [batch_from_pydict({k: v[i:i + per]
+                                            for k, v in data.items()})
+                         for i in range(0, n, per)]
+        if rows is not None:
+            self._batches = [ColumnarBatch(b.columns, b.names,
+                                           jnp.int32(rows))
+                             for b in self._batches]
+        self._gate, self._error = gate, error
+        self.started = threading.Event()
+        self.runs = []  # the thread of each execution
+
+    @property
+    def output_schema(self):
+        return self._batches[0].schema()
+
+    def do_execute(self, ctx):
+        self.runs.append(threading.current_thread().name)
+        self.started.set()
+        for b in self._batches:
+            if self._gate is not None:
+                assert self._gate.wait(10), "gate never opened"
+            if self._error is not None:
+                raise self._error
+            yield b
+
+
+_PROBE = {"k": [5, None, 12, 40, -3, 12, 19, 10, None, 1 << 40, 20, 11],
+          "v": list(range(12))}
+_DIMENSION = {"dk": [14, 10, 20, 12, 17, 11],
+              "name": ["n14", "n10", None, "n12", "n17", "n11"]}
+
+
+class _Opaque(TpuExec):
+    """Passes its child through, and does not say so (the base class's
+    ``_streams_child``): what a sort, an aggregate or an exchange is to
+    ``start_sources``."""
+
+    def __init__(self, child):
+        super().__init__(child)
+
+    @property
+    def output_schema(self):
+        return self.children[0].output_schema
+
+    def do_execute(self, ctx):
+        yield from self.children[0].execute(ctx)
+
+
+def _early_join(probe_src, build_src, over_build=lambda node: node):
+    """``BroadcastHashJoin(Prefetch(probe), Broadcast(Prefetch(build)))``
+    as the planner's pipelining pass leaves it."""
+    from spark_rapids_tpu.exec import BroadcastHashJoinExec
+    from spark_rapids_tpu.exec.exchange import BroadcastExchangeExec
+    from spark_rapids_tpu.expr import col
+    exchange = BroadcastExchangeExec(over_build(PrefetchExec(build_src)))
+    exchange._pipeline_ok = True
+    return BroadcastHashJoinExec(PrefetchExec(probe_src), exchange,
+                                 [col("k")], [col("dk")])
+
+
+def _early_starts(ctx, node):
+    m = ctx.metrics_for(node.exec_id).get("prefetchEarlyStarts")
+    return m.value if m is not None else 0
+
+
+def _drain(node, ctx, out):
+    try:
+        out.append(sum(int(b.num_rows) for b in node.execute(ctx)))
+    except BaseException as e:  # noqa: BLE001 — handed to the test
+        out.append(e)
+
+
+def _assert_all_parked(ctx):
+    assert ctx.early_sources == {}
+    deadline = time.monotonic() + 5.0
+    while time.monotonic() < deadline and _prefetch_threads():
+        time.sleep(0.01)
+    assert not _prefetch_threads()
+    with pipeline._LIVE_LOCK:
+        assert all(it._closed for it in pipeline._LIVE)
+
+
+def test_join_starts_its_probe_producer_before_the_build_is_drained():
+    gate = threading.Event()
+    probe, build = _GatedSource(_PROBE, nbatches=2), \
+        _GatedSource(_DIMENSION, gate=gate)
+    join = _early_join(probe, build)
+    ctx, out = ExecContext(), []
+    consumer = threading.Thread(target=_drain, args=(join, ctx, out))
+    consumer.start()
+    try:
+        # the build side has not produced its first batch (the gate is
+        # shut), and the probe side's producer is already running
+        assert probe.started.wait(10) and build.started.wait(10)
+        assert not gate.is_set() and consumer.is_alive()
+        assert probe.runs[0].startswith("srt-prefetch")
+    finally:
+        gate.set()
+        consumer.join(30)
+    assert not consumer.is_alive() and out == [5]
+    probe_prefetch, exchange = join.children
+    assert _early_starts(ctx, probe_prefetch) == 1
+    assert _early_starts(ctx, exchange) == 1
+    # the scan under the build started on the broadcast producer's
+    # thread, at that thread's first pull: not counted again
+    assert _early_starts(ctx, exchange.children[0]) == 0
+    assert len(probe.runs) == len(build.runs) == 1
+    _assert_all_parked(ctx)
+
+
+def test_only_a_streaming_build_side_runs_beside_the_probe():
+    """An operator between the exchange and its scan that may not be
+    run ahead of its consumer: the build side starts when the join
+    drains it, as before; the probe side's scan still starts early."""
+    probe, build = _GatedSource(_PROBE, nbatches=2), \
+        _GatedSource(_DIMENSION)
+    join = _early_join(probe, build, over_build=_Opaque)
+    ctx = ExecContext()
+    assert sum(int(b.num_rows) for b in join.execute(ctx)) == 5
+    exchange = join.children[1]
+    assert _early_starts(ctx, join.children[0]) == 1
+    assert _early_starts(ctx, exchange) == 0
+    assert _early_starts(ctx, exchange.children[0].children[0]) == 0
+    _assert_all_parked(ctx)
+
+
+def test_early_probe_producer_is_closed_over_an_empty_build():
+    leaks = pipeline.prefetch_thread_leaks()
+    # three probe batches under a queue of two: the producer stands in
+    # backpressure when the join ends without a pull
+    probe = _GatedSource(_PROBE, nbatches=3)
+    join = _early_join(probe, _GatedSource(_DIMENSION, rows=0))
+    ctx = ExecContext()
+    assert list(join.execute(ctx)) == []
+    probe_prefetch = join.children[0]
+    assert _early_starts(ctx, probe_prefetch) == 1 and probe.runs
+    assert "numOutputRows" not in ctx.metrics_for(probe_prefetch.exec_id)
+    assert pipeline.prefetch_thread_leaks() == leaks
+    _assert_all_parked(ctx)
+
+
+@pytest.mark.parametrize("case", ["probe error", "build error", "cancel"])
+def test_early_producers_unwind_with_a_typed_error(case):
+    from spark_rapids_tpu.robustness.admission import (QueryCancelled,
+                                                       QueryContext)
+    err = DataCorruption(f"seeded: {case}")
+    gate = threading.Event()
+    probe = _GatedSource(_PROBE, nbatches=2,
+                         error=err if case == "probe error" else None)
+    build = _GatedSource(_DIMENSION, gate=gate,
+                         error=err if case == "build error" else None)
+    join = _early_join(probe, build)
+    query = QueryContext("early-starts") if case == "cancel" else None
+    ctx, out = ExecContext(query=query), []
+    consumer = threading.Thread(target=_drain, args=(join, ctx, out))
+    consumer.start()
+    try:
+        # both producers run while the consumer waits for the build
+        assert probe.started.wait(10) and build.started.wait(10)
+        if query is not None:
+            query.cancel("test")
+    finally:
+        gate.set()
+        consumer.join(30)
+    assert not consumer.is_alive()
+    if case == "cancel":
+        assert isinstance(out[0], QueryCancelled)
+    else:  # the producer's own exception object, at the consumer
+        assert out[0] is err
+    _assert_all_parked(ctx)
+
+
+def test_a_rerun_plan_starts_fresh_producers():
+    probe, build = _GatedSource(_PROBE, nbatches=2), \
+        _GatedSource(_DIMENSION)
+    join = _early_join(probe, build)
+    for run in (1, 2):
+        join.reset_for_rerun()
+        ctx = ExecContext()
+        assert sum(int(b.num_rows) for b in join.execute(ctx)) == 5
+        assert _early_starts(ctx, join.children[0]) == 1
+        assert _early_starts(ctx, join.children[1]) == 1
+        assert len(probe.runs) == len(build.runs) == run
+        _assert_all_parked(ctx)
+    # nothing of a run is kept on the (cached) nodes
+    nodes = [join, *join.children, join.children[1].children[0]]
+    assert not [v for n in nodes for v in vars(n).values()
+                if isinstance(v, PrefetchIterator)]

@@ -159,6 +159,14 @@ def test_star_query_joins_are_lookups_over_three_batches(star, qid):
     assert runs[qid]["phases"]["lookup_join_batches"] == counters["lookupJoinBatches"]
 
 
+@pytest.mark.parametrize("qid", ["q3", "q42", "q52"])
+def test_star_query_starts_its_three_producers_with_the_joins(star, qid):
+    """The outer join starts item's ``broadcast`` producer and, through the inner join, date_dim's and the
+    fact scan's: three a query, whichever side of a join the SQL names the fact table on."""
+    _, runs = star
+    assert runs[qid]["phases"]["prefetch_early_starts"] == 3
+
+
 def test_like_queries_share_programs(star):
     """q52 after q3 registers fewer programs than q3 did: the same plan with other literals."""
     _, runs = star
