@@ -180,14 +180,19 @@ class StringColumn:
         cross-pair replication) can never overflow-truncate.
         ``unique=True`` (permutations/compactions: each source row used
         at most once) keeps the tight source-sized buffer instead —
-        total gathered bytes can't exceed the source total.
+        total gathered bytes can't exceed the source total — or the
+        first bound where that is the smaller one: a handful of rows
+        repacked out of a 2^20-row batch (a partial aggregate's groups)
+        carry ``pad_bucket`` bytes a row, not the batch's bytes, through
+        every gather below.
         """
         src_cap = self.capacity
         out_cap = indices.shape[0]
         if out_char_capacity is not None:
             nbytes_cap = out_char_capacity
         elif unique:
-            nbytes_cap = self.char_capacity
+            nbytes_cap = min(self.char_capacity,
+                             round_pow2(max(out_cap * self.pad_bucket, 128)))
         else:
             nbytes_cap = round_pow2(max(out_cap * self.pad_bucket, 128))
         safe = jnp.clip(indices, 0, src_cap - 1)
@@ -392,6 +397,41 @@ def _encode_strings(values, valid: np.ndarray, n: int):
     return lens.astype(np.int32), data
 
 
+def _string_column(lens: np.ndarray, data: np.ndarray, valid: np.ndarray,
+                   capacity: int) -> StringColumn:
+    """Host lengths + bytes (null rows zero-length) -> device column."""
+    n = len(lens)
+    offsets = np.zeros(capacity + 1, dtype=np.int32)
+    offsets[1:n + 1] = np.cumsum(lens)
+    offsets[n + 1:] = offsets[n]
+    total = int(offsets[n])
+    char_cap = max(_round_up(total, 128), 128)
+    chars = np.zeros(char_cap, dtype=np.uint8)
+    if total:
+        chars[:total] = data[:total]
+    validity = np.zeros(capacity, dtype=bool)
+    validity[:n] = valid
+    max_len = int(lens.max()) if n else 0
+    return StringColumn(jnp.asarray(offsets), jnp.asarray(chars), jnp.asarray(validity),
+                        pad_bucket=round_pow2(max_len))
+
+
+def string_column_from_utf8(offsets: np.ndarray, data: np.ndarray,
+                           valid: np.ndarray, capacity: int) -> StringColumn:
+    """A device string column from Arrow-layout host buffers
+    (``offsets`` int32[n + 1] into ``data``, as a decoder wrote them):
+    no Python object a row. Null rows must be zero-length; bytes a
+    null row carries are dropped here."""
+    n = len(valid)
+    assert capacity >= n and len(offsets) == n + 1
+    lens = np.diff(offsets).astype(np.int32)
+    data = data[offsets[0]:offsets[n]] if n else data[:0]
+    if n and not valid.all() and lens[~valid].any():
+        keep = np.repeat(valid, lens)
+        data, lens = data[keep], np.where(valid, lens, 0).astype(np.int32)
+    return _string_column(lens, data, valid, capacity)
+
+
 def column_from_numpy(values: np.ndarray, capacity: int,
                       dtype: Optional[dt.DType] = None,
                       mask: Optional[np.ndarray] = None) -> Column:
@@ -404,19 +444,7 @@ def column_from_numpy(values: np.ndarray, capacity: int,
 
     if dtype == dt.STRING:
         lens, data = _encode_strings(values, valid, n)
-        offsets = np.zeros(capacity + 1, dtype=np.int32)
-        offsets[1:n + 1] = np.cumsum(lens)
-        offsets[n + 1:] = offsets[n]
-        total = int(offsets[n])
-        char_cap = max(_round_up(total, 128), 128)
-        chars = np.zeros(char_cap, dtype=np.uint8)
-        if total:
-            chars[:total] = data[:total]
-        validity = np.zeros(capacity, dtype=bool)
-        validity[:n] = valid
-        max_len = int(lens.max()) if n else 0
-        return StringColumn(jnp.asarray(offsets), jnp.asarray(chars), jnp.asarray(validity),
-                            pad_bucket=round_pow2(max_len))
+        return _string_column(lens, data, valid, capacity)
 
     if isinstance(dtype, dt.DecimalType) and dtype.is_wide:
         from .decimal128 import from_unscaled_ints

@@ -43,6 +43,29 @@ PARTIAL = "partial"
 FINAL = "final"
 COMPLETE = "complete"
 
+#: counters of the grouped Pallas lane's programs, from the ``flags``
+#: each launch returns (``K.group_aggregate_pallas``): operator Metric
+#: -> key of the query record's ``phases`` (plan/session.py). A batch is
+#: counted in ``pallasBatches`` when the one-hot kernel took it, and in
+#: exactly one of the other two by how its groups were found.
+LANE_COUNTERS = {"pallasBatches": "pallas_batches",
+                 "groupsResolvedDirect": "groups_direct_batches",
+                 "groupsHashClaimed": "groups_hash_claim_batches"}
+
+
+def count_lane_flags(metrics: dict, flags: list) -> None:
+    """Settles the ``flags`` of a stream's grouped-lane launches into
+    ``LANE_COUNTERS`` (one device read each, after the stream: no
+    per-batch sync)."""
+    if not flags:
+        return
+    import numpy as np
+    lane, direct = np.sum([np.asarray(f) for f in flags], axis=0)
+    for name, n in (("pallasBatches", lane),
+                    ("groupsResolvedDirect", direct),
+                    ("groupsHashClaimed", len(flags) - direct)):
+        metrics.setdefault(name, Metric(name, Metric.DEBUG)).add(int(n))
+
 
 def _state_col_name(agg_index: int, state_name: str) -> str:
     return f"__agg{agg_index}__{state_name}"
@@ -153,11 +176,15 @@ class HashAggregateExec(TpuExec):
                   for fn, _ in self.agg_exprs]
         return key_cols, agg_in
 
-    def _update(self, batch: ColumnarBatch, row_offset) -> ColumnarBatch:
+    def _update(self, batch: ColumnarBatch, row_offset,
+                live=None) -> ColumnarBatch:
+        """``live``: the rows that count, where a fused chain hands its
+        filter over as a mask (exec/fused.py); the batch's prefix
+        otherwise."""
         key_cols, agg_in = self._eval_update_inputs(batch)
         key_batch, states = K.group_aggregate(
             batch, key_cols, agg_in, [fn for fn, _ in self.agg_exprs],
-            row_offset=row_offset)
+            row_offset=row_offset, live=live)
         return self._pack(key_batch, states, key_batch.num_rows,
                           batch.capacity)
 
@@ -284,18 +311,21 @@ class HashAggregateExec(TpuExec):
         return ColumnarBatch(out_cols, names, num_groups)
 
     # --- grouped pallas lane (one-hot MXU matmul partials) ---
-    def _update_pallas(self, batch: ColumnarBatch, row_offset):
-        """_update with the grouped pallas lane compiled in: the
-        <= 1024-group hash-claim fast case takes the one-hot MXU
-        kernel, everything else the stock scatter/sort path — one
-        traced program, lax.cond dispatch. Returns (packed, used)."""
+    def _update_pallas(self, batch: ColumnarBatch, row_offset, live=None):
+        """_update with the grouped pallas lane compiled in: a batch of
+        <= 1024 groups, found by comparison rounds or hash claim, takes
+        the one-hot MXU kernel, everything else the stock scatter/sort
+        path — one traced program, lax.cond dispatch. Returns (packed,
+        flags): ``K.group_aggregate_pallas``'s ``int32[2]``, lane taken
+        and groups found by the rounds."""
         key_cols, agg_in = self._eval_update_inputs(batch)
-        key_batch, states, used = K.group_aggregate_pallas(
+        key_batch, states, flags = K.group_aggregate_pallas(
             batch, key_cols, agg_in, [fn for fn, _ in self.agg_exprs],
             row_offset=row_offset,
-            max_capacity=getattr(self, "_pallas_max_cap", 1 << 24))
+            max_capacity=getattr(self, "_pallas_max_cap", 1 << 24),
+            live=live)
         return self._pack(key_batch, states, key_batch.num_rows,
-                          batch.capacity), used
+                          batch.capacity), flags
 
     def _grouped_pallas_fn(self, ctx: ExecContext):
         """The jitted grouped-lane update, or None (gate miss, either
@@ -342,11 +372,7 @@ class HashAggregateExec(TpuExec):
                                                jnp.int64(row_offset))
             row_offset += int(batch.num_rows)
             yield partial
-        if used_flags:
-            m = ctx.metrics_for(self.exec_id)
-            pb = m.setdefault("pallasBatches",
-                              Metric("pallasBatches", Metric.DEBUG))
-            pb.add(sum(int(u) for u in used_flags))
+        count_lane_flags(ctx.metrics_for(self.exec_id), used_flags)
 
     def _merge_partition(self, ctx: ExecContext, partials,
                          agg_time: Metric) -> Iterator[ColumnarBatch]:

@@ -50,6 +50,7 @@ from ..columnar.vector import ColumnarBatch
 from ..jit_registry import shared_fn_jit
 from ..jit_registry import stats as _registry_stats
 from ..ops import kernels as K
+from .aggregate import count_lane_flags
 from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
 
 #: module-level fusion tally (bench reads this + the registry's
@@ -75,7 +76,10 @@ def fusion_stats() -> dict:
 def _row_stage_fn(spec):
     """One fused stage as a batch -> batch function. Each stage traces
     under ``jax.named_scope(<the operator it replaces>)``, so inside a
-    fused program an op's ``op_name`` still says whose it is."""
+    fused program an op's ``op_name`` still says whose it is. A filter
+    here compacts (``K.filter_batch``: the batch that leaves has its
+    live rows as a prefix); in a chain that ends in an aggregate it
+    does not: see ``_masked_stage_fn``."""
     kind = spec[0]
     if kind == "filter":
         cond = spec[1]
@@ -93,12 +97,60 @@ def _row_stage_fn(spec):
     return proj
 
 
-def _agg_stage(shell, use_pallas: bool, batch, row_offset):
-    """The aggregate that ends a fused chain: (packed, pallas_used)."""
+def _masked_stage_fn(spec):
+    """A stage of a chain whose terminal is the aggregate, as
+    ``(batch, keep) -> (batch, keep)``: a filter evaluates its
+    condition over the rows as they stand and ANDs it into ``keep``,
+    the mask the aggregate applies anyway (``live``: refused rows land
+    on the scratch group with zeroed values) — no gather of every
+    column in front of an operator that reads each row once. A
+    projection passes ``keep`` through. No batch leaves the program
+    with live rows that are not a prefix: the mask ends in the
+    aggregate."""
+    if spec[0] != "filter":
+        proj = _row_stage_fn(spec)
+        return lambda batch, keep: (proj(batch), keep)
+    cond = spec[1]
+
+    def mask(batch: ColumnarBatch, keep):
+        with jax.named_scope("FilterExec"):
+            c = cond.eval(batch)
+            return batch, keep & c.data & c.validity
+    return mask
+
+
+def _masked_agg_chain(specs):
+    """The chain in front of an aggregate terminal and the aggregate, as
+    ``run(batch, row_offset) -> (packed, rows_in, flags)``. ``rows_in``
+    is what the caller advances its row offset by: the rows that
+    entered the chain (the positions order-sensitive aggregates see are
+    the rows' own, unfiltered), or 0 when the filters kept none of
+    them (the caller then emits no partial, as the unfused aggregate
+    never sees an empty batch)."""
+    stage_fns = [_masked_stage_fn(s) for s in specs[:-1]]
+    masked = any(s[0] == "filter" for s in specs[:-1])
+    shell = _agg_shell(specs[-1])
+    use_pallas = bool(specs[-1][1])
+
+    def run(batch, row_offset):
+        rows, keep = batch.num_rows, batch.live_mask() if masked else None
+        for f in stage_fns:
+            batch, keep = f(batch, keep)
+        packed, flags = _agg_stage(shell, use_pallas, batch, row_offset,
+                                   keep)
+        if masked:
+            rows = jnp.where(jnp.any(keep), rows, 0)
+        return packed, rows, flags
+    return run
+
+
+def _agg_stage(shell, use_pallas: bool, batch, row_offset, live):
+    """The aggregate that ends a fused chain: (packed, lane flags)."""
     with jax.named_scope("HashAggregateExec"):
         if use_pallas:
-            return shell._update_pallas(batch, row_offset)
-        return shell._update(batch, row_offset), jnp.bool_(False)
+            return shell._update_pallas(batch, row_offset, live)
+        return shell._update(batch, row_offset, live), \
+            jnp.zeros(2, jnp.int32)
 
 
 def _agg_shell(spec):
@@ -115,32 +167,21 @@ def _fused_program_builder(specs):
     program, a pure function of the stage specs.
 
     Non-aggregate chains: ``run(batch) -> batch``. Aggregate-terminated
-    chains: ``run(batch, row_offset) -> (packed, rows_in, pallas_used)``
-    where ``rows_in`` (rows that reached the update pass) advances the
-    caller's row_offset and ``pallas_used`` reports the grouped MXU
-    lane's per-batch engagement.
+    chains: ``run(batch, row_offset) -> (packed, rows_in, flags)``
+    (``_masked_agg_chain``): ``rows_in`` advances the caller's
+    row_offset and ``flags`` reports the grouped MXU lane's per-batch
+    engagement and how the batch's groups were found.
     """
     specs = tuple(specs)
-    terminal = specs[-1]
-    has_agg = terminal[0] == "agg"
-    stage_fns = [_row_stage_fn(s) for s in
-                 (specs[:-1] if has_agg else specs)]
-    if not has_agg:
-        def run(batch):
-            for f in stage_fns:
-                batch = f(batch)
-            return batch
-        return run
-    shell = _agg_shell(terminal)
-    use_pallas = bool(terminal[1])
+    if specs[-1][0] == "agg":
+        return _masked_agg_chain(specs)
+    stage_fns = [_row_stage_fn(s) for s in specs]
 
-    def run_agg(batch, row_offset):
+    def run(batch):
         for f in stage_fns:
             batch = f(batch)
-        rows_in = batch.num_rows
-        packed, used = _agg_stage(shell, use_pallas, batch, row_offset)
-        return packed, rows_in, used
-    return run_agg
+        return batch
+    return run
 
 
 def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
@@ -154,7 +195,7 @@ def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
 
     Non-aggregate suffixes: ``run(probe, build, *aux) -> (batch,
     total)``. Aggregate-terminated: ``run(probe, build, row_offset,
-    *aux) -> (packed, rows_in, pallas_used, total)``. ``total`` is the
+    *aux) -> (packed, rows_in, flags, total)``. ``total`` is the
     join kernel's true required output size — the host only trusts the
     suffix output when ``total <= out_capacity`` (the capacity-growth
     contract of exec/join.py, unchanged by fusion)."""
@@ -164,8 +205,7 @@ def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
                              table_size)
     specs = tuple(suffix_specs)
     has_agg = bool(specs) and specs[-1][0] == "agg"
-    stage_fns = [_row_stage_fn(s) for s in
-                 (specs[:-1] if has_agg else specs)]
+    stage_fns = [] if has_agg else [_row_stage_fn(s) for s in specs]
 
     def reorder(out: ColumnarBatch) -> ColumnarBatch:
         # kernel output is probe-then-build; plan output is
@@ -188,16 +228,12 @@ def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
                 out = f(out)
             return out, total
         return run
-    shell = _agg_shell(specs[-1])
-    use_pallas = bool(specs[-1][1])
+    suffix = _masked_agg_chain(specs)
 
     def run_agg(probe, build, row_offset, *aux):
         out, total = join(probe, build, aux)
-        for f in stage_fns:
-            out = f(out)
-        rows_in = out.num_rows
-        packed, used = _agg_stage(shell, use_pallas, out, row_offset)
-        return packed, rows_in, used, total
+        packed, rows_in, flags = suffix(out, row_offset)
+        return packed, rows_in, flags, total
     return run_agg
 
 
@@ -401,6 +437,12 @@ class FusedPipelineExec(TpuExec):
                                  Metric("fusedTime", Metric.MODERATE,
                                         "ns"))
         fused_ops.set(len(self.stages))
+        # batches whose filter went into the aggregate as its mask
+        masked = m.setdefault(
+            "aggMaskedFilterBatches",
+            Metric("aggMaskedFilterBatches", Metric.DEBUG)) \
+            if self._agg is not None and any(
+                s[0] == "filter" for s in self._specs) else None
         state = {"offset": 0}
         used_flags: List = []
         calibrated = ctx.tracer is None
@@ -413,6 +455,8 @@ class FusedPipelineExec(TpuExec):
                         batch, jnp.int64(state["offset"]))
                     n_in = int(rows_in)
                     state["offset"] += n_in
+                    if masked is not None:
+                        masked.add(1)
                     if n_in == 0:
                         # the unfused aggregate never sees (and never
                         # emits a partial for) a batch that filtered
@@ -439,10 +483,7 @@ class FusedPipelineExec(TpuExec):
                     split_policy=split_spillable_in_half_by_rows):
                 if out is not None:
                     yield out
-        if used_flags:
-            pb = m.setdefault("pallasBatches",
-                              Metric("pallasBatches", Metric.DEBUG))
-            pb.add(sum(int(u) for u in used_flags))
+        count_lane_flags(m, used_flags)
 
 
 class FusedHashJoinExec(TpuExec):
@@ -692,8 +733,6 @@ class FusedHashJoinExec(TpuExec):
             yield from self.children[0].execute(ctx)
         finally:
             st = self._exec_state
-            if st is not None and st["used"]:
-                pb = m.setdefault("pallasBatches",
-                                  Metric("pallasBatches", Metric.DEBUG))
-                pb.add(sum(int(u) for u in st["used"]))
+            if st is not None:
+                count_lane_flags(m, st["used"])
             self._exec_state = None

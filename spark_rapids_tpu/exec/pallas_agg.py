@@ -513,9 +513,12 @@ def grouped_eligible(agg_exec) -> bool:
     GpuAggregateExec.scala:175): grouping keys present and every
     aggregate sum-decomposable — Sum/Average over floats, Count,
     CountStar. The per-batch <= 1024-group bound is traced (the
-    hash-claim prelude's num_groups), so the decision between the
-    one-hot matmul and the XLA scatter path is a lax.cond inside one
-    compiled program (ops/kernels.py group_aggregate_pallas)."""
+    sort-free prelude's num_groups: comparison rounds for a handful of
+    groups, hash claim past them), so the decision between the one-hot
+    matmul and the XLA scatter path is a lax.cond inside one compiled
+    program (ops/kernels.py group_aggregate_pallas). A filter fused in
+    front reaches that program as its ``live`` mask, not as a
+    compaction (exec/fused.py _masked_agg_chain)."""
     if not agg_exec.group_exprs or agg_exec.mode == "final":
         return False
     schema = list(agg_exec.input_schema)

@@ -133,13 +133,10 @@ def _decode_native(fh, plan: _ChunkPlan, rows: int):
             break
         if got != rows:
             return None
-        blob = out_bytes[:int(offsets[rows])].tobytes()
-        vals = np.empty(rows, object)
-        mv = validity.astype(bool)
-        for k in range(rows):
-            vals[k] = blob[offsets[k]:offsets[k + 1]].decode(
-                "utf-8", "replace") if mv[k] else ""
-        return vals, mv
+        # the decoder's own buffers (null rows zero-length): the column
+        # carries them to the device as they are (HostColumn.utf8)
+        return (offsets, out_bytes[:int(offsets[rows])].copy()), \
+            validity.astype(bool)
     values = np.zeros(rows, plan.np_dtype)
     got = parquet_decode_chunk(chunk, plan.codec, plan.phys_id, rows,
                                plan.max_def, values, validity, scratch)
@@ -151,7 +148,7 @@ def _decode_native(fh, plan: _ChunkPlan, rows: int):
 def _to_host_column(values: np.ndarray, validity: np.ndarray,
                     declared: dt.DType) -> HostColumn:
     if declared == dt.STRING:
-        return HostColumn(values, validity, declared)
+        return HostColumn(None, validity, declared, utf8=values)
     phys = np.dtype(declared.physical)
     if values.dtype != phys:
         # e.g. file INT32 under a declared bigint/decimal(…,s)<=18
@@ -256,6 +253,5 @@ def iter_row_group_tables_native(
                     break
                 end = min(start + max_rows, rows)
                 yield HostTable(
-                    [HostColumn(c.values[start:end],
-                                c.mask[start:end], c.dtype)
-                     for c in ht.columns], list(ht.names))
+                    [c.slice(start, end) for c in ht.columns],
+                    list(ht.names))

@@ -79,6 +79,20 @@ def bucket_compact(batch: ColumnarBatch, key_cols, num_parts: int,
 # ---------------------------------------------------------------------------
 
 
+def _string_words(padded: jnp.ndarray) -> List[jnp.ndarray]:
+    """A padded (capacity, W) byte view as packed big-endian uint64
+    words, eight bytes a word: their order is the strings' order."""
+    cap, w = padded.shape
+    words = []
+    for b0 in range(0, w, 8):
+        word = jnp.zeros(cap, jnp.uint64)
+        for k in range(min(8, w - b0)):
+            word = word | (padded[:, b0 + k].astype(jnp.uint64)
+                           << (8 * (7 - k)))
+        words.append(word)
+    return words
+
+
 def _rank_keys(col: Column) -> List[jnp.ndarray]:
     """Lower a column to sort-key arrays whose ascending order equals SQL
     value order (most significant first). Floats sort natively (XLA's
@@ -86,18 +100,7 @@ def _rank_keys(col: Column) -> List[jnp.ndarray]:
     -0.0 are normalized); strings become packed big-endian uint64 words.
     No 64-bit bitcasts — see utils/bits.py."""
     if isinstance(col, StringColumn):
-        padded = col.padded()
-        cap, w = padded.shape
-        words = []
-        for b0 in range(0, w, 8):
-            chunk = padded[:, b0:b0 + 8]
-            if chunk.shape[1] < 8:
-                chunk = jnp.pad(chunk, ((0, 0), (0, 8 - chunk.shape[1])))
-            word = jnp.zeros(cap, jnp.uint64)
-            for k in range(8):
-                word = word | (chunk[:, k].astype(jnp.uint64) << (8 * (7 - k)))
-            words.append(word)
-        return words
+        return _string_words(col.padded())
     d = col.data
     if jnp.issubdtype(d.dtype, jnp.floating):
         d = jnp.where(d == 0.0, jnp.zeros((), d.dtype), d)
@@ -229,10 +232,14 @@ def _key_batch(key_cols, key_rows, cap, num_groups) -> ColumnarBatch:
         key_out, [f"k{i}" for i in range(len(key_out))], num_groups)
 
 
-def _prelude_exact(batch: ColumnarBatch, key_cols: Sequence[Column]):
+def _prelude_exact(batch: ColumnarBatch, key_cols: Sequence[Column],
+                   live=None):
     """Sort-based grouping (the always-correct fallback): rank-chain
-    sort, adjacent-equality boundaries, one key gather per group."""
-    live = batch.live_mask()
+    sort, adjacent-equality boundaries, one representative row per
+    group. ``live`` is the rows that count (default: the batch's
+    prefix; a fused chain passes its filter's mask, any shape)."""
+    if live is None:
+        live = batch.live_mask()
     cap = batch.capacity
     perm = sort_indices(key_cols, [True] * len(key_cols),
                         [True] * len(key_cols), live)
@@ -247,8 +254,7 @@ def _prelude_exact(batch: ColumnarBatch, key_cols: Sequence[Column]):
     gid_safe = jnp.where(live_s, gid,
                          jnp.minimum(num_groups, cap - 1).astype(jnp.int32))
     key_rows = jnp.take(perm, compaction_indices(boundary))
-    return perm, live_s, gid_safe, num_groups, \
-        _key_batch(key_cols, key_rows, cap, num_groups)
+    return perm, live_s, gid_safe, num_groups, key_rows
 
 
 # multiplicative mixers for the claim rounds (odd 64-bit constants from
@@ -257,8 +263,11 @@ _CLAIM_MIXERS = (0x9E3779B97F4A7C15, 0xC2B2AE3D27D4EB4F,
                  0x165667B19E3779F9, 0x27D4EB2F165667C5)
 
 
-def _prelude_fast(batch: ColumnarBatch, key_cols: Sequence[Column]):
-    """Sort-free hash-claim grouping.
+def _prelude_fast(batch: ColumnarBatch, key_cols: Sequence[Column],
+                  live=None):
+    """Sort-free hash-claim grouping: what finds the groups of a batch
+    that holds more of them than ``_prelude_direct``'s rounds resolve
+    (``_prelude_hashed`` decides, from the traced count).
 
     Rows claim hash-table slots by scatter-min of a 64-bit key hash
     (one table per round; losers retry under a fresh mixer). Winners of
@@ -274,7 +283,8 @@ def _prelude_fast(batch: ColumnarBatch, key_cols: Sequence[Column]):
     + gathers over static shapes.
     """
     from ..expr import hashing as H
-    live = batch.live_mask()
+    if live is None:
+        live = batch.live_mask()
     cap = batch.capacity
     h1 = jnp.full((cap,), 0x3C6EF372, jnp.uint32)
     h2 = jnp.full((cap,), 0xA54FF53A, jnp.uint32)
@@ -333,8 +343,132 @@ def _prelude_fast(batch: ColumnarBatch, key_cols: Sequence[Column]):
     ok = (~jnp.any(unresolved)) & (~jnp.any(live & ~eq))
     gid_safe = jnp.where(live, gid,
                          jnp.minimum(num_groups, cap - 1).astype(jnp.int32))
-    return ok, (arange, live, gid_safe, num_groups,
-                _key_batch(key_cols, key_rows, cap, num_groups))
+    return ok, (arange, live, gid_safe, num_groups, key_rows)
+
+
+#: rounds of ``_prelude_direct``: a batch with at most this many groups
+#: is grouped by comparison alone
+DIRECT_GROUP_ROUNDS = 8
+
+
+#: longest fixed width ``_string_key_bytes`` reads without a gather
+DENSE_KEY_WIDTHS = 8
+
+
+def _string_key_bytes(col: StringColumn) -> jnp.ndarray:
+    """``col.padded()`` without its gather where the column allows it:
+    when every row in front of the zero-length tail has the same length
+    L <= ``DENSE_KEY_WIDTHS`` (a CHAR(L) column without nulls, rows in
+    their original order), row i's bytes are ``chars[i * L:(i + 1) *
+    L]`` and the char buffer, reshaped, IS the view. Decided from the
+    offsets the program sees (``lax.switch`` over L: a reshape wants a
+    static width); anything else takes the gather."""
+    cap, w = col.capacity, col.pad_bucket
+    widths = min(w, DENSE_KEY_WIDTHS)
+    total = col.offsets[cap]
+    first = col.offsets[1] - col.offsets[0]
+    dense = (first >= 1) & (first <= widths) & jnp.all(
+        col.offsets == jnp.minimum(
+            jnp.arange(cap + 1, dtype=jnp.int32) * first, total))
+
+    def view(length: int):
+        def run(_):
+            chars = col.chars
+            if chars.shape[0] < cap * length:
+                chars = jnp.pad(chars, (0, cap * length - chars.shape[0]))
+            rows = chars[:cap * length].reshape(cap, length)
+            filled = jnp.arange(cap, dtype=jnp.int32) * length < total
+            rows = jnp.where(filled[:, None], rows, jnp.zeros((), jnp.uint8))
+            return jnp.pad(rows, ((0, 0), (0, w - length)))
+        return run
+
+    return jax.lax.switch(
+        jnp.where(dense, first, 0),
+        [lambda _: col.padded()] + [view(n) for n in range(1, widths + 1)],
+        None)
+
+
+def _prelude_direct(batch: ColumnarBatch, key_cols: Sequence[Column],
+                    live=None, rounds: int = DIRECT_GROUP_ROUNDS):
+    """Grouping by comparison, for a batch that holds a handful of
+    groups: no scatter, no sort, no row-count gather.
+
+    Each round takes the first live row no earlier round claimed and
+    compares every row's TRUE key with it, elementwise (null-safe, NaN
+    equal to NaN, strings by length and packed words: the comparison
+    ``_keys_eq_pairs`` makes, against one row instead of row by row);
+    the rows that match are that round's group. ``rounds`` rounds
+    resolve ``rounds`` groups in ``rounds`` passes over the batch; a
+    ``while_loop`` ends early when nothing is left. ``ok`` is false when
+    rows remain after the last round: the caller then takes the
+    hash-claim prelude. Exact by construction (no hash), rows stay in
+    their original order, group ids follow first appearance.
+
+    Cost a round: one ``argmax`` over ``capacity`` bools, one scalar
+    read a key word, one compare a key word. A string key's words come
+    from ``_string_key_bytes``: free for fixed-width keys, one
+    ``capacity x pad_bucket`` gather otherwise (once, not a round).
+    """
+    if live is None:
+        live = batch.live_mask()
+    cap = batch.capacity
+    keys = []  # (words, validity): a key column as comparable arrays
+    for c in key_cols:
+        if isinstance(c, StringColumn):
+            words = _string_words(_string_key_bytes(c)) + [c.lengths()]
+        else:
+            words = [c.data]
+        keys.append((words, c.validity))
+
+    def equals_row(row):
+        eq = jnp.ones(cap, jnp.bool_)
+        for words, validity in keys:
+            data_eq = jnp.ones(cap, jnp.bool_)
+            for w in words:
+                ref = w[row]
+                same = w == ref
+                if jnp.issubdtype(w.dtype, jnp.floating):
+                    same = same | (jnp.isnan(w) & jnp.isnan(ref))
+                data_eq = data_eq & same
+            eq = eq & (validity == validity[row]) & (~validity | data_eq)
+        return eq
+
+    def unfinished(state):
+        unresolved, _, _, r = state
+        return (r < rounds) & jnp.any(unresolved)
+
+    def one_round(state):
+        unresolved, gid, key_rows, r = state
+        first = jnp.argmax(unresolved).astype(jnp.int32)
+        match = unresolved & equals_row(first)
+        return (unresolved & ~match, jnp.where(match, r, gid),
+                key_rows.at[r].set(first), r + 1)
+
+    unresolved, gid, key_rows, num_groups = jax.lax.while_loop(
+        unfinished, one_round,
+        (live, jnp.zeros(cap, jnp.int32), jnp.zeros(rounds, jnp.int32),
+         jnp.int32(0)))
+    ok = ~jnp.any(unresolved)
+    gid_safe = jnp.where(live, gid,
+                         jnp.minimum(num_groups, cap - 1).astype(jnp.int32))
+    key_rows = jnp.zeros(cap, jnp.int32).at[:rounds].set(key_rows)
+    return ok, (jnp.arange(cap, dtype=jnp.int32), live, gid_safe,
+                num_groups, key_rows)
+
+
+def _prelude_hashed(batch: ColumnarBatch, key_cols: Sequence[Column],
+                    live=None):
+    """Sort-free grouping, how the groups are found following how many
+    the batch holds: the comparison rounds of ``_prelude_direct`` first;
+    when rows remain after them, the hash-claim tables of
+    ``_prelude_fast`` (inside one ``lax.cond``: only one of the two
+    runs). Returns ``(ok, direct, prelude)``: ``ok`` false sends the
+    caller to the sort path, ``direct`` says the rounds sufficed."""
+    direct, resolved = _prelude_direct(batch, key_cols, live)
+    ok, prelude = jax.lax.cond(
+        direct, lambda _: (jnp.bool_(True), resolved),
+        lambda _: _prelude_fast(batch, key_cols, live), None)
+    return ok, direct, prelude
 
 
 def _use_hash_grouping(batch: ColumnarBatch, key_cols, agg_fns) -> bool:
@@ -348,62 +482,74 @@ def _use_hash_grouping(batch: ColumnarBatch, key_cols, agg_fns) -> bool:
 
 
 def _sorted_group_prelude(batch: ColumnarBatch, key_cols: Sequence[Column],
-                          allow_hash: bool = False):
+                          live=None):
     """Sort-path grouping machinery for update and merge passes (the
-    hash-claim fast path is dispatched by group_aggregate/group_merge
-    directly so they can also skip the input gathers; ``allow_hash`` is
-    kept for signature compatibility and ignored).
+    sort-free preludes are dispatched by group_aggregate/group_merge
+    directly so they can also skip the input gathers).
 
-    Returns (perm, live_s, gid_safe, num_groups, key_batch). Dead rows
+    Returns (perm, live_s, gid_safe, num_groups, key_rows). Dead rows
     are routed to a scratch gid just past the live groups so their
     (zeroed) values never pollute a real group. Order-sensitive
     aggregates recover each row's original position from ``perm``.
     """
-    del allow_hash
-    live = batch.live_mask()
+    if live is None:
+        live = batch.live_mask()
     cap = batch.capacity
     if not key_cols:
-        # global aggregate: live rows are a prefix already — no sort
+        # global aggregate: one group of the live rows, wherever they
+        # stand — no sort
         gid, num_groups, _ = group_ids([], live)
         gid_safe = jnp.where(
             live, gid, jnp.minimum(num_groups,
                                    max(cap - 1, 0)).astype(jnp.int32))
         return (jnp.arange(cap, dtype=jnp.int32), live, gid_safe,
-                num_groups, ColumnarBatch([], [], num_groups))
-    return _prelude_exact(batch, key_cols)
+                num_groups, jnp.zeros(cap, jnp.int32))
+    return _prelude_exact(batch, key_cols, live)
+
+
+def _group_states(prelude, fast: bool, agg_inputs, agg_fns, cap,
+                  row_offset):
+    """The update pass over found groups: per-aggregate partial states."""
+    perm, live_s, gid, _, _ = prelude
+    states = []
+    for inp, fn in zip(agg_inputs, agg_fns):
+        if inp is None:
+            col_s = None
+        elif fast:
+            # sort-free prelude: rows untouched, perm is the identity —
+            # skip the (pure-overhead) identity gathers
+            col_s = inp
+        else:
+            col_s = _gather_rows(inp, perm, live_s)
+        states.append(fn.update(gid, col_s, cap, live_s,
+                                row_offset=row_offset,
+                                perm=None if fast else perm))
+    return states
 
 
 def group_aggregate(batch: ColumnarBatch, key_cols: Sequence[Column],
                     agg_inputs: Sequence[Optional[Column]], agg_fns: Sequence,
-                    row_offset=0) -> Tuple[ColumnarBatch, List[dict]]:
-    """Sort-based group-by update pass: raw rows -> per-group partial
-    states. ``row_offset`` is the stream-global position of this batch's
-    row 0, consumed by order-sensitive aggregates (first/last)."""
+                    row_offset=0, live=None
+                    ) -> Tuple[ColumnarBatch, List[dict]]:
+    """Group-by update pass: raw rows -> per-group partial states.
+    ``row_offset`` is the stream-global position of this batch's row 0,
+    consumed by order-sensitive aggregates (first/last). ``live`` is the
+    mask of the rows that count: the batch's prefix by default, the
+    prefix ANDed with a fused filter's predicate where the chain in
+    front hands its filter over instead of compacting (exec/fused.py)."""
     cap = batch.capacity
 
     def body(prelude, fast: bool):
-        perm, live_s, gid, num_groups, key_batch = prelude
-        states = []
-        for inp, fn in zip(agg_inputs, agg_fns):
-            if inp is None:
-                col_s = None
-            elif fast:
-                # hash path: rows untouched, perm is the identity —
-                # skip the (pure-overhead) identity gathers
-                col_s = inp
-            else:
-                col_s = _gather_rows(inp, perm, live_s)
-            states.append(fn.update(gid, col_s, cap, live_s,
-                                    row_offset=row_offset,
-                                    perm=None if fast else perm))
-        return key_batch, states
+        return (_key_batch(key_cols, prelude[4], cap, prelude[3]),
+                _group_states(prelude, fast, agg_inputs, agg_fns, cap,
+                              row_offset))
 
     if not _use_hash_grouping(batch, key_cols, agg_fns):
-        return body(_sorted_group_prelude(batch, key_cols, False), False)
-    ok, fast_prelude = _prelude_fast(batch, key_cols)
+        return body(_sorted_group_prelude(batch, key_cols, live), False)
+    ok, _, hashed = _prelude_hashed(batch, key_cols, live)
     return jax.lax.cond(
-        ok, lambda _: body(fast_prelude, True),
-        lambda _: body(_prelude_exact(batch, key_cols), False), None)
+        ok, lambda _: body(hashed, True),
+        lambda _: body(_prelude_exact(batch, key_cols, live), False), None)
 
 
 def pallas_group_fns_ok(agg_inputs: Sequence[Optional[Column]],
@@ -442,42 +588,67 @@ def pallas_group_fns_ok(agg_inputs: Sequence[Optional[Column]],
 _CAP_FALLBACK_WARNED = [False]
 
 
+def _key_batch_few(key_cols, key_rows, cap: int, num_groups, few: int
+                   ) -> ColumnarBatch:
+    """``_key_batch`` for at most ``few`` groups: the representatives
+    are gathered into ``few`` slots and padded to ``cap`` (the shape
+    both branches of the caller's ``lax.cond`` must share), so a string
+    key costs a ``few``-row gather, not a ``cap``-row one."""
+    if few >= cap:
+        return _key_batch(key_cols, key_rows, cap, num_groups)
+    rows, klm = key_rows[:few], live_mask(few, num_groups)
+    pad = cap - few
+    out = []
+    for c in key_cols:
+        if isinstance(c, StringColumn):
+            # distinct source rows: their bytes fit both bounds
+            nbytes = min(round_pow2(max(few * c.pad_bucket, 128)),
+                         c.char_capacity)
+            g = c.gather(rows, klm, out_char_capacity=nbytes)
+            out.append(StringColumn(
+                jnp.concatenate([g.offsets, jnp.full(pad, g.offsets[few])]),
+                jnp.pad(g.chars, (0, c.char_capacity - nbytes)),
+                jnp.pad(g.validity, (0, pad)), c.pad_bucket))
+        else:
+            g = c.gather(rows, klm)
+            out.append(ColumnVector(jnp.pad(g.data, (0, pad)),
+                                    jnp.pad(g.validity, (0, pad)), c.dtype))
+    return ColumnarBatch(out, [f"k{i}" for i in range(len(out))], num_groups)
+
+
 def group_aggregate_pallas(batch: ColumnarBatch, key_cols: Sequence[Column],
                            agg_inputs: Sequence[Optional[Column]],
                            agg_fns: Sequence, row_offset=0,
                            num_buckets: int = 1024,
-                           max_capacity: int = 1 << 24,
+                           max_capacity: int = 1 << 24, live=None,
                            ) -> Tuple[ColumnarBatch, List[dict], jnp.ndarray]:
     """Grouped update pass with the pallas one-hot MXU lane.
 
-    Same contract as :func:`group_aggregate` plus a traced ``used``
-    flag. When the hash-claim prelude resolves exactly AND the batch
-    has at most ``num_buckets`` groups, per-bucket partials come from
-    ``ops/pallas_kernels.tile_group_reduce`` (a (tile, B) one-hot
-    contracted on the MXU — no scatters); otherwise the stock
-    scatter/sort path runs inside the same ``lax.cond``. Mirrors the
-    reference's device hash groupby being THE aggregate path
+    Same contract as :func:`group_aggregate` (``live`` included: dead
+    rows, whether past the prefix or refused by a fused filter, land on
+    the scratch gid with zeroed values) plus traced ``flags``:
+    ``int32[2]``, ``flags[0]`` 1 when the lane took the batch,
+    ``flags[1]`` 1 when ``_prelude_direct``'s comparison rounds found
+    its groups (0: the hash-claim tables did). When a sort-free prelude
+    resolves exactly AND the batch has at most ``num_buckets`` groups,
+    per-bucket partials come from ``ops/pallas_kernels.tile_group_reduce``
+    (a (tile, B) one-hot contracted on the MXU — no scatters) and the
+    representatives' keys from a ``num_buckets``-row gather; otherwise
+    the stock scatter/sort path runs inside the same ``lax.cond``.
+    Mirrors the reference's device hash groupby being THE aggregate path
     (GpuAggregateExec.scala:175) rather than a special case.
 
     Callers gate with :func:`pallas_group_fns_ok` — this function
     assumes every aggregate is sum-decomposable.
     """
     cap = batch.capacity
+    if live is None:
+        live = batch.live_mask()
 
     def stock(prelude, fast: bool):
-        perm, live_s, gid, num_groups, key_batch = prelude
-        states = []
-        for inp, fn in zip(agg_inputs, agg_fns):
-            if inp is None:
-                col_s = None
-            elif fast:
-                col_s = inp
-            else:
-                col_s = _gather_rows(inp, perm, live_s)
-            states.append(fn.update(gid, col_s, cap, live_s,
-                                    row_offset=row_offset,
-                                    perm=None if fast else perm))
-        return key_batch, states
+        return (_key_batch(key_cols, prelude[4], cap, prelude[3]),
+                _group_states(prelude, fast, agg_inputs, agg_fns, cap,
+                              row_offset))
 
     # counts accumulate in float32 lanes on the MXU: a group can hold
     # at most `cap` rows, and float32 represents integers exactly only
@@ -502,12 +673,12 @@ def group_aggregate_pallas(batch: ColumnarBatch, key_cols: Sequence[Column],
                          capacity=int(cap),
                          max_capacity=int(max_capacity))
         kb, st = group_aggregate(batch, key_cols, agg_inputs, agg_fns,
-                                 row_offset)
-        return kb, st, jnp.bool_(False)
+                                 row_offset, live)
+        return kb, st, jnp.zeros(2, jnp.int32)
 
     from ..expr import aggregates as Agg
-    ok, fast_prelude = _prelude_fast(batch, key_cols)
-    _, live, gid, num_groups, key_batch = fast_prelude
+    ok, direct, hashed = _prelude_hashed(batch, key_cols, live)
+    _, _, gid, num_groups, key_rows = hashed
     small = ok & (num_groups <= num_buckets)
 
     def pallas_branch(_):
@@ -543,15 +714,17 @@ def group_aggregate_pallas(batch: ColumnarBatch, key_cols: Sequence[Column],
             else:
                 states.append({"count": to_cap(outs[i], jnp.int64)})
                 i += 1
-        return key_batch, states
+        return _key_batch_few(key_cols, key_rows, cap, num_groups,
+                              num_buckets), states
 
     def fallback(_):
         return jax.lax.cond(
-            ok, lambda __: stock(fast_prelude, True),
-            lambda __: stock(_prelude_exact(batch, key_cols), False), None)
+            ok, lambda __: stock(hashed, True),
+            lambda __: stock(_prelude_exact(batch, key_cols, live), False),
+            None)
 
     kb, st = jax.lax.cond(small, pallas_branch, fallback, None)
-    return kb, st, small
+    return kb, st, jnp.stack([small, direct]).astype(jnp.int32)
 
 
 def group_merge(batch: ColumnarBatch, key_cols: Sequence[Column],
@@ -568,7 +741,8 @@ def group_merge(batch: ColumnarBatch, key_cols: Sequence[Column],
     cap = batch.capacity
 
     def body(prelude, fast: bool):
-        perm, live_s, gid, num_groups, key_batch = prelude
+        perm, live_s, gid, num_groups, key_rows = prelude
+        key_batch = _key_batch(key_cols, key_rows, cap, num_groups)
 
         def _sort_state(v):
             from ..columnar.nested import ListColumn
@@ -584,10 +758,10 @@ def group_merge(batch: ColumnarBatch, key_cols: Sequence[Column],
         return key_batch, merged, num_groups
 
     if not _use_hash_grouping(batch, key_cols, agg_fns):
-        return body(_sorted_group_prelude(batch, key_cols, False), False)
-    ok, fast_prelude = _prelude_fast(batch, key_cols)
+        return body(_sorted_group_prelude(batch, key_cols), False)
+    ok, _, hashed = _prelude_hashed(batch, key_cols)
     return jax.lax.cond(
-        ok, lambda _: body(fast_prelude, True),
+        ok, lambda _: body(hashed, True),
         lambda _: body(_prelude_exact(batch, key_cols), False), None)
 
 

@@ -194,8 +194,10 @@ def tile_group_reduce(gid: jax.Array, values: Sequence[jax.Array],
     fallback for large key domains.
 
     ``gid``: int32[n] bucket ids in [0, num_buckets); masked-out rows
-    must carry values == 0 (sum identity) — their gid may be anything
-    in range. ``values``: 1-D float arrays. Returns one
+    (past the batch's rows, or refused by a filter the fused chain
+    handed over as the aggregate's mask) must carry values == 0 (sum
+    identity) — their gid may be anything in range; rows keep their
+    original order, whichever prelude found the groups. ``values``: 1-D float arrays. Returns one
     float64-accumulated array of shape [num_buckets] per value column;
     the caller maps buckets back to group keys.
 

@@ -23,22 +23,60 @@ import numpy as np
 from ..columnar import dtypes as dt
 from ..columnar.vector import (ColumnarBatch, ColumnVector, StringColumn,
                                choose_capacity, column_from_numpy,
-                               from_physical)
+                               from_physical, string_column_from_utf8)
 
 Schema = List  # [(name, DType), ...]
 
 
 class HostColumn:
-    __slots__ = ("values", "mask", "dtype")
+    """``values`` in the device's physical lane encoding, ``mask`` true
+    where a row is not null. A STRING column may instead carry ``utf8``:
+    ``(offsets int32[n + 1], bytes uint8[])``, the Arrow / device layout
+    as a decoder wrote it (null rows zero-length). Such a column goes
+    to the device as it is (``table_to_batch``) and concatenates and
+    slices as buffers; its object array of ``str`` is made only when
+    something reads ``values`` (the CPU operators)."""
 
-    def __init__(self, values: np.ndarray, mask: np.ndarray, dtype: dt.DType):
-        assert len(values) == len(mask)
-        self.values = values
+    __slots__ = ("_values", "mask", "dtype", "utf8")
+
+    def __init__(self, values: Optional[np.ndarray], mask: np.ndarray,
+                 dtype: dt.DType, utf8=None):
+        assert values is not None or utf8 is not None
+        assert values is None or len(values) == len(mask)
+        self._values = values
         self.mask = np.asarray(mask, dtype=bool)
         self.dtype = dtype
+        self.utf8 = utf8
+
+    @property
+    def values(self) -> np.ndarray:
+        if self._values is None:
+            import pyarrow as pa
+            offsets, data = self.utf8
+            n = len(self.mask)
+            arr = pa.Array.from_buffers(
+                pa.binary(), n, [None, pa.py_buffer(offsets),
+                                 pa.py_buffer(data)])
+            vals = np.empty(n, object)
+            vals[:] = [b.decode("utf-8", "replace")
+                       for b in arr.to_pylist()]
+            self._values = vals
+        return self._values
+
+    @values.setter
+    def values(self, values: np.ndarray) -> None:
+        self._values, self.utf8 = values, None
 
     def __len__(self):
-        return len(self.values)
+        return len(self.mask)
+
+    def slice(self, start: int, end: int) -> "HostColumn":
+        if self.utf8 is None:
+            return HostColumn(self._values[start:end], self.mask[start:end],
+                              self.dtype)
+        offsets, data = self.utf8
+        return HostColumn(None, self.mask[start:end], self.dtype,
+                          utf8=(offsets[start:end + 1], data))
 
     def take(self, idx: np.ndarray, valid: Optional[np.ndarray] = None) -> "HostColumn":
         safe = np.clip(idx, 0, max(len(self.values) - 1, 0))
@@ -109,10 +147,29 @@ def concat_tables(tables: Sequence[HostTable]) -> HostTable:
     first = tables[0]
     cols = []
     for i in range(len(first.columns)):
-        values = np.concatenate([t.columns[i].values for t in tables])
-        mask = np.concatenate([t.columns[i].mask for t in tables])
+        parts = [t.columns[i] for t in tables]
+        mask = np.concatenate([c.mask for c in parts])
+        if all(c.utf8 is not None for c in parts):
+            cols.append(HostColumn(None, mask, first.columns[i].dtype,
+                                   utf8=_concat_utf8([c.utf8 for c in parts])))
+            continue
+        values = np.concatenate([c.values for c in parts])
         cols.append(HostColumn(values, mask, first.columns[i].dtype))
     return HostTable(cols, first.names)
+
+
+def _concat_utf8(parts):
+    """(offsets, bytes) of string columns laid end to end; a part's
+    offsets may start anywhere in its bytes (a slice)."""
+    offsets = np.empty(sum(len(o) - 1 for o, _ in parts) + 1, np.int32)
+    offsets[0] = 0
+    at, base, chunks = 0, 0, []
+    for o, data in parts:
+        n = len(o) - 1
+        offsets[at + 1:at + n + 1] = o[1:] - (o[0] - base)
+        chunks.append(data[o[0]:o[n]])
+        at, base = at + n, base + int(o[n] - o[0])
+    return offsets, np.concatenate(chunks) if chunks else np.empty(0, np.uint8)
 
 
 def from_pydict(data: dict, schema: Schema) -> HostTable:
@@ -176,6 +233,8 @@ def table_to_batch(table: HostTable,
                       for i in range(len(c))]
             cols.append(nested_column_from_pylist(
                 values + [None] * (cap - n), cap, c.dtype))
+        elif c.dtype == dt.STRING and c.utf8 is not None:
+            cols.append(string_column_from_utf8(*c.utf8, c.mask, cap))
         elif c.dtype == dt.STRING:
             cols.append(column_from_numpy(
                 np.asarray(c.values, dtype=object), cap,

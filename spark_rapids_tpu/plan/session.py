@@ -17,6 +17,7 @@ import numpy as np
 from ..columnar import dtypes as dt
 from ..conf import SrtConf, active_conf, set_active_conf
 from ..exec.base import ExecContext, TpuExec
+from ..exec.aggregate import LANE_COUNTERS as _LANE_COUNTERS
 from ..exec.join import JOIN_COUNTERS as _JOIN_COUNTERS
 from ..expr.aggregates import (Average, Count, CountStar, First, Last, Max,
                                Min, StddevSamp, Sum)
@@ -418,6 +419,11 @@ _PHASE_METRICS = {"scanDecodeTime": "scan_decode_ns",
 # path answered each pair, capacity relaunches, host reads of device scalars
 _PHASE_METRICS.update((name, key) for name, (_, _, key)
                       in _JOIN_COUNTERS.items() if key)
+# the grouped aggregate: batches the Pallas lane took, how each batch's
+# groups were found (exec/aggregate.py LANE_COUNTERS), and the batches
+# whose filter a fused chain handed to the aggregate as its mask
+_PHASE_METRICS.update(_LANE_COUNTERS)
+_PHASE_METRICS["aggMaskedFilterBatches"] = "agg_masked_filter_batches"
 
 
 def _query_phases(ctx_metrics: Dict, **timed) -> Dict[str, int]:

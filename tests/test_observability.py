@@ -513,7 +513,11 @@ PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
               "dispatch_ns", "launches",
               # the join execs' counters (exec/join.py JOIN_COUNTERS)
               "join_build_ns", "lookup_join_batches", "hash_join_batches",
-              "join_capacity_relaunches", "join_readbacks"}
+              "join_capacity_relaunches", "join_readbacks",
+              # the grouped aggregate's (exec/aggregate.py LANE_COUNTERS,
+              # exec/fused.py aggMaskedFilterBatches)
+              "pallas_batches", "groups_direct_batches",
+              "groups_hash_claim_batches", "agg_masked_filter_batches"}
 
 
 @pytest.fixture(scope="module")

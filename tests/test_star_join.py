@@ -167,6 +167,18 @@ def test_star_query_starts_its_three_producers_with_the_joins(star, qid):
     assert runs[qid]["phases"]["prefetch_early_starts"] == 3
 
 
+@pytest.mark.parametrize("qid", ["q3", "q42", "q52"])
+def test_star_group_by_keeps_the_xla_branch(star, qid):
+    """The star group-by runs over a few hundred joined rows, under the 1024-row gate of the grouped Pallas
+    lane and of the sort-free preludes: no batch goes to the lane or through the comparison rounds, and no
+    filter stands between the joins and the aggregate to hand over as a mask (the answers:
+    ``test_star_query_equals_pandas_reference``)."""
+    _, runs = star
+    phases = runs[qid]["phases"]
+    assert phases["pallas_batches"] == phases["groups_direct_batches"] == 0
+    assert phases["groups_hash_claim_batches"] == phases["agg_masked_filter_batches"] == 0
+
+
 def test_like_queries_share_programs(star):
     """q52 after q3 registers fewer programs than q3 did: the same plan with other literals."""
     _, runs = star

@@ -183,6 +183,41 @@ def test_group_aggregate_int64_key(one_chip):
              _shape(one_chip, (), jnp.int32))
 
 
+def test_group_rounds_and_lane_with_string_keys(one_chip, as_on_chip):
+    """Q1's shape at a capacity that compiles in seconds: two string keys read through
+    ``_string_key_bytes``'s reshape-or-gather switch, the comparison rounds' ``while_loop`` under a fused
+    filter's mask, then the lane's branch alone (its kernel at this row count, the representatives' keys
+    from a 1024-row gather padded to the batch's capacity). The fallbacks behind them (hash claim, sort
+    path) are the programs ``test_group_aggregate_int64_key`` asks for."""
+    from spark_rapids_tpu.columnar import dtypes as dt
+    from spark_rapids_tpu.columnar.vector import (ColumnVector, ColumnarBatch,
+                                                  StringColumn)
+    from spark_rapids_tpu.ops import kernels as K
+
+    cap = 1 << 12
+
+    def f(off_a, chars_a, ok_a, off_b, chars_b, ok_b, val, num_rows, live):
+        keys = [StringColumn(off_a, chars_a, ok_a, pad_bucket=8),
+                StringColumn(off_b, chars_b, ok_b, pad_bucket=16)]
+        batch = ColumnarBatch(keys + [ColumnVector(val, ok_a, dt.FLOAT64)],
+                              ["a", "b", "v"], num_rows)
+        ok, (_, _, gid, num_groups, key_rows) = K._prelude_direct(
+            batch, keys, live)
+        sums = PK.tile_group_reduce(jnp.minimum(gid, PK.GROUP_BUCKETS - 1),
+                                    [jnp.where(live, val, 0.0)])
+        return ok, sums, K._key_batch_few(keys, key_rows, cap, num_groups,
+                                          PK.GROUP_BUCKETS)
+
+    string = [_shape(one_chip, (cap + 1,), jnp.int32),
+              _shape(one_chip, (cap,), jnp.uint8),
+              _shape(one_chip, (cap,), jnp.bool_)]
+    text = _compile(f, *string, *string,
+                    _shape(one_chip, (cap,), jnp.float64),
+                    _shape(one_chip, (), jnp.int32),
+                    _shape(one_chip, (cap,), jnp.bool_))
+    assert "tpu_custom_call" in text and "while" in text
+
+
 def test_q6_mask_filter_in_the_aggregates_program(one_chip, as_on_chip):
     """Q6 as tpch_sf1 holds it: FLOAT64 money and quantity, so on the
     chip the kernel refuses the predicate and it becomes the mask XLA
