@@ -534,12 +534,20 @@ class RunAhead:
     drops what is queued, waits for what runs, and leaves every
     borrowed thread parked. ``pooled`` counts the tasks taken from the
     pool, ``ahead`` those that were done when the consumer asked.
+
+    ``hold_until[i] >= i`` names the task whose taking gives task
+    ``i``'s cost back to the budget (default: its own): tasks that fill
+    one buffer between them (a scan batch's files) stay on the budget
+    until the consumer has the last of them. Such a run of tasks must
+    fit the budget together, or the consumer would wait for one the
+    budget never admits.
     """
 
     def __init__(self, tasks: "list[tuple[int, Callable[[], Iterator]]]",
                  threads: int, max_bytes: int,
                  conf: Optional[SrtConf] = None, query=None,
-                 name: str = "ahead", affinity: Optional[str] = None):
+                 name: str = "ahead", affinity: Optional[str] = None,
+                 hold_until: "Optional[list[int]]" = None):
         from ..robustness import faults
         self._tasks = tasks
         self._max_bytes = max(int(max_bytes), 0)
@@ -552,6 +560,8 @@ class RunAhead:
         self._done: dict = {}  # task index -> (items, error)
         self._bytes = 0
         self._bytes_peak = 0
+        self._hold_until = hold_until or list(range(len(tasks)))
+        self._held = 0  # taken, still on the budget
         self._stopped = False
         self.pooled = 0
         self.ahead = 0
@@ -622,7 +632,10 @@ class RunAhead:
                 while i not in self._done:
                     self._cv.wait()
                 items, error = self._done.pop(i)
-                self._bytes -= cost
+                self._held += cost
+                if self._hold_until[i] <= i:
+                    self._bytes -= self._held
+                    self._held = 0
                 self._admit()
             for item in items:
                 yield i, item

@@ -58,6 +58,16 @@ def _declared_ok(t: dt.DType) -> bool:
     return True
 
 
+def placed_lanes(schema) -> List[Tuple[str, np.dtype]]:
+    """``(name, numpy dtype)`` of the columns the decoder can write
+    straight into a batch's own buffers: the fixed-width lanes of this
+    envelope. Strings have no place known before their bytes are read;
+    they, and the columns pyarrow decodes (booleans among them), are
+    assembled as before."""
+    return [(n, np.dtype(t.physical)) for n, t in schema
+            if t not in (dt.STRING, dt.BOOL) and _declared_ok(t)]
+
+
 class _ChunkPlan:
     __slots__ = ("col_idx", "phys_id", "np_dtype", "codec", "max_def",
                  "offset", "length", "scratch")
@@ -109,13 +119,19 @@ def _plan_chunk(pf: "pq.ParquetFile", rg: int, col_idx: int,
                       int(ct.total_compressed_size), scratch)
 
 
-def _decode_native(fh, plan: _ChunkPlan, rows: int):
+def _decode_native(fh, plan: _ChunkPlan, rows: int, out=None):
     """-> (values ndarray, validity bool ndarray) or None on any
-    decoder error (falls back)."""
+    decoder error (falls back). ``out``: the ``rows`` rows of a batch's
+    own buffers ``(values, validity)`` this chunk has its place in; the
+    decoder writes every row of both (zeros under nulls), a file type
+    narrower than the buffer's is cast into it."""
     from ..native import parquet_decode_chunk, parquet_decode_chunk_binary
     fh.seek(plan.offset)
     chunk = fh.read(plan.length)
-    validity = np.zeros(rows, np.uint8)
+    # the decoder writes one byte a row, 0 or 1: a bool array's bytes
+    validity = np.zeros(rows, bool) \
+        if out is None or plan.phys_id == _PHYS_BINARY else out[1]
+    valid_u8 = validity.view(np.uint8)
     scratch = np.empty(plan.scratch, np.uint8)
     if plan.phys_id == _PHYS_BINARY:
         offsets = np.zeros(rows + 1, np.int32)
@@ -126,7 +142,7 @@ def _decode_native(fh, plan: _ChunkPlan, rows: int):
             out_bytes = np.empty(cap, np.uint8)
             got = parquet_decode_chunk_binary(
                 chunk, plan.codec, rows, plan.max_def, offsets,
-                out_bytes, validity, scratch)
+                out_bytes, valid_u8, scratch)
             if got == -3 and attempt == 0:
                 cap *= 4
                 continue
@@ -135,14 +151,17 @@ def _decode_native(fh, plan: _ChunkPlan, rows: int):
             return None
         # the decoder's own buffers (null rows zero-length): the column
         # carries them to the device as they are (HostColumn.utf8)
-        return (offsets, out_bytes[:int(offsets[rows])].copy()), \
-            validity.astype(bool)
-    values = np.zeros(rows, plan.np_dtype)
+        return (offsets, out_bytes[:int(offsets[rows])].copy()), validity
+    values = out[0] if out is not None and out[0].dtype == plan.np_dtype \
+        else np.zeros(rows, plan.np_dtype)
     got = parquet_decode_chunk(chunk, plan.codec, plan.phys_id, rows,
-                               plan.max_def, values, validity, scratch)
+                               plan.max_def, values, valid_u8, scratch)
     if got != rows:
         return None
-    return values, validity.astype(bool)
+    if out is not None and values is not out[0]:
+        out[0][:] = values
+        values = out[0]
+    return values, validity
 
 
 def _to_host_column(values: np.ndarray, validity: np.ndarray,
@@ -157,12 +176,13 @@ def _to_host_column(values: np.ndarray, validity: np.ndarray,
 
 
 def _decode_row_group(pf, fh, rg: int, rows: int, want, file_cols,
-                      declared, options=None):
+                      declared, options=None, dest=None):
     native: Dict[str, Tuple[np.ndarray, np.ndarray]] = {}
     fallback: List[str] = []
     for name in want:
         plan = _plan_chunk(pf, rg, file_cols[name], declared[name])
-        out = _decode_native(fh, plan, rows) if plan else None
+        out = _decode_native(fh, plan, rows, (dest or {}).get(name)) \
+            if plan else None
         if out is None:
             fallback.append(name)
         else:
@@ -193,10 +213,20 @@ def _decode_row_group(pf, fh, rg: int, rows: int, want, file_cols,
 
 def iter_row_group_tables_native(
         path: str, schema, options: dict, max_rows: int,
-        partition_values: Optional[dict]) -> Iterator[HostTable]:
+        partition_values: Optional[dict],
+        dest: Optional[dict] = None) -> Iterator[HostTable]:
     """Row-group-chunked HostTables with per-column native decode.
     Raises on structural mismatch — the caller catches and reruns the
-    pyarrow path."""
+    pyarrow path.
+
+    ``dest``: ``{column: (values, validity)}``, this file's rows of a
+    batch's own buffers (io/scan.py ``_PlacedBatch``), for the
+    fixed-width columns. Row groups decode into them one after another;
+    a table all of whose ``dest`` columns ARE those rows says so in
+    ``placed`` (its first row's place among the file's). A column or a
+    row group that went through pyarrow, or a rebase that rewrote a
+    lane, leaves ``placed`` None, and the rows it could not use
+    unread."""
     from .scan import _apply_read_rebase
     declared: Dict[str, dt.DType] = dict(schema)
     part_names = set((partition_values or {}).keys())
@@ -206,13 +236,17 @@ def iter_row_group_tables_native(
             if n in file_cols and n not in part_names]
     if pf.metadata.num_row_groups == 0:
         raise ValueError("no row groups")  # fallback handles empties
+    at = 0
     with open(path, "rb") as fh:
         for rg in range(pf.metadata.num_row_groups):
             rows = pf.metadata.row_group(rg).num_rows
+            here = {n: (v[at:at + rows], m[at:at + rows])
+                    for n, (v, m) in (dest or {}).items()
+                    if at + rows <= len(v)}
             try:
                 cols, names = _decode_row_group(pf, fh, rg, rows, want,
                                                 file_cols, declared,
-                                                options)
+                                                options, here)
             except Exception:
                 # per-ROW-GROUP fallback: earlier row groups already
                 # streamed out, so this one must be recovered in place
@@ -236,17 +270,21 @@ def iter_row_group_tables_native(
                 if name not in part_names:
                     raise ValueError(f"column {name} missing from file")
                 v = (partition_values or {}).get(name)
-                mask = np.full(rows, v is not None)
-                if t == dt.STRING:
-                    vals = np.full(rows, v if v is not None else "",
-                                   dtype=object)
-                else:
-                    phys = np.dtype(t.physical)
-                    vals = np.full(rows, v if v is not None else 0,
-                                   dtype=phys)
+                vals, mask = here.get(name) or (
+                    np.empty(rows, object if t == dt.STRING
+                             else np.dtype(t.physical)),
+                    np.empty(rows, bool))
+                vals[:] = v if v is not None else \
+                    "" if t == dt.STRING else 0
+                mask[:] = v is not None
                 out_cols.append(HostColumn(vals, mask, t))
             ht = HostTable(out_cols, out_names)
             _apply_read_rebase(ht, options)
+            if here and rows <= max_rows and all(
+                    c._values is here[n][0]
+                    for n, c in zip(out_names, out_cols) if n in here):
+                ht.placed = at
+            at += rows
             for start in range(0, rows, max_rows):
                 if start == 0 and rows <= max_rows:
                     yield ht

@@ -31,6 +31,7 @@ import glob as globlib
 import logging
 import os
 import struct as structlib
+import threading
 import time
 from typing import Iterator, List, Optional, Sequence
 
@@ -38,7 +39,7 @@ import numpy as np
 import pyarrow as pa
 
 from ..columnar import dtypes as dt
-from ..columnar.vector import ColumnarBatch
+from ..columnar.vector import ColumnarBatch, choose_capacity
 from ..conf import (MAX_READER_BATCH_SIZE_ROWS, PIPELINE_MAX_BYTES,
                     READER_THREADS, READER_TYPE)
 from ..exec.base import ExecContext, Metric, Schema, TpuExec
@@ -46,8 +47,8 @@ from ..exec.pipeline import RunAhead
 from ..expr import core as E
 from ..expr import predicates as P
 from ..obs.trace import annotate
-from ..plan.host_table import (HostTable, concat_tables, empty_like,
-                               table_to_batch)
+from ..plan.host_table import (HostColumn, HostTable, concat_tables,
+                               empty_like, table_to_batch)
 from ..plan.logical import LogicalPlan
 from ..robustness.faults import fault_point
 from ..robustness.integrity import DataCorruption
@@ -505,7 +506,8 @@ def _timed_decode(tables: Iterator[HostTable], decode_time
 def iter_file_tables(path: str, fmt: str, schema: Schema,
                      options: dict, arrow_filter,
                      max_rows: int, conf=None,
-                     partition_values: Optional[dict] = None
+                     partition_values: Optional[dict] = None,
+                     dest: Optional[dict] = None
                      ) -> Iterator[HostTable]:
     """Path-naming wrapper over :func:`_iter_file_tables`: any decode
     error is re-raised with the failing file's path prepended (same
@@ -518,7 +520,11 @@ def iter_file_tables(path: str, fmt: str, schema: Schema,
     planning and read, and ``srt.sql.ignoreCorruptFiles`` swallows
     decode/checksum failures — both skip-and-warn, keeping any rows the
     file already yielded (FilePartitionReader.ignoreCorruptFiles
-    contract). Default for both is false: fail fast."""
+    contract). Default for both is false: fail fast.
+
+    ``dest``: this file's rows of its batch's own buffers, where the
+    scan laid its batches out (``_PlacedBatch``); the native parquet
+    lane decodes into them and marks the tables it placed."""
     from ..conf import (IGNORE_CORRUPT_FILES, IGNORE_MISSING_FILES,
                         active_conf)
     cnf = conf or active_conf()
@@ -526,7 +532,7 @@ def iter_file_tables(path: str, fmt: str, schema: Schema,
         fault_point("scan.file", detail=path)
         yield from _timed_decode(
             _named_file_tables(path, fmt, schema, options, arrow_filter,
-                               max_rows, conf, partition_values),
+                               max_rows, conf, partition_values, dest),
             (options or {}).get("_decode_time"))
     except Exception as e:
         if _is_missing_file_error(e):
@@ -547,12 +553,13 @@ def iter_file_tables(path: str, fmt: str, schema: Schema,
 def _named_file_tables(path: str, fmt: str, schema: Schema,
                        options: dict, arrow_filter,
                        max_rows: int, conf=None,
-                       partition_values: Optional[dict] = None
+                       partition_values: Optional[dict] = None,
+                       dest: Optional[dict] = None
                        ) -> Iterator[HostTable]:
     try:
         yield from _iter_file_tables(path, fmt, schema, options,
                                      arrow_filter, max_rows, conf,
-                                     partition_values)
+                                     partition_values, dest)
     except Exception as e:
         if path not in str(e):
             if isinstance(e, OSError):
@@ -575,10 +582,29 @@ def _named_file_tables(path: str, fmt: str, schema: Schema,
         raise
 
 
+def _native_parquet(conf, options) -> bool:
+    """Whether a parquet file of this scan takes the native decode lane
+    first. Default-on only when a real accelerator consumes the batches:
+    the native path decodes EVERY row (the device filter is ~free on
+    TPU); on the CPU-emulation backend pyarrow's row-level filter
+    pushdown wins, so the default follows the backend (an explicit
+    setting is always honored)."""
+    from ..conf import PARQUET_NATIVE_DECODE, active_conf
+    c = conf or active_conf()
+    if not c.get(PARQUET_NATIVE_DECODE) or \
+            (options or {}).get("__force_arrow_decode"):
+        return False
+    if PARQUET_NATIVE_DECODE.key not in c._settings:
+        import jax
+        return jax.default_backend() != "cpu"
+    return True
+
+
 def _iter_file_tables(path: str, fmt: str, schema: Schema,
                       options: dict, arrow_filter,
                       max_rows: int, conf=None,
-                      partition_values: Optional[dict] = None
+                      partition_values: Optional[dict] = None,
+                      dest: Optional[dict] = None
                       ) -> Iterator[HostTable]:
     """Decode one file on the host into row-sliced HostTables conforming
     to the DECLARED schema: positional rename when file column names
@@ -623,20 +649,7 @@ def _iter_file_tables(path: str, fmt: str, schema: Schema,
     path = resolve_read_path(path, conf)
     names = [n for n, _ in schema]
     if fmt == "parquet":
-        from ..conf import PARQUET_NATIVE_DECODE, active_conf
-        c = conf or active_conf()
-        use_native = c.get(PARQUET_NATIVE_DECODE) and \
-            not (options or {}).get("__force_arrow_decode")
-        if use_native and \
-                PARQUET_NATIVE_DECODE.key not in c._settings:
-            # default-on only when a real accelerator consumes the
-            # batches: the native path decodes EVERY row (the device
-            # filter is ~free on TPU); on the CPU-emulation backend
-            # pyarrow's row-level filter pushdown wins, so the default
-            # follows the backend (explicit setting always honored)
-            import jax
-            use_native = jax.default_backend() != "cpu"
-        if use_native:
+        if _native_parquet(conf, options):
             # native column-chunk decode (C++, GIL-free). Fallback to
             # the arrow path happens ONLY before the first table is
             # yielded (setup/footer surprises); after that, per-row-
@@ -656,7 +669,8 @@ def _iter_file_tables(path: str, fmt: str, schema: Schema,
             first = None
             try:
                 it = iter_row_group_tables_native(
-                    path, schema, options, max_rows, partition_values)
+                    path, schema, options, max_rows, partition_values,
+                    dest)
                 first = next(it, None)
             except Exception:
                 failed = True
@@ -809,33 +823,159 @@ def _conform(table: "pa.Table", schema: Schema) -> "pa.Table":
     return table
 
 
-def _decoded_bytes(path: str, fmt: str, schema: Schema) -> int:
-    """What a file's tables will hold once decoded, for the reader
-    pool's byte budget: rows (from the footer, where the format has one)
-    times the schema's fixed widths, plus the file's uncompressed size
-    when a column is of variable width. Formats without a footer count
-    their size on disk. A file that cannot be sized counts 0: decoding
-    it will say what is wrong with it, in file order."""
+def _footprint(path: str, fmt: str, schema: Schema):
+    """``(bytes, row groups)`` of one file, from its footer. Bytes: what
+    its tables will hold once decoded, for the reader pool's byte
+    budget: rows (where the format has a footer) times the schema's
+    fixed widths, plus the file's uncompressed size when a column is of
+    variable width; formats without a footer count their size on disk.
+    Row groups: a parquet file's row counts, else None. A file that
+    cannot be sized counts ``(0, None)``: decoding it will say what is
+    wrong with it, in file order."""
+    groups = None
     try:
         size = os.path.getsize(path)
         if fmt == "parquet":
             import pyarrow.parquet as pq
             md = pq.read_metadata(path)
             rows = md.num_rows
-            size = sum(md.row_group(i).total_byte_size
-                       for i in range(md.num_row_groups))
+            meta = [md.row_group(i) for i in range(md.num_row_groups)]
+            size = sum(g.total_byte_size for g in meta)
+            groups = [g.num_rows for g in meta]
         elif fmt == "orc":
             import pyarrow.orc as orc
             rows = orc.ORCFile(path).nrows
         else:
-            return size
+            return size, None
     except _CORRUPT_ERRORS:
-        return 0
+        return 0, None
     # the host representation of each column: fixed width, or objects
     kinds = [c.values.dtype for c in empty_like(schema).columns]
     fixed = sum(k.itemsize + 1 for k in kinds if k != object)
     var = sum(k == object for k in kinds)
-    return rows * fixed + (size + rows * 9 * var if var else 0)
+    return rows * fixed + (size + rows * 9 * var if var else 0), groups
+
+
+class _PlacedBatch:
+    """One batch of a scan that laid its batches out from the footers:
+    files ``lo..hi`` of the scan, file ``i``'s rows from ``starts[i]``
+    on, in buffers of the batch's capacity, one ``(values, validity)``
+    pair for each column of ``lanes`` (name, numpy dtype). The buffers
+    are the batch's own: made when the first of its files begins to
+    decode, on that reader thread, written once by the decoders (every
+    row below ``rows``; zero past it), handed to the upload as they are
+    and dropped here (``take``)."""
+
+    def __init__(self, lo: int, lanes):
+        self.lo = self.hi = lo
+        self.rows = 0
+        self.lanes = lanes
+        self.starts: dict = {}
+        #: file -> [(first row among the file's, rows)], a table each
+        self.tables: dict = {}
+        self._lock = threading.Lock()
+        self._buffers = None
+
+    @property
+    def capacity(self) -> int:
+        return choose_capacity(self.rows)
+
+    @property
+    def pad_bytes(self) -> int:
+        return (self.capacity - self.rows) * sum(
+            k.itemsize + 1 for _, k in self.lanes)
+
+    def add(self, i: int, groups) -> None:
+        self.hi = i + 1
+        self.starts[i] = self.rows
+        self.tables[i] = [(sum(groups[:k]), r)
+                          for k, r in enumerate(groups) if r]
+        self.rows += sum(groups)
+
+    def dest(self, i: int) -> dict:
+        """File ``i``'s rows of the buffers, for its decoder."""
+        with self._lock:
+            if self._buffers is None:
+                cap, n = self.capacity, self.rows
+                self._buffers = {}
+                for name, kind in self.lanes:
+                    values, valid = np.empty(cap, kind), np.empty(cap, bool)
+                    values[n:], valid[n:] = 0, False
+                    self._buffers[name] = (values, valid)
+            lo = self.starts[i]
+            hi = lo + sum(r for _, r in self.tables[i])
+            return {name: (v[lo:hi], m[lo:hi])
+                    for name, (v, m) in self._buffers.items()}
+
+    def take(self, got) -> Optional[HostTable]:
+        """The batch as one table over its buffers, once every file
+        delivered what its footer promised, each table in its place
+        (``HostTable.placed``); None where one did not. ``got``:
+        ``(file, table)`` as the files delivered them. Columns without a
+        lane (strings) are laid end to end as in any coalesced batch."""
+        with self._lock:
+            buffers, self._buffers = self._buffers, None
+        tables = [t for _, t in got if t.num_rows]
+        if buffers is None or [
+                (i, t.placed, t.num_rows) for i, t in got if t.num_rows] \
+                != [(i, at, r) for i in range(self.lo, self.hi)
+                    for at, r in self.tables[i]]:
+            return None
+        names = tables[0].names
+        loose = [j for j, name in enumerate(names) if name not in buffers]
+        joined = concat_tables(
+            [HostTable([t.columns[j] for j in loose],
+                       [names[j] for j in loose]) for t in tables]) \
+            if loose else None
+        cols = []
+        for name, c in zip(names, tables[0].columns):
+            if name in buffers:
+                v, m = buffers[name]
+                cols.append(HostColumn(v[:self.rows], m[:self.rows],
+                                       c.dtype, padded=(v, m)))
+            else:
+                cols.append(joined.column(name))
+        return HostTable(cols, names)
+
+
+def _lay_out(groups, costs, reader: str, max_rows: int, max_bytes: int,
+             lanes) -> dict:
+    """``{file: _PlacedBatch}`` for the files whose batch can be decoded
+    in place. ``groups[i]``: file ``i``'s row groups' rows (None:
+    unknown). The batches are today's, by today's rule, which runs over
+    the tables a file yields (a row group, cut at ``max_rows``):
+    COALESCING adds tables until ``rows >= max_rows`` and flushes,
+    MULTITHREADED makes a batch of each. A batch is placed when it has
+    rows, each of its files lies in it whole with a footer that says
+    where, and its files' costs plus the padding fit the budget alone;
+    the other files are read as before."""
+    batches, cur, rows = [], [], 0
+    for i, rgs in enumerate(groups):
+        for r in [min(max_rows, g - at) for g in rgs or ()
+                  for at in range(0, g, max_rows)] or [0]:
+            cur.append(i)
+            rows += r
+            if reader == "MULTITHREADED" or rows >= max_rows:
+                batches.append(cur)
+                cur, rows = [], 0
+    if cur:
+        batches.append(cur)
+    seen = {}
+    for b, files in enumerate(batches):
+        for i in files:
+            seen.setdefault(i, set()).add(b)
+    placed = {}
+    for files in batches:
+        files = sorted(set(files))
+        if any(groups[i] is None or len(seen[i]) > 1 for i in files):
+            continue
+        batch = _PlacedBatch(files[0], lanes)
+        for i in files:
+            batch.add(i, groups[i])
+        if batch.rows and batch.pad_bytes + sum(
+                costs[i] for i in files) <= max_bytes:
+            placed.update((i, batch) for i in files)
+    return placed
 
 
 class FileSourceScanExec(TpuExec):
@@ -884,7 +1024,8 @@ class FileSourceScanExec(TpuExec):
         # flushes them into scan metrics
         stats = self._decode_stats = {
             "native_files": 0, "host_files": 0, "host_columns": 0,
-            "pooled_files": 0, "ahead_files": 0}
+            "pooled_files": 0, "ahead_files": 0,
+            "batches": 0, "inplace_batches": 0}
         options["_decode_stats"] = stats
         # read + decode + conform of every file, timed where it runs
         # (iter_file_tables, on the pool's threads): thread time
@@ -915,22 +1056,11 @@ class FileSourceScanExec(TpuExec):
         def pv(p):
             return self.scan.partition_values_for(p)
         if reader in ("COALESCING", "MULTITHREADED") and len(scan_paths) > 1:
-            files = self._decoded_files(ctx, scan_paths, args, pv)
+            files, placed = self._decoded_files(ctx, scan_paths, args, pv,
+                                                reader)
             try:
-                if reader == "MULTITHREADED":
-                    for i, t in files:
-                        yield scan_paths[i], t
-                    return
-                pending: List[HostTable] = []
-                rows = 0
-                for _, t in files:
-                    pending.append(t)
-                    rows += t.num_rows
-                    if rows >= max_rows:
-                        yield None, concat_tables(pending)
-                        pending, rows = [], 0
-                if pending:
-                    yield None, concat_tables(pending)
+                yield from self._batch_tables(files, placed, scan_paths,
+                                              reader, max_rows, stats)
             finally:
                 files.close()
                 stats["pooled_files"] += files.pooled
@@ -940,8 +1070,44 @@ class FileSourceScanExec(TpuExec):
                 for t in iter_file_tables(p, *args, pv(p)):
                     yield p, t
 
+    @staticmethod
+    def _batch_tables(files, placed: dict, scan_paths: List[str],
+                      reader: str, max_rows: int, stats: dict):
+        """``(path or None, table)`` a batch, from the pool's ``(file,
+        table)`` stream. A placed batch (``_lay_out``) is the table over
+        its own buffers once its files have all delivered in place;
+        where one did not, and for every other file, the batch is
+        today's: a table a batch (MULTITHREADED), or tables laid end to
+        end until ``rows >= max_rows`` (COALESCING)."""
+        def flush(tables):
+            if reader == "MULTITHREADED":
+                return [(scan_paths[i], t) for i, t in tables]
+            return [(None, concat_tables([t for _, t in tables]))] \
+                if tables else []
+
+        def finish(batch, got):
+            table = batch.take(got)
+            if table is None:
+                return flush(got)
+            stats["inplace_batches"] += 1
+            return [(scan_paths[batch.lo] if reader == "MULTITHREADED"
+                     else None, table)]
+
+        batch, got, rows = None, [], 0
+        for i, t in files:
+            if placed.get(i) is not batch:
+                yield from finish(batch, got) if batch else flush(got)
+                batch, got, rows = placed.get(i), [], 0
+            got.append((i, t))
+            rows += t.num_rows
+            if batch is None and (reader == "MULTITHREADED"
+                                  or rows >= max_rows):
+                yield from flush(got)
+                got, rows = [], 0
+        yield from finish(batch, got) if batch else flush(got)
+
     def _decoded_files(self, ctx: ExecContext, scan_paths: List[str],
-                       args: tuple, pv) -> RunAhead:
+                       args: tuple, pv, reader: str):
         """The tables of ``scan_paths`` in file order, as ``(index of the
         file, HostTable)``, decoded ahead of this thread on the kept
         reader threads (exec/pipeline.py ``RunAhead``): at most
@@ -950,18 +1116,45 @@ class FileSourceScanExec(TpuExec):
         srt.exec.pipeline.maxBytesInFlight, each file sized from its
         footer. A file over that budget alone streams through this
         thread row group by row group, as every file of a PERFILE scan
-        does, so it never materialises whole."""
-        conf = ctx.conf
+        does, so it never materialises whole.
 
-        def task(p):
-            return (_decoded_bytes(p, self.scan.fmt, self._schema),
-                    lambda: iter_file_tables(p, *args, pv(p)))
+        With them ``{file: _PlacedBatch}``: where parquet files go
+        through the native lane, the footers also say which batch each
+        file lands in and where, so a file decodes into its rows of that
+        batch's buffers (``_lay_out``). Such a file's bytes stay on the
+        budget until its batch's last file is taken, and the first file
+        of a batch carries the padding."""
+        conf = ctx.conf
+        fmt, schema, options, _, max_rows, _ = args
+        sized = [_footprint(p, fmt, schema) for p in scan_paths]
+        costs = [cost for cost, _ in sized]
+        max_bytes = conf.get(PIPELINE_MAX_BYTES)
+        placed = {}
+        if fmt == "parquet" and _native_parquet(conf, options) and \
+                not options.get("__iceberg_pos_deletes"):
+            from .native_parquet import placed_lanes
+            lanes = placed_lanes(schema)
+            if lanes:
+                placed = _lay_out([g for _, g in sized], costs, reader,
+                                  max_rows, max_bytes, lanes)
+        for i, batch in placed.items():
+            if i == batch.lo:
+                costs[i] += batch.pad_bytes
+
+        def task(i, p):
+            batch = placed.get(i)
+            if batch is None:
+                return costs[i], lambda: iter_file_tables(p, *args, pv(p))
+            return costs[i], lambda: iter_file_tables(
+                p, *args, pv(p), batch.dest(i))
         return RunAhead(
-            [task(p) for p in scan_paths],
+            [task(i, p) for i, p in enumerate(scan_paths)],
             threads=min(conf.get(READER_THREADS), os.cpu_count() or 1),
-            max_bytes=conf.get(PIPELINE_MAX_BYTES), conf=conf,
+            max_bytes=max_bytes, conf=conf,
             query=ctx.query, name=f"decode-{self.exec_id}",
-            affinity=f"decode:{scan_paths[0]}")
+            affinity=f"decode:{scan_paths[0]}",
+            hold_until=[placed[i].hi - 1 if i in placed else i
+                        for i in range(len(scan_paths))]), placed
 
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         m = ctx.metrics_for(self.exec_id)
@@ -974,12 +1167,14 @@ class FileSourceScanExec(TpuExec):
         empty = True
         sizes = {}
         tables = self._host_tables(ctx)
+        batches = 0
         try:
             while True:
-                # this thread stands waiting for the next decoded table:
-                # on the reader pool's next file (and the concatenation
-                # of a batch's files), or decoding inline where the scan
-                # has no pool (one file, PERFILE, a file over the budget)
+                # this thread stands waiting for the next batch's table:
+                # on the reader pool for the batch's files (and, where
+                # they did not decode into the batch's own buffers, their
+                # concatenation), or decoding inline where the scan has
+                # no pool (one file, PERFILE, a file over the budget)
                 t0 = time.perf_counter_ns()
                 with annotate("scan.wait"):
                     item = next(tables, None)
@@ -1007,6 +1202,7 @@ class FileSourceScanExec(TpuExec):
                     set_input_file(path, 0, sizes[path])
                 else:
                     set_input_file(None)
+                batches += 1
                 yield batch
         finally:
             # an abandoned scan (LocalLimit, error unwind) parks its
@@ -1014,12 +1210,15 @@ class FileSourceScanExec(TpuExec):
             tables.close()
         stats = getattr(self, "_decode_stats", None)
         if stats and (stats["native_files"] or stats["host_files"]):
+            stats["batches"] = batches
             for key, mname in (("native_files", "scanNativeDecodedFiles"),
                                ("host_files", "scanHostDecodedFiles"),
                                ("host_columns",
                                 "scanHostDecodedColumns"),
                                ("pooled_files", "scanPooledFiles"),
-                               ("ahead_files", "scanDecodeAheadFiles")):
+                               ("ahead_files", "scanDecodeAheadFiles"),
+                               ("batches", "scanBatches"),
+                               ("inplace_batches", "scanInPlaceBatches")):
                 if stats[key]:
                     m.setdefault(mname, Metric(mname, Metric.MODERATE)) \
                         .add(stats[key])

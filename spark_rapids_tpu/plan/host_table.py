@@ -18,6 +18,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax.numpy as jnp
 import numpy as np
 
 from ..columnar import dtypes as dt
@@ -35,18 +36,26 @@ class HostColumn:
     as a decoder wrote it (null rows zero-length). Such a column goes
     to the device as it is (``table_to_batch``) and concatenates and
     slices as buffers; its object array of ``str`` is made only when
-    something reads ``values`` (the CPU operators)."""
+    something reads ``values`` (the CPU operators).
 
-    __slots__ = ("_values", "mask", "dtype", "utf8")
+    A fixed-width column may carry ``padded``: ``(values, validity)`` of
+    a batch capacity, of which ``values`` and ``mask`` are the first
+    ``n`` rows, zero under nulls and past row ``n`` — the device's
+    layout, written there by a scan's decoders (io/scan.py
+    ``_PlacedBatch``). ``table_to_batch`` at that capacity transfers the
+    two buffers as they are; nobody writes to them again."""
+
+    __slots__ = ("_values", "mask", "dtype", "utf8", "padded")
 
     def __init__(self, values: Optional[np.ndarray], mask: np.ndarray,
-                 dtype: dt.DType, utf8=None):
+                 dtype: dt.DType, utf8=None, padded=None):
         assert values is not None or utf8 is not None
         assert values is None or len(values) == len(mask)
         self._values = values
         self.mask = np.asarray(mask, dtype=bool)
         self.dtype = dtype
         self.utf8 = utf8
+        self.padded = padded
 
     @property
     def values(self) -> np.ndarray:
@@ -65,7 +74,7 @@ class HostColumn:
 
     @values.setter
     def values(self, values: np.ndarray) -> None:
-        self._values, self.utf8 = values, None
+        self._values, self.utf8, self.padded = values, None, None
 
     def __len__(self):
         return len(self.mask)
@@ -101,6 +110,10 @@ class HostTable:
         assert len(columns) == len(names)
         self.columns = list(columns)
         self.names = list(names)
+        #: set by a decoder that wrote this table's fixed-width columns
+        #: into the rows a scan gave it (io/native_parquet.py): the
+        #: first row's place among the file's
+        self.placed: Optional[int] = None
 
     @property
     def num_rows(self) -> int:
@@ -244,6 +257,10 @@ def table_to_batch(table: HostTable,
             from ..columnar.decimal128 import from_unscaled_ints
             cols.append(from_unscaled_ints(list(c.values), cap, c.dtype,
                                            mask=c.mask))
+        elif c.padded is not None and len(c.padded[0]) == cap:
+            values, validity = c.padded
+            cols.append(ColumnVector(jnp.asarray(values),
+                                     jnp.asarray(validity), c.dtype))
         else:
             cols.append(column_from_numpy(c.values, cap, dtype=c.dtype,
                                           mask=c.mask))

@@ -411,6 +411,8 @@ class TpuSession:
 _PHASE_METRICS = {"scanDecodeTime": "scan_decode_ns",
                   "scanPooledFiles": "scan_pooled_files",
                   "scanDecodeAheadFiles": "scan_ahead_files",
+                  "scanBatches": "scan_batches",
+                  "scanInPlaceBatches": "scan_inplace_batches",
                   "scanWaitTime": "scan_wait_ns",
                   "scanTime": "scan_upload_ns",
                   "prefetchWaitTime": "prefetch_wait_ns",

@@ -483,14 +483,126 @@ def test_scan_counts_pooled_and_decoded_ahead_files(uneven_dir, tmp_path):
             for name, metric in per_exec.items():
                 totals[name] = totals.get(name, 0) + metric.value
         phases = df.session._last_execution["record"]["phases"]
+        assert totals["scanBatches"] == phases["scan_batches"]
+        assert totals.get("scanInPlaceBatches", 0) \
+            == phases["scan_inplace_batches"]
         return (totals.get("scanPooledFiles", 0),
                 totals.get("scanDecodeAheadFiles", 0),
-                phases["scan_pooled_files"], phases["scan_ahead_files"])
+                phases["scan_pooled_files"], phases["scan_ahead_files"],
+                phases["scan_inplace_batches"])
     s = TpuSession()
-    pooled, ahead, p_pooled, p_ahead = counters(s.read.parquet(uneven_dir))
+    pooled, ahead, p_pooled, p_ahead, _ = counters(
+        s.read.parquet(uneven_dir))
     assert pooled == p_pooled == len(UNEVEN_ROWS)
     assert 0 <= ahead == p_ahead <= pooled
+    native = TpuSession(SrtConf(NATIVE))
+    assert counters(native.read.parquet(uneven_dir))[4] == 2
     one = str(tmp_path / "one")
     s.create_dataframe({"a": [1, 2, 3]}).write.parquet(one)
     assert len(os.listdir(one)) == 1
-    assert counters(s.read.parquet(one)) == (0, 0, 0, 0)
+    assert counters(s.read.parquet(one)) == (0, 0, 0, 0, 0)
+    # a one-file scan has no pool and places nothing, whatever the lane
+    assert counters(native.read.parquet(one)) == (0, 0, 0, 0, 0)
+
+
+# ---------------------------------------------------------------------------
+# a laid-out scan: files decode into their batch's own padded buffers
+# (io/scan.py _lay_out / _PlacedBatch, io/native_parquet.py)
+# ---------------------------------------------------------------------------
+
+#: the native lane is the chip's default; here it is asked for
+NATIVE = {"srt.sql.format.parquet.nativeDecode.enabled": "true",
+          "srt.sql.reader.batchSizeRows": "1024"}
+
+
+@pytest.fixture(scope="module")
+def placed_dir(tmp_path_factory):
+    """The uneven six files under one partition directory: ``a`` int64,
+    ``b`` float64 with nulls, ``c`` INT32 (read as bigint), ``s`` string
+    with nulls, ``f`` boolean (pyarrow decodes it: no lane of its own in
+    a placed batch), and the partition column ``p``."""
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    d = tmp_path_factory.mktemp("placed")
+    os.makedirs(d / "p=7")
+    start = 0
+    for i, n in enumerate(UNEVEN_ROWS):
+        pq.write_table(pa.table({
+            "a": np.arange(start, start + n, dtype=np.int64),
+            "b": pa.array(np.arange(start, start + n) / 7.0,
+                          mask=np.arange(n) % 5 == 0),
+            "c": np.arange(n, dtype=np.int32),
+            "s": pa.array([f"s{j % 13}" if j % 7 else None
+                           for j in range(n)], type=pa.string()),
+            "f": np.arange(n) % 3 == 0}),
+            str(d / "p=7" / f"part-{i:05d}.parquet"))
+        start += n
+    return str(d)
+
+
+def _device_batches(path, settings, reader):
+    """The scan's device batches, each as (names, rows, every buffer of
+    every column to its full capacity), and the scan's counters."""
+    from spark_rapids_tpu.exec.base import ExecContext
+    from spark_rapids_tpu.io.scan import FileScan, FileSourceScanExec
+    conf = SrtConf({READER_TYPE.key: reader, **NATIVE, **settings})
+    schema = [(n, dt.INT64 if n == "c" else t)
+              for n, t in FileScan(path, "parquet").schema]
+    node = FileSourceScanExec(FileScan(path, "parquet", schema=schema))
+    ctx = ExecContext(conf)
+    out = []
+    for b in node.execute(ctx):
+        buffers = [[np.asarray(getattr(c, slot)).tolist()
+                    for slot in ("data", "offsets", "chars", "validity")
+                    if hasattr(c, slot)] for c in b.columns]
+        out.append((b.names, int(b.num_rows), buffers))
+    counters = {k: m.value for k, m in ctx.metrics_for(node.exec_id).items()}
+    return out, counters
+
+
+@pytest.mark.parametrize("reader", ["COALESCING", "MULTITHREADED"])
+def test_placed_batches_equal_the_inline_scans(placed_dir, reader):
+    placed, counters = _device_batches(placed_dir, {}, reader)
+    inline, inline_counters = _device_batches(placed_dir, INLINE, reader)
+    # names, rows, capacity, values (zeros under nulls and in the padding)
+    # and validity of every column, batch for batch
+    assert placed == inline
+    assert [n for _, n, _ in placed] == (
+        [1854, 338] if reader == "COALESCING" else [700, 130, 1024, 5, 333])
+    assert placed[0][0] == ["a", "b", "c", "s", "f", "p"]
+    assert counters["scanInPlaceBatches"] == counters["scanBatches"] \
+        == len(placed)
+    assert "scanInPlaceBatches" not in inline_counters
+    assert inline_counters["scanBatches"] == len(placed)
+
+
+@pytest.mark.parametrize("reader", ["COALESCING", "MULTITHREADED"])
+@pytest.mark.parametrize("spoiled", ["corrupt", "pyarrow"])
+def test_a_file_that_cannot_be_placed_spoils_its_batch_alone(
+        placed_dir, tmp_path, reader, spoiled):
+    """File 2 of 6 is garbage and skipped, or of a codec the native lane
+    leaves to pyarrow: its batch is assembled the old way from what its
+    files delivered, the other batches still come from their buffers."""
+    import shutil
+
+    import pyarrow.parquet as pq
+    d = tmp_path / "mix"
+    shutil.copytree(placed_dir, d)
+    victim = str(d / "p=7" / "part-00002.parquet")
+    if spoiled == "corrupt":
+        with open(victim, "wb") as f:
+            f.write(b"PAR1 this is not a parquet file PAR1")
+    else:
+        pq.write_table(pq.read_table(victim), victim, compression="lz4")
+    settings = {"srt.sql.ignoreCorruptFiles": True}
+    got, counters = _device_batches(str(d), settings, reader)
+    inline, _ = _device_batches(str(d), {**settings, **INLINE}, reader)
+    assert got == inline
+    lo = sum(UNEVEN_ROWS[:2])
+    assert [v for _, n, cols in got for v in cols[0][0][:n]] == [
+        v for v in range(sum(UNEVEN_ROWS))
+        if spoiled == "pyarrow" or not lo <= v < lo + UNEVEN_ROWS[2]]
+    assert counters["scanBatches"] == len(got)
+    # COALESCING: files 0-3 are one batch; MULTITHREADED: a batch a file
+    assert counters["scanInPlaceBatches"] == len(got) - (
+        reader == "COALESCING" or spoiled == "pyarrow")

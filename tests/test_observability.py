@@ -509,6 +509,7 @@ def test_program_names_are_registry_labels():
 
 PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
               "scan_decode_ns", "scan_pooled_files", "scan_ahead_files",
+              "scan_batches", "scan_inplace_batches",
               "scan_wait_ns", "scan_upload_ns", "prefetch_wait_ns", "prefetch_early_starts",
               "dispatch_ns", "launches",
               # the join execs' counters (exec/join.py JOIN_COUNTERS)
@@ -585,6 +586,10 @@ def test_query_record_carries_phases(traced_parquet_query):
     assert totals["prefetchWaitTime"] == phases["prefetch_wait_ns"]
     # no join in the plan: the scan's producer started at its first pull
     assert phases["prefetch_early_starts"] == 0
+    # three files, one batch; the pyarrow lane (this backend's default)
+    # places nothing
+    assert totals["scanBatches"] == phases["scan_batches"] == 1
+    assert phases["scan_inplace_batches"] == 0
 
 
 def test_host_ranges_are_in_the_profilers_trace(traced_parquet_query):

@@ -667,6 +667,72 @@ def test_run_ahead_stress_more_workers_than_cores():
     assert all(p.is_set() for p in ahead._parked)
 
 
+def test_run_ahead_holds_a_run_of_tasks_until_its_last_is_taken():
+    """``hold_until``: the tasks that fill one buffer stay on the budget
+    until the consumer has the last of them; the peak still fits."""
+    tasks = [_gen_task(30, [i]) for i in range(8)]
+    ahead = pipeline.RunAhead(tasks, threads=4, max_bytes=100,
+                              hold_until=[2, 2, 2, 5, 5, 5, 6, 7])
+    try:
+        it = iter(ahead)
+        assert [next(it), next(it)] == [(0, 0), (1, 1)]
+        # two taken and held, the third admitted, no room for a fourth
+        assert ahead._held == 60 and ahead._bytes == 90
+        assert next(it) == (2, 2) and ahead._held == 0
+        assert list(it) == [(i, i) for i in range(3, 8)]
+    finally:
+        ahead.close()
+    assert ahead._bytes == 0 and ahead._bytes_peak <= 100
+    assert ahead.pooled == 8
+
+
+def test_placed_scan_keeps_the_budget_and_parks_its_threads(
+        tmp_path, monkeypatch):
+    """A scan that decodes into its batches' own buffers: what is on the
+    budget (a batch's files until the batch is taken, padding included)
+    never passes maxBytesInFlight, and closing it in the middle of a
+    batch leaves every reader thread parked."""
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+
+    from spark_rapids_tpu.io import scan as scan_mod
+    for i in range(6):
+        pq.write_table(pa.table({
+            "a": np.arange(500 * i, 500 * (i + 1), dtype=np.int64),
+            "b": np.arange(500, dtype=np.float64)}),
+            str(tmp_path / f"part-{i}.parquet"))
+    made = []
+
+    class Spy(pipeline.RunAhead):
+        def __init__(self, *args, **kwargs):
+            super().__init__(*args, **kwargs)
+            made.append(self)
+    monkeypatch.setattr(scan_mod, "RunAhead", Spy)
+    # a batch: three files of 500 rows, 18 bytes a row, padded to 2048
+    batch = 2048 * 18
+    conf = SrtConf({"srt.sql.format.parquet.nativeDecode.enabled": "true",
+                    "srt.sql.reader.batchSizeRows": "1024",
+                    "srt.exec.pipeline.maxBytesInFlight": str(batch + 9000)})
+    node = scan_mod.FileSourceScanExec(
+        scan_mod.FileScan(str(tmp_path), "parquet"))
+    ctx = ExecContext(conf)
+    rows = [int(b.num_rows) for b in node.execute(ctx)]
+    assert rows == [1500, 1500]
+    counters = ctx.metrics_for(node.exec_id)
+    assert counters["scanInPlaceBatches"].value == 2
+    assert batch <= made[0]._bytes_peak <= batch + 9000
+    assert made[0]._bytes == 0
+    before = pipeline.prefetch_thread_leaks()
+    batches = node.execute(ExecContext(conf))
+    assert int(next(batches).num_rows) == 1500
+    batches.close()
+    assert all(p.is_set() for p in made[1]._parked)
+    assert not made[1]._queue and not made[1]._done
+    assert pipeline.prefetch_thread_leaks() == before
+    assert not [t for t in _prefetch_threads() if t.is_alive()]
+
+
 # ---------------------------------------------------------------------------
 # a join starts the producers beneath it when it starts (start_sources)
 # ---------------------------------------------------------------------------
