@@ -178,13 +178,19 @@ class TpuSemaphore:
                 self._holders[tid] += 1
                 return
         # uncontended fast path: only actual blocking counts as wait
-        # (GpuTaskMetrics semaphore-wait accumulator)
+        # (GpuTaskMetrics semaphore-wait accumulator), charged to the
+        # task and to the thread's current query, as a launch is
         if not self._sem.acquire(blocking=False):
             t0 = time.perf_counter_ns()
-            self._sem.acquire()
+            with annotate("semaphore.wait"):
+                self._sem.acquire()
+            wait_ns = time.perf_counter_ns() - t0
             from ..memory.budget import task_context
-            task_context().semaphore_wait_ns += \
-                time.perf_counter_ns() - t0
+            from ..robustness.admission import current_query
+            task_context().semaphore_wait_ns += wait_ns
+            query = current_query()
+            if query is not None:
+                query.count_semaphore_wait(wait_ns)
         with self._lock:
             self._holders[tid] = 1
 
@@ -213,12 +219,26 @@ _GLOBAL_SEM: Optional[TpuSemaphore] = None
 _SEM_LOCK = threading.Lock()
 
 
-def device_semaphore() -> TpuSemaphore:
+def device_semaphore(conf: Optional[SrtConf] = None) -> TpuSemaphore:
+    """Process-wide device semaphore, sized from config on first use:
+    ``srt.sql.concurrentTpuTasks`` of the first session that executes
+    (the ``query_semaphore`` idiom, robustness/admission.py)."""
     global _GLOBAL_SEM
     with _SEM_LOCK:
         if _GLOBAL_SEM is None:
-            _GLOBAL_SEM = TpuSemaphore(active_conf().get(CONCURRENT_TASKS))
+            _GLOBAL_SEM = TpuSemaphore(
+                (conf or active_conf()).get(CONCURRENT_TASKS))
         return _GLOBAL_SEM
+
+
+def reset_device_semaphore(conf: Optional[SrtConf] = None
+                           ) -> Optional[TpuSemaphore]:
+    """Test hook: drop the singleton (resized from conf on next use, or
+    immediately when a conf is given), as ``reset_query_semaphore``."""
+    global _GLOBAL_SEM
+    with _SEM_LOCK:
+        _GLOBAL_SEM = None
+    return device_semaphore(conf) if conf is not None else None
 
 
 class ExecContext:
@@ -232,7 +252,7 @@ class ExecContext:
         #: covering every operator — and shipped to producer/fetch
         #: threads spawned on the query's behalf.
         self.query = query
-        self.semaphore = device_semaphore()
+        self.semaphore = device_semaphore(self.conf)
         self.metrics: Dict[str, Dict[str, Metric]] = {}
         #: SelfTimer stacks, one per pulling thread (see timer_stack)
         self._timer_stacks = threading.local()

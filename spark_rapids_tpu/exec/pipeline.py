@@ -228,6 +228,23 @@ class _ProducerPool:
 _PRODUCERS = _ProducerPool()
 
 
+class _ReaderBounds:
+    """What the reader pool holds over the whole process, under the one
+    condition every ``RunAhead`` waits on: ``live`` reader threads
+    inside a task, ``bytes`` admitted and not yet given back. A stream
+    admits and starts against these, first come: the reference's
+    ``multiThreadedRead.numThreads`` is one pool an executor, shared by
+    its tasks, not one a scan."""
+
+    def __init__(self):
+        self.cv = threading.Condition()
+        self.live = 0
+        self.bytes = 0
+
+
+_READERS = _ReaderBounds()
+
+
 def prefetch_buffer_bytes() -> int:
     """Total bytes queued across all live prefetchers in this process
     (obs/resource.py sampler probe; racy reads are fine for a gauge)."""
@@ -519,7 +536,19 @@ class RunAhead:
     generator, ``cost`` is an estimate of the bytes its items will hold.
     Up to ``threads`` producers borrowed from the pool drain tasks into
     lists, in submission order, while the cost of what is queued, running
-    or done and not yet taken stays within ``max_bytes``. Iterating
+    or done and not yet taken stays within ``max_bytes``. Both bounds
+    are the process's (``_ReaderBounds``): a producer starts a task only
+    while fewer than ``threads`` reader threads are inside one, this
+    stream's or another's, and a stream that already holds something
+    starts on its next run of tasks (below; a task alone is a run) only
+    while all live streams together stay within ``max_bytes``. What a
+    stream's consumer can come to wait for is never held to another
+    stream's bytes: a stream that holds nothing always admits, and a
+    run whose first task is admitted is admitted to its end against the
+    stream's own bytes alone. So no stream waits on another's consumer,
+    and the streams together hold at most the budget and one run each
+    of the others. One live stream is bounded exactly as if the bounds
+    were its own. Iterating
     yields ``(task index, item)`` in submission order. A task that is
     over the budget alone never goes to the pool: the consumer runs its
     generator itself, item by item, when it gets there, so such a task
@@ -533,7 +562,10 @@ class RunAhead:
     the threads, and into the heaps, that decoded it last. ``close()``
     drops what is queued, waits for what runs, and leaves every
     borrowed thread parked. ``pooled`` counts the tasks taken from the
-    pool, ``ahead`` those that were done when the consumer asked.
+    pool, ``ahead`` those that were done when the consumer asked,
+    ``threads_peak`` the most reader threads that were inside a task at
+    once, this stream's and every other live one's, when one of its own
+    started.
 
     ``hold_until[i] >= i`` names the task whose taking gives task
     ``i``'s cost back to the budget (default: its own): tasks that fill
@@ -554,7 +586,8 @@ class RunAhead:
         self._conf = conf
         self._query = query
         self._fault_tag = faults.current_op() if faults.armed() else ""
-        self._cv = threading.Condition()
+        self._cv = _READERS.cv
+        self._threads = max(int(threads), 1)
         self._next = 0  # first task not admitted yet
         self._queue: deque = deque()  # admitted, not yet claimed
         self._done: dict = {}  # task index -> (items, error)
@@ -565,8 +598,9 @@ class RunAhead:
         self._stopped = False
         self.pooled = 0
         self.ahead = 0
+        self.threads_peak = 0
         self._thread_name = f"srt-prefetch-{name}"
-        n = min(max(int(threads), 1), sum(
+        n = min(self._threads, sum(
             1 for cost, _ in tasks if cost <= self._max_bytes))
         with self._cv:
             self._admit()
@@ -577,16 +611,28 @@ class RunAhead:
 
     def _admit(self) -> None:
         """Hand the pool every next task the budget has room for; stop
-        at one the consumer has to run itself. Callers hold ``_cv``."""
+        at one the consumer has to run itself. The process's bytes are
+        asked only where a run starts and the stream holds something;
+        inside a run the stream's own decide, as if it were alone.
+        Callers hold ``_cv``."""
+        admitted = False
         while self._next < len(self._tasks) and not self._stopped:
-            cost = self._tasks[self._next][0]
+            i = self._next
+            cost = self._tasks[i][0]
             if self._bytes + cost > self._max_bytes:
                 break
-            self._queue.append(self._next)
+            starts_run = i == 0 or self._hold_until[i - 1] < i
+            if starts_run and self._bytes and \
+                    _READERS.bytes + cost > self._max_bytes:
+                break
+            self._queue.append(i)
             self._bytes += cost
+            _READERS.bytes += cost
             self._bytes_peak = max(self._bytes_peak, self._bytes)
             self._next += 1
-        self._cv.notify_all()
+            admitted = True
+        if admitted:
+            self._cv.notify_all()
 
     def _work(self) -> None:
         from ..robustness import faults
@@ -598,19 +644,26 @@ class RunAhead:
         with scope:
             while True:
                 with self._cv:
-                    while not (self._queue or self._stopped
-                               or self._next >= len(self._tasks)):
+                    while not (self._queue
+                               and _READERS.live < self._threads):
+                        if self._stopped or not self._queue and \
+                                self._next >= len(self._tasks):
+                            return
                         self._cv.wait()
-                    if self._stopped or not self._queue:
+                    if self._stopped:
                         return
                     i = self._queue.popleft()
                     make = self._tasks[i][1]
+                    _READERS.live += 1
+                    self.threads_peak = max(self.threads_peak,
+                                            _READERS.live)
                 items, error = [], None
                 try:
                     items.extend(make())
                 except BaseException as e:  # noqa: BLE001 — relayed
                     error = e
                 with self._cv:
+                    _READERS.live -= 1
                     if not self._stopped:
                         self._done[i] = (items, error)
                     self._cv.notify_all()
@@ -624,18 +677,23 @@ class RunAhead:
                 with self._cv:
                     self._next = i + 1
                     self._admit()
+                    self._cv.notify_all()  # the last task: workers leave
                 continue
             with self._cv:
                 self.pooled += 1
                 if i in self._done:
                     self.ahead += 1
                 while i not in self._done:
+                    # another stream may have given bytes back
+                    self._admit()
                     self._cv.wait()
                 items, error = self._done.pop(i)
                 self._held += cost
                 if self._hold_until[i] <= i:
                     self._bytes -= self._held
+                    _READERS.bytes -= self._held
                     self._held = 0
+                    self._cv.notify_all()
                 self._admit()
             for item in items:
                 yield i, item
@@ -644,6 +702,8 @@ class RunAhead:
 
     def close(self, join_timeout: float = 30.0) -> None:
         with self._cv:
+            _READERS.bytes -= self._bytes  # what was never taken
+            self._bytes = 0
             self._stopped = True
             queued = len(self._queue)
             self._queue.clear()

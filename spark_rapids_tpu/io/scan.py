@@ -1025,7 +1025,7 @@ class FileSourceScanExec(TpuExec):
         stats = self._decode_stats = {
             "native_files": 0, "host_files": 0, "host_columns": 0,
             "pooled_files": 0, "ahead_files": 0,
-            "batches": 0, "inplace_batches": 0}
+            "batches": 0, "inplace_batches": 0, "reader_threads_peak": 0}
         options["_decode_stats"] = stats
         # read + decode + conform of every file, timed where it runs
         # (iter_file_tables, on the pool's threads): thread time
@@ -1065,6 +1065,8 @@ class FileSourceScanExec(TpuExec):
                 files.close()
                 stats["pooled_files"] += files.pooled
                 stats["ahead_files"] += files.ahead
+                stats["reader_threads_peak"] = max(
+                    stats["reader_threads_peak"], files.threads_peak)
         else:
             for p in scan_paths:
                 for t in iter_file_tables(p, *args, pv(p)):
@@ -1222,6 +1224,12 @@ class FileSourceScanExec(TpuExec):
                 if stats[key]:
                     m.setdefault(mname, Metric(mname, Metric.MODERATE)) \
                         .add(stats[key])
+            if stats["reader_threads_peak"]:
+                # a gauge: the most reader threads inside a file at once
+                # over the process while this scan's decoded, not a sum
+                peak = m.setdefault("scanReaderThreadsPeak", Metric(
+                    "scanReaderThreadsPeak", Metric.MODERATE))
+                peak.set(max(peak.value, stats["reader_threads_peak"]))
 
     def node_description(self) -> str:
         desc = "Tpu" + self.scan.node_description()

@@ -111,22 +111,29 @@ def plan_cache_key(plan, conf):
         return None
 
 
+#: trees one key keeps: twice srt.sql.concurrentQueryTasks' default, so
+#: every admitted query that sends one text finds a tree of its own
+_MAX_TREES_PER_KEY = 8
+
+
 class PhysicalPlanCache:
-    """Small FIFO memo of structural key -> physical plan.
+    """Small FIFO memo of structural key -> physical plans.
 
     Cached exec trees hold one-shot execution state (shuffle ids,
-    write flags, metrics), so an entry may be EXECUTING on at most one
+    write flags, metrics), so a tree may be EXECUTING on at most one
     thread at a time. Serial callers reuse via ``reset_for_rerun``;
     concurrent callers (the serving front door runs many sessions over
-    one shared cache) take an execution *lease* — if the entry's lease
-    is already held, the caller plans a fresh tree instead of racing
-    on shared instances."""
+    one shared cache) take an execution *lease* on a tree. A key holds
+    up to ``_MAX_TREES_PER_KEY`` trees: a caller that finds every one of
+    them leased plans a fresh tree, which joins them, so N streams that
+    send one text plan it N times in all and not once a collision
+    (planning a star query is tens of milliseconds of Python)."""
 
     def __init__(self, max_entries: int = 32):
         import threading
         self.max_entries = max_entries
+        #: key -> [(physical, lease lock)], newest last
         self._entries: dict = {}
-        self._leases: dict = {}
         self._mu = threading.Lock()
         # lifetime counters, reported as hit rates by the serving
         # bench (tools/serve_bench.py) alongside the jit-registry's
@@ -134,50 +141,28 @@ class PhysicalPlanCache:
         self.misses = 0
         self.busy_bypasses = 0
 
-    def get(self, key):
-        with self._mu:
-            p = self._entries.get(key)
-            if p is None:
-                self.misses += 1
-            else:
-                self.hits += 1
-            return p
-
     def lease(self, key):
         """(physical, release_fn) with the execution lease held, or
-        (None, None). A busy entry — mid-execution on another thread —
-        counts as a miss (the caller replans uncached)."""
+        (None, None). A key whose trees are all busy — mid-execution on
+        other threads — counts as a miss (the caller plans another)."""
         with self._mu:
-            p = self._entries.get(key)
-            if p is None:
+            instances = self._entries.get(key)
+            if not instances:
                 self.misses += 1
                 return None, None
-            lock = self._leases.get(key)
-        if lock is not None and not lock.acquire(blocking=False):
-            with self._mu:
-                self.misses += 1
-                self.busy_bypasses += 1
+            for physical, lock in instances:
+                if lock.acquire(blocking=False):
+                    self.hits += 1
+                    return physical, lock.release
+            self.misses += 1
+            self.busy_bypasses += 1
             return None, None
-        with self._mu:
-            self.hits += 1
-        return p, (lock.release if lock is not None else None)
 
     def stats(self) -> dict:
         with self._mu:
             return {"hits": self.hits, "misses": self.misses,
                     "busy_bypasses": self.busy_bypasses,
                     "entries": len(self._entries)}
-
-    def put(self, key, physical) -> None:
-        import threading
-        with self._mu:
-            if key not in self._entries and \
-                    len(self._entries) >= self.max_entries:
-                oldest = next(iter(self._entries))
-                self._entries.pop(oldest)
-                self._leases.pop(oldest, None)
-            self._entries[key] = physical
-            self._leases[key] = threading.Lock()
 
     def put_leased(self, key, physical):
         """Insert with the execution lease pre-acquired: the builder
@@ -187,16 +172,16 @@ class PhysicalPlanCache:
         lock = threading.Lock()
         lock.acquire()
         with self._mu:
-            if key not in self._entries and \
-                    len(self._entries) >= self.max_entries:
-                oldest = next(iter(self._entries))
-                self._entries.pop(oldest)
-                self._leases.pop(oldest, None)
-            self._entries[key] = physical
-            self._leases[key] = lock
+            instances = self._entries.get(key)
+            if instances is None:
+                if len(self._entries) >= self.max_entries:
+                    self._entries.pop(next(iter(self._entries)))
+                instances = self._entries[key] = []
+            if len(instances) >= _MAX_TREES_PER_KEY:
+                instances.pop(0)  # still leased where it runs; then dropped
+            instances.append((physical, lock))
         return lock.release
 
     def clear(self) -> None:
         with self._mu:
             self._entries.clear()
-            self._leases.clear()

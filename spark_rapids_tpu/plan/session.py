@@ -299,7 +299,8 @@ class TpuSession:
         tc = task_context()
         tc0 = (tc.spilled_bytes, tc.retry_count, tc.split_count)
         # a caller's token may have run queries before this one
-        launch0 = (qctx.dispatch_ns, qctx.launches)
+        launch0 = (qctx.dispatch_ns, qctx.launches,
+                   qctx.semaphore_wait_ns)
         fetch_ns = 0
         is_tpu = isinstance(physical, TpuExec)
         # serving identity fields ride on QueryStart/QueryEnd (only
@@ -379,9 +380,12 @@ class TpuSession:
                      "oom_splits": tc.split_count - tc0[2],
                      "phases": _query_phases(
                          ctx.metrics, parse_ns=parse_ns, plan_ns=plan_ns,
+                         admission_wait_ns=qctx.admission_wait_ns or 0,
                          execute_ns=wall_ns, fetch_ns=fetch_ns,
                          dispatch_ns=qctx.dispatch_ns - launch0[0],
-                         launches=qctx.launches - launch0[1])}
+                         launches=qctx.launches - launch0[1],
+                         semaphore_wait_ns=qctx.semaphore_wait_ns
+                         - launch0[2])}
             rec = _registry().record_query(qid, summary, wall_ns,
                                            status, **extra)
             self._last_execution = {"physical": physical, "ctx": ctx,
@@ -428,19 +432,37 @@ _PHASE_METRICS.update(_LANE_COUNTERS)
 _PHASE_METRICS["aggMaskedFilterBatches"] = "agg_masked_filter_batches"
 
 
+#: a gauge's peak, not a sum: the largest over the plan's operators
+_PHASE_PEAKS = {"scanReaderThreadsPeak": "reader_threads_peak"}
+
+#: the phases a query's session clocks itself, in the order a request
+#: meets them; ``serve_ns`` is the server's (serve/server.py), 0 for a
+#: query no server ran. With ``_PHASE_METRICS``' and ``_PHASE_PEAKS``'
+#: values, every key a record's ``phases`` holds
+TIMED_PHASES = ("parse_ns", "plan_ns", "admission_wait_ns", "execute_ns",
+                "fetch_ns", "dispatch_ns", "launches",
+                "semaphore_wait_ns", "serve_ns")
+
+
 def _query_phases(ctx_metrics: Dict, **timed) -> Dict[str, int]:
     """The ``phases`` of one query's record: where its wall went, each
     number measured where the work happens (docs/OBSERVABILITY.md,
     "Host ranges and query phases"). ``timed`` are the phases the
     session clocks itself; the scan's and the pipeline's come from the
     operators' metrics."""
-    phases = dict(timed)
+    phases = dict.fromkeys(TIMED_PHASES, 0)
+    phases.update(timed)
     phases.update((key, 0) for key in _PHASE_METRICS.values())
+    phases.update((key, 0) for key in _PHASE_PEAKS.values())
     for metrics in ctx_metrics.values():
         for name, key in _PHASE_METRICS.items():
             metric = metrics.get(name)
             if metric is not None:
                 phases[key] += int(metric.value)
+        for name, key in _PHASE_PEAKS.items():
+            metric = metrics.get(name)
+            if metric is not None:
+                phases[key] = max(phases[key], int(metric.value))
     return phases
 
 

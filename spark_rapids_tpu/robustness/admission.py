@@ -44,6 +44,7 @@ from typing import Optional
 from ..conf import (ADMISSION_BACKOFF_BASE_S, ADMISSION_MAX_QUEUE_DEPTH,
                     CONCURRENT_QUERY_TASKS, active_conf)
 from ..obs import events as _events
+from ..obs.trace import annotate
 
 __all__ = ["AdmissionRejected", "QueryInterrupted", "QueryCancelled",
            "DeadlineExceeded", "QueryContext", "QuerySemaphore",
@@ -81,7 +82,7 @@ class QueryContext:
 
     __slots__ = ("query_id", "deadline", "cancel_reason", "_cancelled",
                  "admission_wait_ns", "dispatch_ns", "launches",
-                 "_launch_lock")
+                 "semaphore_wait_ns", "_launch_lock")
 
     def __init__(self, query_id: str = "",
                  deadline: Optional[float] = None):
@@ -100,12 +101,19 @@ class QueryContext:
         #: (jit_registry ``launch.*`` ranges; ``phases`` of the record)
         self.dispatch_ns = 0
         self.launches = 0
+        #: ns its threads stood blocked for a device permit
+        #: (exec/base.py TpuSemaphore, ``semaphore.wait`` ranges)
+        self.semaphore_wait_ns = 0
         self._launch_lock = threading.Lock()
 
     def count_launch(self, ns: int) -> None:
         with self._launch_lock:
             self.dispatch_ns += ns
             self.launches += 1
+
+    def count_semaphore_wait(self, ns: int) -> None:
+        with self._launch_lock:
+            self.semaphore_wait_ns += ns
 
     @property
     def admission_tier(self) -> str:
@@ -275,18 +283,19 @@ class QuerySemaphore:
             t0 = time.perf_counter_ns()
             attempt = 0
             try:
-                while not (self._queue[0] is ticket
-                           and self._active < self.permits):
-                    if token is not None:
-                        token.check()  # cancel/deadline while queued
-                    # backoff + jitter bounds how stale a deadline
-                    # check can get; release() notifies so an open
-                    # slot is claimed immediately, not at backoff
-                    attempt += 1
-                    backoff = (self.backoff_base_s
-                               * min(2 ** (attempt - 1), 64)
-                               * (1.0 + random.random() * 0.25))
-                    self._cv.wait(timeout=backoff)
+                with annotate("admission.wait"):
+                    while not (self._queue[0] is ticket
+                               and self._active < self.permits):
+                        if token is not None:
+                            token.check()  # cancel/deadline while queued
+                        # backoff + jitter bounds how stale a deadline
+                        # check can get; release() notifies so an open
+                        # slot is claimed immediately, not at backoff
+                        attempt += 1
+                        backoff = (self.backoff_base_s
+                                   * min(2 ** (attempt - 1), 64)
+                                   * (1.0 + random.random() * 0.25))
+                        self._cv.wait(timeout=backoff)
                 self._queue.popleft()
                 self._active += 1
                 self._holders[tid] = 1
@@ -294,8 +303,6 @@ class QuerySemaphore:
                 wait_ns = time.perf_counter_ns() - t0
                 if token is not None:
                     token.admission_wait_ns = wait_ns
-                from ..memory.budget import task_context
-                task_context().semaphore_wait_ns += wait_ns
                 _events.emit("QueryAdmitted", query_id=qid,
                              active=self._active, queued_ns=wait_ns)
             except BaseException:

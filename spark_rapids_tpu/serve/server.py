@@ -51,6 +51,7 @@ from ..conf import (RESULT_CACHE_ENABLED, RESULT_CACHE_MAX_BYTES,
                     SERVE_AUTH_TOKEN, SERVE_HOST, SERVE_MAX_SESSIONS,
                     SERVE_PORT, SERVE_STREAM_CHUNK_ROWS, SrtConf)
 from ..obs import events as _events
+from ..obs.trace import annotate
 from ..robustness.admission import (AdmissionRejected, QueryContext,
                                     QueryInterrupted)
 from . import protocol as P
@@ -216,7 +217,10 @@ class SqlServer:
                     args=(state, sock, send_lock, rid, req),
                     daemon=True, name=f"srt-serve-s{sid}r{rid}")
                 with state.lock:
-                    state.threads.append(t)
+                    # a session lives for thousands of requests: keep
+                    # the threads teardown may have to join, not all
+                    state.threads = [x for x in state.threads
+                                     if x.is_alive()] + [t]
                     state.requests += 1
                 t.start()
         except (ConnectionError, OSError, P.ProtocolError):
@@ -228,6 +232,11 @@ class SqlServer:
     # --- request execution ------------------------------------------------
     def _run_request(self, state: _SessionState, sock, send_lock,
                      rid: int, req: dict) -> None:
+        with annotate("serve.request"):
+            self._serve(state, sock, send_lock, rid, req)
+
+    def _serve(self, state: _SessionState, sock, send_lock,
+               rid: int, req: dict) -> None:
         import os as _os
 
         qid = f"q{_os.getpid()}-s{state.session_id}r{rid}"
@@ -260,22 +269,36 @@ class SqlServer:
                         "wall_ns": time.perf_counter_ns() - t0,
                     }, lock=send_lock)
                     return
-            table = sess.execute(plan, query=qctx,
-                                 parse_ns=df.parse_ns)
-            payloads = self._serialize_result(table)
-            for payload in payloads:
-                P.send_frame(sock, P.OP_BATCH, sid, rid, payload,
-                             lock=send_lock)
-            if fp is not None:
-                self.result_cache.put(fp, payloads, table.num_rows)
-            P.send_json(sock, P.OP_EOS, sid, rid, {
-                "status": "ok",
-                "cache": "miss" if fp is not None else "off",
-                "tier": qctx.admission_tier,
-                "wait_ns": qctx.admission_wait_ns or 0,
-                "rows": table.num_rows,
-                "wall_ns": time.perf_counter_ns() - t0,
-            }, lock=send_lock)
+            table, rec = sess._execute_recorded(plan, query=qctx,
+                                                parse_ns=df.parse_ns)
+            with annotate("serve.serialize"):
+                payloads = self._serialize_result(table)
+            phases = rec["phases"]
+            # what the request spent outside the session's own phases:
+            # this thread's start, the per-request session, the frames
+            engine_ns = sum(phases[k] for k in (
+                "parse_ns", "plan_ns", "admission_wait_ns", "execute_ns"))
+            with annotate("serve.send"):
+                for payload in payloads:
+                    P.send_frame(sock, P.OP_BATCH, sid, rid, payload,
+                                 lock=send_lock)
+                if fp is not None:
+                    self.result_cache.put(fp, payloads, table.num_rows)
+                # the trailer's serve_ns lacks the trailer's own frame;
+                # the record's is whole once that is sent
+                phases["serve_ns"] = \
+                    time.perf_counter_ns() - t0 - engine_ns
+                P.send_json(sock, P.OP_EOS, sid, rid, {
+                    "status": "ok",
+                    "cache": "miss" if fp is not None else "off",
+                    "tier": qctx.admission_tier,
+                    "wait_ns": qctx.admission_wait_ns or 0,
+                    "rows": table.num_rows,
+                    "wall_ns": time.perf_counter_ns() - t0,
+                    "query_id": qid,
+                    "phases": phases,
+                }, lock=send_lock)
+            phases["serve_ns"] = time.perf_counter_ns() - t0 - engine_ns
         except AdmissionRejected as e:
             with self._lock:
                 self.load_shed += 1

@@ -512,6 +512,10 @@ PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
               "scan_batches", "scan_inplace_batches",
               "scan_wait_ns", "scan_upload_ns", "prefetch_wait_ns", "prefetch_early_starts",
               "dispatch_ns", "launches",
+              # the waits at the two gates, the server's share (0 for a
+              # query no server ran), the reader pool's gauge
+              "admission_wait_ns", "semaphore_wait_ns", "serve_ns",
+              "reader_threads_peak",
               # the join execs' counters (exec/join.py JOIN_COUNTERS)
               "join_build_ns", "lookup_join_batches", "hash_join_batches",
               "join_capacity_relaunches", "join_readbacks",
@@ -576,6 +580,13 @@ def test_query_record_carries_phases(traced_parquet_query):
     assert phases["fetch_ns"] > 0
     assert phases["execute_ns"] == record["wall_ns"]
     assert phases["execute_ns"] >= phases["scan_upload_ns"] > 0
+    from spark_rapids_tpu.plan.session import TIMED_PHASES
+    assert set(TIMED_PHASES) <= PHASE_KEYS
+    # one query, in process: nobody to queue behind or to serve
+    assert phases["admission_wait_ns"] == phases["serve_ns"] == 0
+    assert phases["semaphore_wait_ns"] == 0
+    # three files on the reader pool: one to three threads at once
+    assert 1 <= phases["reader_threads_peak"] <= 3
     totals = {}
     for per_exec in metrics.values():
         for name, metric in per_exec.items():

@@ -613,6 +613,127 @@ def test_run_ahead_close_mid_stream_parks_every_thread():
     assert not [t for t in _prefetch_threads() if t.is_alive()]
 
 
+def test_live_streams_share_the_reader_threads_of_the_process():
+    """Four streams of four threads each over the same bound: never
+    more than four reader threads inside a task at once, and every
+    stream still delivers everything in order."""
+    lock = threading.Lock()
+    inside, most = [0], [0]
+
+    def make(tag):
+        def run():
+            with lock:
+                inside[0] += 1
+                most[0] = max(most[0], inside[0])
+            time.sleep(0.003)
+            with lock:
+                inside[0] -= 1
+            yield tag
+        return run
+    streams = [pipeline.RunAhead([(1, make((s, i))) for i in range(8)],
+                                 threads=4, max_bytes=100, name=f"s{s}")
+               for s in range(4)]
+    got = [[] for _ in streams]
+    pulls = [threading.Thread(target=lambda k=k: got[k].extend(streams[k]))
+             for k in range(4)]
+    try:
+        for t in pulls:
+            t.start()
+        for t in pulls:
+            t.join(30)
+    finally:
+        for ahead in streams:
+            ahead.close()
+    assert got == [[(i, (s, i)) for i in range(8)] for s in range(4)]
+    assert most[0] <= 4
+    assert max(a.threads_peak for a in streams) == most[0] > 1
+    assert pipeline._READERS.live == 0 and pipeline._READERS.bytes == 0
+    # one live stream alone is bounded as before: by its own threads
+    alone = pipeline.RunAhead([_gen_task(1, [i], delay=0.002)
+                               for i in range(6)], threads=2, max_bytes=100)
+    try:
+        assert [item for _, item in alone] == list(range(6))
+    finally:
+        alone.close()
+    assert 1 <= alone.threads_peak <= 2
+
+
+def test_live_streams_share_the_byte_budget_and_none_waits_on_another():
+    """A stream that holds something admits only while all live streams
+    together stay within the budget; one that holds nothing always
+    admits its next task, so a stream whose consumer is not pulling
+    cannot stall the others."""
+    parked = pipeline.RunAhead([_gen_task(40, [i]) for i in range(4)],
+                               threads=2, max_bytes=100, name="parked")
+    try:
+        # nobody pulls ``parked``: it fills its share of the budget
+        deadline = time.monotonic() + 10
+        while len(parked._done) < 2 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert pipeline._READERS.bytes == 80 and parked._next == 2
+        other = pipeline.RunAhead([_gen_task(40, [i]) for i in range(4)],
+                                  threads=2, max_bytes=100, name="other")
+        try:
+            # 80 + 40 > 100: one task at a time, and yet all of them
+            assert other._next == 1
+            assert [item for _, item in other] == [0, 1, 2, 3]
+            assert other.pooled == 4
+        finally:
+            other.close()
+        assert pipeline._READERS.bytes == 80
+        assert [item for _, item in parked] == [0, 1, 2, 3]
+    finally:
+        parked.close()
+    assert pipeline._READERS.bytes == 0 and pipeline._READERS.live == 0
+
+
+@pytest.mark.parametrize("pulling", ["one_at_a_time", "all_at_once"])
+def test_runs_held_together_never_wait_on_another_streams_bytes(pulling):
+    """Streams whose tasks give their bytes back a run at a time (a scan
+    batch's files, ``hold_until``), with more runs between them than the
+    budget holds: a run whose first task is admitted is admitted to its
+    end whatever the other streams hold, so a consumer that has taken
+    part of a run never waits for a task the budget refuses."""
+    def stream(s):
+        return pipeline.RunAhead(
+            [_gen_task(30, [(s, i)], delay=0.001) for i in range(6)],
+            threads=2, max_bytes=100, name=f"runs{s}",
+            hold_until=[1, 1, 3, 3, 5, 5])
+    want = [[(i, (s, i)) for i in range(6)] for s in range(4)]
+    streams = [stream(0)]
+    try:
+        # nobody pulls stream 0 yet: it runs ahead to the budget's edge
+        deadline = time.monotonic() + 10
+        while len(streams[0]._done) < 3 and time.monotonic() < deadline:
+            time.sleep(0.005)
+        assert pipeline._READERS.bytes == 90 and streams[0]._next == 3
+        streams += [stream(s) for s in (1, 2, 3)]
+        # each of the others: its first run whole (30 + 30), over the
+        # process's budget, and not the first task of its second
+        assert [a._next for a in streams[1:]] == [2, 2, 2]
+        assert pipeline._READERS.bytes == 90 + 3 * 60
+        got = [[] for _ in streams]
+        if pulling == "one_at_a_time":
+            # the others finish while stream 0's consumer stands still
+            order = [[1], [2], [3], [0]]
+        else:
+            order = [[0, 1, 2, 3]]
+        for ks in order:
+            pulls = [threading.Thread(
+                target=lambda k=k: got[k].extend(streams[k]),
+                daemon=True) for k in ks]
+            for t in pulls:
+                t.start()
+            for t in pulls:
+                t.join(30)
+            assert not [t for t in pulls if t.is_alive()], "a stream stalled"
+        assert got == want
+    finally:
+        for ahead in streams:
+            ahead.close()
+    assert pipeline._READERS.bytes == 0 and pipeline._READERS.live == 0
+
+
 def test_run_ahead_same_table_same_threads_conf_and_fault_scope():
     from spark_rapids_tpu.conf import active_conf
     from spark_rapids_tpu.robustness import faults

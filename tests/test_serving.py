@@ -411,3 +411,210 @@ def test_in_process_queries_stay_untagged():
     starts = [r for r in captured if r.get("event") == "QueryStart"]
     assert starts and all("tenant" not in r and "session_id" not in r
                           for r in starts)
+
+
+# -------------------------------------- concurrent streams: the record
+
+NEW_PHASES = ("admission_wait_ns", "semaphore_wait_ns", "serve_ns")
+
+
+def _served_concurrently(s, clients=4, rounds=1):
+    """``clients`` sessions submit Q_SUM / Q_CNT at once, ``rounds``
+    times each; returns [(sql, pydict, EOS info)] of every answer."""
+    answers, errors = [], []
+    together = threading.Barrier(clients)
+
+    def run(i):
+        try:
+            with SqlClient(server.endpoint, tenant=f"stream-{i}") as c:
+                together.wait(60)
+                for r in range(rounds):
+                    sql = Q_SUM if (i + r) % 2 == 0 else Q_CNT
+                    got = c.submit(sql)
+                    answers.append((sql, got.to_pydict(), got.info))
+        except BaseException as e:  # noqa: BLE001
+            errors.append((i, repr(e)))
+
+    with SqlServer(s) as server:
+        threads = [threading.Thread(target=run, args=(i,))
+                   for i in range(clients)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+    assert not errors, errors
+    assert _drain(s.conf)
+    return answers
+
+
+def _records(answers):
+    from spark_rapids_tpu.obs.registry import registry
+    by_id = {r["query_id"]: r for r in registry().queries()}
+    return [by_id[info["query_id"]] for _, _, info in answers]
+
+
+def test_four_concurrent_streams_get_the_reference_answers_and_phases():
+    s = _session({"srt.sql.concurrentQueryTasks": "4"})
+    reset_query_semaphore(s.conf)
+    oracles = {q: _rows_to_pydict(s.sql(q).collect())
+               for q in (Q_SUM, Q_CNT)}
+    answers = _served_concurrently(s, clients=4, rounds=2)
+    assert len(answers) == 8
+    for sql, got, info in answers:
+        assert got == oracles[sql]
+        assert info["status"] == "ok" and info["cache"] == "off"
+        # the trailer carries the query's own phases
+        assert all(k in info["phases"] for k in NEW_PHASES)
+    for rec in _records(answers):
+        phases = rec["phases"]
+        assert all(k in phases for k in NEW_PHASES)
+        # four streams under four permits: nobody queues
+        assert phases["admission_wait_ns"] == 0
+        assert phases["serve_ns"] > 0 and phases["semaphore_wait_ns"] >= 0
+
+
+def test_one_admission_permit_makes_the_queued_streams_wait():
+    s = _session({"srt.sql.concurrentQueryTasks": "1",
+                  "srt.sql.admission.maxQueueDepth": "8",
+                  "srt.sql.admission.backoffBaseSec": "0.01"})
+    reset_query_semaphore(s.conf)
+    answers = _served_concurrently(s, clients=4)
+    waits = sorted(r["phases"]["admission_wait_ns"]
+                   for r in _records(answers))
+    # the first in runs at once; whoever arrives while it runs queues
+    assert waits[0] == 0 and waits[-1] > 0
+    tiers = [info["tier"] for _, _, info in answers]
+    assert tiers.count("queued") == sum(w > 0 for w in waits)
+    assert tiers.count("immediate") == sum(w == 0 for w in waits)
+    # a queued query's wait is no part of its execution span, nor of
+    # what its server spent around the session
+    for rec in _records(answers):
+        p = rec["phases"]
+        assert rec["wall_ns"] == p["execute_ns"]
+        assert p["serve_ns"] > 0
+
+
+def test_admission_wait_is_not_counted_as_semaphore_wait():
+    from spark_rapids_tpu.memory.budget import task_context
+    from spark_rapids_tpu.robustness.admission import (QueryContext,
+                                                       QuerySemaphore)
+    sem = QuerySemaphore(1, max_queue_depth=4, backoff_base_s=0.01)
+    sem.acquire(QueryContext("holder"))
+    waited = {}
+
+    def queued():
+        before = task_context().semaphore_wait_ns
+        token = QueryContext("queued")
+        sem.acquire(token)
+        sem.release()
+        waited["admission"] = token.admission_wait_ns
+        waited["semaphore"] = task_context().semaphore_wait_ns - before
+
+    t = threading.Thread(target=queued)
+    t.start()
+    time.sleep(0.05)
+    sem.release()
+    t.join(30)
+    assert waited["admission"] > 0 and waited["semaphore"] == 0
+
+
+def test_the_sessions_conf_sizes_the_device_semaphore():
+    from spark_rapids_tpu.exec.base import (device_semaphore,
+                                            reset_device_semaphore)
+    try:
+        reset_device_semaphore()
+        s = _session({"srt.sql.concurrentTpuTasks": "1"})
+        s.sql(Q_CNT).collect()
+        assert device_semaphore().permits == 1
+        assert reset_device_semaphore(
+            SrtConf({"srt.sql.concurrentTpuTasks": "3"})).permits == 3
+    finally:
+        reset_device_semaphore()
+
+
+def test_a_querys_blocked_time_at_the_device_gate_is_its_own():
+    """Two queries' threads over a one-permit semaphore: the one that
+    stood blocked records the wait on its own token, the holder none."""
+    from spark_rapids_tpu.exec.base import (ExecContext,
+                                            reset_device_semaphore)
+    from spark_rapids_tpu.robustness.admission import (QueryContext,
+                                                       query_scope)
+    try:
+        conf = SrtConf({"srt.sql.concurrentTpuTasks": "1"})
+        reset_device_semaphore(conf)
+        holder, blocked = QueryContext("holder"), QueryContext("blocked")
+        sem = ExecContext(conf, query=holder).semaphore
+
+        def stand():
+            with query_scope(blocked), sem:
+                pass
+        with query_scope(holder):
+            sem.acquire_if_necessary()
+            t = threading.Thread(target=stand)
+            t.start()
+            time.sleep(0.05)
+            sem.release_if_held()
+        t.join(30)
+        assert blocked.semaphore_wait_ns >= 40_000_000
+        assert holder.semaphore_wait_ns == 0
+    finally:
+        reset_device_semaphore()
+
+
+def test_two_streams_scan_placed_batches_over_one_byte_budget(tmp_path):
+    """Two clients at once over a table of eight files whose scan lays
+    three files to a batch (``hold_until`` runs of the reader pool),
+    with a byte budget that holds one such batch and not two: neither
+    stream may come to wait for a file the other's bytes keep out, in a
+    plain scan or under a join that starts both its scans at once."""
+    import numpy as np
+    import pyarrow as pa
+    import pyarrow.parquet as pq
+    for i in range(8):
+        lo = 400 * i
+        pq.write_table(pa.table({
+            "a": np.arange(lo, lo + 400, dtype=np.int64),
+            "b": np.arange(lo, lo + 400) / 4.0}),
+            str(tmp_path / f"part-{i:05d}.parquet"))
+    s = TpuSession(SrtConf({
+        "srt.shuffle.partitions": 2,
+        "srt.sql.format.parquet.nativeDecode.enabled": "true",
+        "srt.sql.reader.batchSizeRows": "1024",
+        "srt.exec.pipeline.maxBytesInFlight": "40000"}))
+    s.create_or_replace_temp_view("m", s.read.parquet(str(tmp_path)))
+    q_scan = "SELECT count(*) AS n, sum(a) AS sa, sum(b) AS sb FROM m"
+    q_join = ("SELECT count(*) AS n, sum(x.b + y.b) AS sb "
+              "FROM m x JOIN m y ON x.a = y.a")
+    want = {q_scan: {"n": [3200], "sa": [3200 * 3199 // 2],
+                     "sb": [3200 * 3199 / 8.0]},
+            q_join: {"n": [3200], "sb": [3200 * 3199 / 4.0]}}
+    answers, errors = [], []
+    together = threading.Barrier(2)
+
+    def run(i):
+        try:
+            with SqlClient(server.endpoint, tenant=f"stream-{i}") as c:
+                together.wait(60)
+                for sql in (q_scan, q_join, q_scan):
+                    got = c.submit(sql, timeout_ms=60_000)
+                    answers.append((sql, got.to_pydict(), got.info))
+        except BaseException as e:  # noqa: BLE001
+            errors.append((i, repr(e)))
+
+    with SqlServer(s) as server:
+        threads = [threading.Thread(target=run, args=(i,), daemon=True)
+                   for i in range(2)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(120)
+        assert not [t for t in threads if t.is_alive()], "a stream stalled"
+    assert not errors, errors
+    assert len(answers) == 6
+    for sql, got, info in answers:
+        assert got == want[sql]
+        # three batches a scan, each decoded into its own buffers
+        assert info["phases"]["scan_inplace_batches"] >= 3
+    assert _drain(s.conf)
+    from spark_rapids_tpu.exec import pipeline
+    assert pipeline._READERS.bytes == 0 and pipeline._READERS.live == 0
