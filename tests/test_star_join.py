@@ -255,6 +255,47 @@ def test_lookup_join_with_an_empty_build(join_type):
     assert len(results[0]) == (12 if join_type in (J.LEFT_OUTER, J.RIGHT_OUTER, J.LEFT_ANTI) else 0)
 
 
+def _keep(cap, one_in, seed=34):
+    """``cap`` flags: none kept (``one_in`` 0), all kept (1), or each kept with probability 1 / ``one_in``."""
+    if one_in < 2:
+        return np.full(cap, bool(one_in))
+    return np.random.default_rng(seed).integers(0, one_in, cap) == 0
+
+
+#: case -> (keep flags, out_capacity); 2^14 rows at most: eager, a few small programs
+FIRST_KEPT = {
+    "nothing kept": (_keep(1024, 0), 64),
+    "everything kept": (_keep(1024, 1), 1024),
+    "everything kept, a prefix asked for": (_keep(4096, 1), 128),
+    "1/12 kept, out_capacity below the kept count": (_keep(16384, 12), 1024),
+    "1/12 kept, out_capacity above the kept count": (_keep(4096, 12), 512),
+    "out_capacity equal to the kept count": (np.arange(4096) % 8 == 3, 512),
+    "out_capacity above cap": (_keep(1024, 2), 2048),
+    "the last row alone": (np.arange(2048) == 2047, 16),
+    "cap 8": (_keep(8, 2), 16),
+    "cap 64": (_keep(64, 3), 16),
+    "cap 1000, no power of two": (_keep(1000, 12), 128),
+    "cap 3000, over 1024 and no multiple of it": (_keep(3000, 2), 2048),
+    "1/12 kept, a 1024th of the rows asked for": (_keep(16384, 12), 16),
+    "a 1024th of the rows asked for, fewer kept": (np.arange(16384) % 2000 == 1999, 16),
+    "a 1024th of the rows asked for, nothing kept": (_keep(8192, 0), 8),
+    "a 1024th of the rows asked for, the last row alone": (np.arange(8192) == 8191, 8),
+}
+
+
+@pytest.mark.parametrize("case", list(FIRST_KEPT))
+def test_first_kept_equals_flatnonzero(case):
+    """Entry j is the position of the j-th kept row; past the last kept row, the capacity's last row."""
+    import jax.numpy as jnp
+    from spark_rapids_tpu.ops import kernels as K
+    keep, out_capacity = FIRST_KEPT[case]
+    kept = np.flatnonzero(keep)[:out_capacity]
+    want = np.full(out_capacity, len(keep) - 1, np.int32)
+    want[:len(kept)] = kept
+    got = np.asarray(K.first_kept(jnp.asarray(keep), out_capacity))
+    assert got.dtype == np.int32 and np.array_equal(got, want)
+
+
 def _pandas_inner(probe, build, keys, build_keys):
     left, right = pd.DataFrame(probe), pd.DataFrame(build)
     left = left.dropna(subset=list(keys))
