@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..jit_registry import read_back, read_int
 from . import dtypes as dt
 
 _U32 = np.uint64(0xFFFFFFFF)
@@ -69,13 +70,13 @@ class Decimal128Column:
         return Decimal128Column(hi, lo, validity, self.dtype)
 
     def to_numpy(self, num_rows: Optional[int] = None):
-        n = self.capacity if num_rows is None else int(num_rows)
-        hi = np.asarray(self.hi)[:n].astype(object)
-        lo = np.asarray(self.lo)[:n].astype(object)
+        n = self.capacity if num_rows is None else read_int(num_rows)
+        hi = read_back(self.hi)[0][:n].astype(object)
+        lo = read_back(self.lo)[0][:n].astype(object)
         vals = np.empty(n, dtype=object)
         for i in range(n):
             vals[i] = int(hi[i]) * (1 << 64) + int(lo[i])
-        return vals, np.asarray(self.validity)[:n]
+        return vals, read_back(self.validity)[0][:n]
 
     def __repr__(self):
         return f"Decimal128Column({self.dtype}, capacity={self.capacity})"

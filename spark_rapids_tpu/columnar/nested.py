@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..jit_registry import read_back, read_int
 from . import dtypes as dt
 
 
@@ -126,8 +127,8 @@ class ListColumn:
         """Host copy: object array of python lists (logical values);
         map-typed columns rebuild dicts from their entry structs."""
         from .vector import from_physical
-        n = self.capacity if num_rows is None else int(num_rows)
-        offs = np.asarray(self.offsets)
+        n = self.capacity if num_rows is None else read_int(num_rows)
+        offs, = read_back(self.offsets)
         child_vals, child_mask = self.child.to_numpy()
         et = self.dtype.element_type
         as_map = self._map_type is not None
@@ -142,7 +143,7 @@ class ListColumn:
                           if e is not None}
             else:
                 out[i] = items
-        return out, np.asarray(self.validity)[:n]
+        return out, read_back(self.validity)[0][:n]
 
     def __repr__(self):
         return (f"ListColumn({self.dtype}, capacity={self.capacity}, "
@@ -195,7 +196,7 @@ class StructColumn:
         return StructColumn(kids, validity, self.dtype)
 
     def to_numpy(self, num_rows: Optional[int] = None):
-        n = self.capacity if num_rows is None else int(num_rows)
+        n = self.capacity if num_rows is None else read_int(num_rows)
         field_data = []
         for c, (fname, ftype) in zip(self.children, self.dtype.fields):
             vals, mask = c.to_numpy(n)
@@ -204,7 +205,7 @@ class StructColumn:
         for i in range(n):
             out[i] = {fname: _child_value(vals, mask, i, ftype)
                       for fname, ftype, vals, mask in field_data}
-        return out, np.asarray(self.validity)[:n]
+        return out, read_back(self.validity)[0][:n]
 
     def __repr__(self):
         return f"StructColumn({self.dtype}, capacity={self.capacity})"

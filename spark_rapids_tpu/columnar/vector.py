@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..jit_registry import read_back, read_int
 from . import dtypes as dt
 
 
@@ -109,8 +110,10 @@ class ColumnVector:
 
     def to_numpy(self, num_rows: Optional[int] = None):
         """Host copy of live values as a (values, mask) pair."""
-        n = self.capacity if num_rows is None else int(num_rows)
-        return np.asarray(self.data)[:n], np.asarray(self.validity)[:n]
+        n = self.capacity if num_rows is None else read_int(num_rows)
+        data, = read_back(self.data)
+        validity, = read_back(self.validity)
+        return data[:n], validity[:n]
 
     def __repr__(self):
         return f"ColumnVector({self.dtype}, capacity={self.capacity})"
@@ -222,13 +225,14 @@ class StringColumn:
         return StringColumn(new_offsets, new_chars, validity, self.pad_bucket)
 
     def to_numpy(self, num_rows: Optional[int] = None):
-        n = self.capacity if num_rows is None else int(num_rows)
-        offs = np.asarray(self.offsets)
-        chars = np.asarray(self.chars).tobytes()
+        n = self.capacity if num_rows is None else read_int(num_rows)
+        offs, = read_back(self.offsets)
+        chars = read_back(self.chars)[0].tobytes()
         vals = np.array(
             [chars[offs[i]:offs[i + 1]].decode("utf-8", errors="replace") for i in range(n)],
             dtype=object)
-        return vals, np.asarray(self.validity)[:n]
+        validity, = read_back(self.validity)
+        return vals, validity[:n]
 
     def __repr__(self):
         return f"StringColumn(capacity={self.capacity}, char_capacity={self.char_capacity})"

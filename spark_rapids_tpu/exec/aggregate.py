@@ -28,7 +28,8 @@ from ..columnar.vector import (ColumnVector, ColumnarBatch, choose_capacity,
                                live_mask)
 from ..expr.aggregates import AggregateFunction
 from ..expr.core import Expression, make_result, output_name
-from ..jit_registry import named_jit, shared_fn_jit, shared_method_jit
+from ..jit_registry import (named_jit, read_back, read_int, shared_fn_jit,
+                            shared_method_jit)
 from ..ops import kernels as K
 from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
 
@@ -60,7 +61,7 @@ def count_lane_flags(metrics: dict, flags: list) -> None:
     if not flags:
         return
     import numpy as np
-    lane, direct = np.sum([np.asarray(f) for f in flags], axis=0)
+    lane, direct = np.sum([read_back(f)[0] for f in flags], axis=0)
     for name, n in (("pallasBatches", lane),
                     ("groupsResolvedDirect", direct),
                     ("groupsHashClaimed", len(flags) - direct)):
@@ -359,7 +360,7 @@ class HashAggregateExec(TpuExec):
         grouped_fn = self._grouped_pallas_fn(ctx)
         used_flags: List = []
         for batch in self.children[0].execute(ctx):
-            if int(batch.num_rows) == 0:
+            if read_int(batch.num_rows) == 0:
                 continue
             with ctx.semaphore, NvtxTimer(agg_time, "agg.update"):
                 if grouped_fn is not None:
@@ -370,7 +371,7 @@ class HashAggregateExec(TpuExec):
                 else:
                     partial = self._jit_update(batch,
                                                jnp.int64(row_offset))
-            row_offset += int(batch.num_rows)
+            row_offset += read_int(batch.num_rows)
             yield partial
         count_lane_flags(ctx.metrics_for(self.exec_id), used_flags)
 
@@ -388,9 +389,9 @@ class HashAggregateExec(TpuExec):
         total = 0
         try:
             for p in partials:
-                if int(p.num_rows) == 0:
+                if read_int(p.num_rows) == 0:
                     continue
-                total += int(p.num_rows)
+                total += read_int(p.num_rows)
                 held.append(with_retry_no_split(
                     lambda b=p: SpillableBatch(
                         b, SpillPriority.ACTIVE_ON_DECK)))
@@ -431,7 +432,7 @@ class HashAggregateExec(TpuExec):
         """Shrink a compacted bucket to its tight capacity (compact
         keeps the source capacity; without this the fallback would
         inflate the merge set ~P times)."""
-        n = int(batch.num_rows)
+        n = read_int(batch.num_rows)
         cap = choose_capacity(max(n, 8))
         if cap >= batch.capacity:
             return batch
@@ -463,7 +464,7 @@ class HashAggregateExec(TpuExec):
                 for p in range(P):
                     with ctx.semaphore:
                         sub = split(batch, jnp.int32(p))
-                    n = int(sub.num_rows)
+                    n = read_int(sub.num_rows)
                     if n:
                         sub = self._repack(ctx, sub)
                         bucket_rows[p] += n
@@ -630,7 +631,7 @@ class HashAggregateExec(TpuExec):
             totals = plan.init_totals()
             saw = False
             for batch in source.execute(ctx):
-                if int(batch.num_rows) == 0:
+                if read_int(batch.num_rows) == 0:
                     continue
                 saw = True
                 with ctx.semaphore, NvtxTimer(agg_time, "agg.pallas"):

@@ -16,6 +16,7 @@ from typing import Dict, Iterator, List, Optional, Sequence
 from ..columnar import dtypes as dt
 from ..columnar.vector import ColumnarBatch
 from ..conf import CONCURRENT_TASKS, SrtConf, active_conf
+from ..jit_registry import read_int
 from ..obs.trace import annotate
 
 Schema = List  # [(name, DType), ...]
@@ -179,15 +180,13 @@ class TpuSemaphore:
                 return
         # uncontended fast path: only actual blocking counts as wait
         # (GpuTaskMetrics semaphore-wait accumulator), charged to the
-        # task and to the thread's current query, as a launch is
+        # thread's current query, as a launch is
         if not self._sem.acquire(blocking=False):
             t0 = time.perf_counter_ns()
             with annotate("semaphore.wait"):
                 self._sem.acquire()
             wait_ns = time.perf_counter_ns() - t0
-            from ..memory.budget import task_context
             from ..robustness.admission import current_query
-            task_context().semaphore_wait_ns += wait_ns
             query = current_query()
             if query is not None:
                 query.count_semaphore_wait(wait_ns)
@@ -389,7 +388,7 @@ class TpuExec:
             "numOutputBatches", Metric("numOutputBatches",
                                        Metric.MODERATE))
         for b in stream:
-            rows.add(int(b.num_rows))
+            rows.add(read_int(b.num_rows))
             batches.add(1)
             yield b
 
@@ -432,7 +431,7 @@ class TpuExec:
                     if dump_dir:
                         ctx.dump_crash(self, e, dump_dir)
                     raise
-            rows.add(int(batch.num_rows))
+            rows.add(read_int(batch.num_rows))
             batches.add(1)
             if dump_dir:
                 ctx.last_batches[self.exec_id] = \

@@ -21,7 +21,7 @@ from ..columnar import dtypes as dt
 from ..columnar.vector import (ColumnVector, ColumnarBatch, choose_capacity,
                                live_mask)
 from ..expr.core import Expression, output_name
-from ..jit_registry import shared_fn_jit, shared_method_jit
+from ..jit_registry import read_int, shared_fn_jit, shared_method_jit
 from ..ops import kernels as K
 from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
 
@@ -93,7 +93,7 @@ class ProjectExec(TpuExec):
             with ctx.semaphore:
                 out = self._jit_ctx(batch, jnp.int64(offset),
                                     jnp.int32(ctx.partition_id))
-            offset += int(batch.num_rows)
+            offset += read_int(batch.num_rows)
             yield out
 
     def node_description(self) -> str:
@@ -155,7 +155,7 @@ class LocalLimitExec(TpuExec):
                 return
             with ctx.semaphore:
                 out = self._jit(batch, jnp.int64(remaining))
-            remaining -= int(out.num_rows)
+            remaining -= read_int(out.num_rows)
             yield out
 
     def node_description(self) -> str:
@@ -322,7 +322,7 @@ class CoalesceBatchesExec(TpuExec):
                 wait.add(_time.perf_counter_ns() - t0)
                 break
             wait.add(_time.perf_counter_ns() - t0)
-            n = int(batch.num_rows)
+            n = read_int(batch.num_rows)
             if n == 0:
                 continue
             if n >= target and not pending:
@@ -394,7 +394,7 @@ class SampleExec(TpuExec):
         for batch in self.children[0].execute(ctx):
             with ctx.semaphore:
                 out = self._jit(batch, jnp.int64(offset))
-            offset += int(batch.num_rows)
+            offset += read_int(batch.num_rows)
             yield out
 
     def node_description(self) -> str:

@@ -36,7 +36,7 @@ from ..columnar.vector import (ColumnVector, ColumnarBatch, StringColumn,
                                choose_capacity)
 from ..conf import SHUFFLE_PARTITIONS
 from ..expr.core import Expression
-from ..jit_registry import shared_fn_jit
+from ..jit_registry import read_int, shared_fn_jit
 from ..ops import kernels as K
 from ..parallel.partition import (PartitionedBatch, hash_partition_ids,
                                   partition_batch, range_partition_ids,
@@ -232,7 +232,7 @@ class ShuffleExchangeExec(TpuExec):
                         // max(len(batches), 1))
         samples: List[tuple] = []  # row tuples of physical values
         for b in batches:
-            n = int(b.num_rows)
+            n = read_int(b.num_rows)
             take = min(n, per_batch)
             if take == 0:
                 continue
@@ -368,7 +368,7 @@ class ShuffleExchangeExec(TpuExec):
             try:
                 from ..memory.retry import with_retry_no_split
                 for batch in self.children[0].execute(ctx):
-                    if int(batch.num_rows) == 0:
+                    if read_int(batch.num_rows) == 0:
                         continue
                     held.append(with_retry_no_split(
                         lambda b=batch: SpillableBatch(
@@ -398,7 +398,7 @@ class ShuffleExchangeExec(TpuExec):
                             local_ok=ctx.cluster is None)
                     write_bytes.add(with_retry_no_split(write_one))
                     part_time.add(time.perf_counter_ns() - t0)
-                    write_rows.add(int(batch.num_rows))
+                    write_rows.add(read_int(batch.num_rows))
                     if push_route is not None:
                         mgr.push_map_output(self.shuffle_id, map_id,
                                             push_route,
@@ -547,7 +547,7 @@ class ShuffleExchangeExec(TpuExec):
         from ..memory.retry import with_retry_no_split
         written: List[int] = []
         for batch in child.execute(ctx):
-            if int(batch.num_rows) == 0:
+            if read_int(batch.num_rows) == 0:
                 continue
             t0 = time.perf_counter_ns()
 
@@ -563,7 +563,7 @@ class ShuffleExchangeExec(TpuExec):
                 wrote = mgr.write_map_output(
                     self.shuffle_id, map_id, parts,
                     local_ok=ctx.cluster is None)
-                return int(b.num_rows), wrote
+                return read_int(b.num_rows), wrote
             rows_written, bytes_written = with_retry_no_split(write_one)
             part_time.add(time.perf_counter_ns() - t0)
             write_rows.add(rows_written)
@@ -967,10 +967,10 @@ class BroadcastExchangeExec(TpuExec):
                 stream = self.children[0].execute(ctx)
             with NvtxTimer(bt, "broadcast.build"):
                 batches = [b for b in stream
-                           if int(b.num_rows) > 0]
+                           if read_int(b.num_rows) > 0]
                 if not batches:
                     return None
-                total = sum(int(b.num_rows) for b in batches)
+                total = sum(read_int(b.num_rows) for b in batches)
                 with ctx.semaphore:
                     self._materialized = (
                         batches[0] if len(batches) == 1

@@ -47,7 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar.vector import ColumnarBatch
-from ..jit_registry import shared_fn_jit
+from ..jit_registry import read_int, shared_fn_jit
 from ..jit_registry import stats as _registry_stats
 from ..ops import kernels as K
 from .aggregate import count_lane_flags
@@ -392,7 +392,7 @@ class FusedPipelineExec(TpuExec):
             if st is self._agg:
                 break
             cur = st._jit(cur)
-            if int(cur.num_rows) == 0:
+            if read_int(cur.num_rows) == 0:
                 return False
         parent = None
         for frame in reversed(ctx.timer_stack):
@@ -453,7 +453,7 @@ class FusedPipelineExec(TpuExec):
                 if self._agg is not None:
                     out, rows_in, used = self._fn(
                         batch, jnp.int64(state["offset"]))
-                    n_in = int(rows_in)
+                    n_in = read_int(rows_in)
                     state["offset"] += n_in
                     if masked is not None:
                         masked.add(1)
@@ -472,7 +472,7 @@ class FusedPipelineExec(TpuExec):
             return out
 
         for batch in self.children[0].execute(ctx):
-            if int(batch.num_rows) == 0:
+            if read_int(batch.num_rows) == 0:
                 continue
             if not calibrated:
                 calibrated = self._calibrate(ctx, batch,
@@ -690,13 +690,13 @@ class FusedHashJoinExec(TpuExec):
         grouped_fn = self._agg._grouped_pallas_fn(ctx) \
             if self._use_pallas and self._agg is not None else None
         for batch in stream:
-            if int(batch.num_rows) == 0:
+            if read_int(batch.num_rows) == 0:
                 continue
             cur = batch
             emit = True
             for stage in self.suffix:
                 if stage is self._agg:
-                    n_in = int(cur.num_rows)
+                    n_in = read_int(cur.num_rows)
                     if n_in == 0:
                         emit = False
                         break

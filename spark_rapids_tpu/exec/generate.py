@@ -17,7 +17,7 @@ import jax
 from ..columnar import dtypes as dt
 from ..columnar.vector import ColumnarBatch, choose_capacity
 from ..expr.collections import Explode
-from ..jit_registry import shared_fn_jit
+from ..jit_registry import read_int, shared_fn_jit
 from ..ops import kernels as K
 from .base import ExecContext, Metric, Schema, TpuExec
 
@@ -70,13 +70,13 @@ class GenerateExec(TpuExec):
                                Metric("generateOverflowRetries",
                                       Metric.DEBUG))
         for batch in self.children[0].execute(ctx):
-            if int(batch.num_rows) == 0:
+            if read_int(batch.num_rows) == 0:
                 continue
             out_cap = choose_capacity(max(batch.capacity, 16))
             for _ in range(_MAX_GROWTH_STEPS + 1):
                 with ctx.semaphore:
                     out, total = self._fn(out_cap)(batch)
-                total = int(total)
+                total = read_int(total)
                 if total <= out_cap:
                     break
                 retries.add(1)

@@ -22,13 +22,12 @@ from __future__ import annotations
 import time
 from typing import Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
-import jax
 import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
 from ..columnar.vector import ColumnarBatch, choose_capacity
 from ..expr.core import Expression
-from ..jit_registry import shared_fn_jit
+from ..jit_registry import read_back, read_int, shared_fn_jit
 from ..ops import kernels as K
 from ..obs.trace import annotate
 from .base import ExecContext, Metric, Schema, TpuExec
@@ -274,13 +273,13 @@ class _HashJoinBase(TpuExec):
             name, Metric(name, level, unit))
 
     def _read(self, ctx: ExecContext, *scalars):
-        """Host values of device scalars, read together: ONE round trip,
-        counted in ``joinReadbacks``. Values already on the host cost
-        nothing and count nothing."""
+        """Host values of device scalars, read together: ONE round trip
+        (``read_back``), counted in ``joinReadbacks``. Values already on
+        the host cost nothing and count nothing."""
         if all(isinstance(x, int) for x in scalars):
             return scalars
         self._counter(ctx, "joinReadbacks").add(1)
-        return tuple(int(x) for x in jax.device_get(scalars))
+        return tuple(int(x) for x in read_back(*scalars))
 
     # --- build side: computed once per build batch ---
     def _lookup_side(self, ctx: ExecContext, build: ColumnarBatch
@@ -511,7 +510,7 @@ class _HashJoinBase(TpuExec):
         """Shrink a compacted batch to its tight capacity bucket —
         compact() preserves the source capacity, so without this the
         sub-partition machinery would multiply, not bound, memory."""
-        n = int(batch.num_rows)
+        n = read_int(batch.num_rows)
         cap = choose_capacity(max(n, 8))
         if cap >= batch.capacity:
             return batch
@@ -537,14 +536,14 @@ class _HashJoinBase(TpuExec):
         skew_m = m.setdefault("joinSubPartitionSkew",
                               Metric("joinSubPartitionSkew", Metric.DEBUG))
         build = build_holder.pop()
-        P = max(2, -(-int(build.num_rows) // max(threshold, 1)))
+        P = max(2, -(-read_int(build.num_rows) // max(threshold, 1)))
         parts_m.add(P)
         sub_builds: List[Optional[SpillableBatch]] = []
         split_b = self._split_fn(P, "build")
         for p in range(P):
             with ctx.semaphore:
                 sub = split_b(build, jnp.int32(p))
-            if int(sub.num_rows) == 0:
+            if read_int(sub.num_rows) == 0:
                 sub_builds.append(None)
                 continue
             sub = self._repack(ctx, sub)
@@ -560,12 +559,12 @@ class _HashJoinBase(TpuExec):
         probe_buckets: List[List[SpillableBatch]] = [[] for _ in range(P)]
         try:
             for probe in probe_stream:
-                if int(probe.num_rows) == 0:
+                if read_int(probe.num_rows) == 0:
                     continue
                 for p in range(P):
                     with ctx.semaphore:
                         sub = split_p(probe, jnp.int32(p))
-                    if int(sub.num_rows) == 0:
+                    if read_int(sub.num_rows) == 0:
                         continue
                     sub = self._repack(ctx, sub)
                     from ..memory.retry import with_retry_no_split
@@ -585,7 +584,7 @@ class _HashJoinBase(TpuExec):
                     continue
                 from ..memory.retry import with_retry_no_split
                 bucket_build = with_retry_no_split(sb.get)
-                n_build = int(bucket_build.num_rows)
+                n_build = read_int(bucket_build.num_rows)
                 if n_build > threshold:
                     skew_m.add(1)
                 if n_build > threshold and self.join_type == INNER:
@@ -643,7 +642,7 @@ class _HashJoinBase(TpuExec):
         from ..conf import JOIN_BLOOM_BITS_PER_KEY
         min_rows = ctx.conf.get(JOIN_BLOOM_MIN_PROBE_ROWS)
         num_bits = B.choose_num_bits(
-            int(build.num_rows), ctx.conf.get(JOIN_BLOOM_BITS_PER_KEY))
+            read_int(build.num_rows), ctx.conf.get(JOIN_BLOOM_BITS_PER_KEY))
         eager = self._eager_keys()
         bkey = ("bloom_build", num_bits)
         if bkey not in self._jit_cache:
@@ -664,13 +663,13 @@ class _HashJoinBase(TpuExec):
 
         def filtered():
             for probe in probe_stream:
-                n = int(probe.num_rows)
+                n = read_int(probe.num_rows)
                 if n < min_rows:
                     yield probe
                     continue
                 with ctx.semaphore:
                     out = self._jit_cache[pkey](bits, probe)
-                dropped.add(n - int(out.num_rows))
+                dropped.add(n - read_int(out.num_rows))
                 yield out
         return filtered()
 
@@ -747,7 +746,7 @@ class _HashJoinBase(TpuExec):
         on probe-side partitioned scans."""
         for name, bk, scans in self._dpp_targets(ctx):
             kcol = bk.eval(build)
-            vals, mask = kcol.to_numpy(int(build.num_rows))
+            vals, mask = kcol.to_numpy(read_int(build.num_rows))
             keys = {v.item() if hasattr(v, "item") else v
                     for v, ok in zip(vals, mask) if ok}
             m = ctx.metrics_for(self.exec_id)

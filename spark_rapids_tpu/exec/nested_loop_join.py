@@ -25,7 +25,7 @@ from ..columnar import dtypes as dt
 from ..columnar.vector import (ColumnVector, ColumnarBatch, StringColumn,
                                choose_capacity, live_mask)
 from ..expr.core import Expression
-from ..jit_registry import shared_fn_jit
+from ..jit_registry import read_int, shared_fn_jit
 from ..ops import kernels as K
 from .base import ExecContext, Metric, Schema, TpuExec
 
@@ -112,17 +112,17 @@ class BroadcastNestedLoopJoinExec(TpuExec):
 
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         build_batches = [b for b in self.children[1].execute(ctx)
-                         if int(b.num_rows) > 0]
+                         if read_int(b.num_rows) > 0]
         if not build_batches:
             return
-        total_b = sum(int(b.num_rows) for b in build_batches)
+        total_b = sum(read_int(b.num_rows) for b in build_batches)
         with ctx.semaphore:
             build = (build_batches[0] if len(build_batches) == 1 else
                      K.concat_batches(build_batches,
                                       choose_capacity(total_b)))
         bcap = build.capacity
         for probe in self.children[0].execute(ctx):
-            n_probe = int(probe.num_rows)
+            n_probe = read_int(probe.num_rows)
             if n_probe == 0:
                 continue
             total_slots = probe.capacity * bcap
@@ -135,7 +135,7 @@ class BroadcastNestedLoopJoinExec(TpuExec):
                 with ctx.semaphore:
                     out = fn(probe, build, jnp.int32(n_probe),
                              jnp.int32(start), build.num_rows)
-                if int(out.num_rows) > 0:
+                if read_int(out.num_rows) > 0:
                     yield out
 
     def node_description(self) -> str:

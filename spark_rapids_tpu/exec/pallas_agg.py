@@ -53,6 +53,7 @@ from ..expr import arithmetic as A
 from ..expr import core as E
 from ..expr import predicates as Pr
 from ..expr.cast import Cast
+from ..jit_registry import read_back
 from ..ops import pallas_kernels as PK
 
 _SAFE_NODES = (
@@ -452,7 +453,7 @@ class PallasAggPlan:
 
     def combine(self, totals: List[float], partials) -> None:
         for i, (k, p) in enumerate(zip(self.kinds, partials)):
-            v = float(p)
+            v = float(read_back(p)[0])
             if k == PK.SUM:
                 totals[i] += v
             elif np.isnan(v) or np.isnan(totals[i]):

@@ -455,8 +455,11 @@ class PrefetchIterator:
                     item, n = self._buf.popleft()
                     self._bytes -= n
                     self._cv.notify_all()
-                    if waited and self._wait_metric is not None:
-                        self._wait_metric.add(waited)
+                    if waited:
+                        if self._wait_metric is not None:
+                            self._wait_metric.add(waited)
+                        if self._query is not None:
+                            self._query.count_scan_wait(waited)
                     return item
                 # buffered items drain before an error surfaces: the
                 # consumer sees exactly the prefix the producer emitted

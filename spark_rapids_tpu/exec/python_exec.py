@@ -14,7 +14,7 @@ import io
 from typing import Iterator, List, Tuple
 
 from ..columnar.vector import ColumnarBatch
-from ..jit_registry import named_jit
+from ..jit_registry import named_jit, read_int
 from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
 
 
@@ -68,7 +68,7 @@ class ArrowEvalPythonExec(TpuExec):
         pool = worker_pool()
         names = [n for n, _ in self._out_schema]
         for batch in self.children[0].execute(ctx):
-            if int(batch.num_rows) == 0:
+            if read_int(batch.num_rows) == 0:
                 continue
             with ctx.semaphore:
                 inputs = self._jit_inputs(batch)

@@ -25,7 +25,7 @@ from ..columnar import dtypes as dt
 from ..columnar.vector import (ColumnVector, ColumnarBatch,
                                choose_capacity, live_mask)
 from ..expr.core import Expression
-from ..jit_registry import shared_fn_jit, shared_method_jit
+from ..jit_registry import read_int, shared_fn_jit, shared_method_jit
 from ..ops import kernels as K
 from .base import ExecContext, Metric, Schema, TpuExec
 
@@ -199,9 +199,9 @@ class SortExec(TpuExec):
         try:
             from ..memory.retry import with_retry_no_split
             for batch in stream:
-                if int(batch.num_rows) == 0:
+                if read_int(batch.num_rows) == 0:
                     continue
-                total += int(batch.num_rows)
+                total += read_int(batch.num_rows)
                 max_run = max(max_run, batch.capacity)
                 runs.append(with_retry_no_split(
                     lambda b=batch: SpillableBatch(
@@ -292,7 +292,7 @@ class SortExec(TpuExec):
         from ..memory.spill import SpillableBatch, SpillPriority
         C = max(256, budget // (2 * k))
         chunk_cap = choose_capacity(C)
-        n = int(run.num_rows)
+        n = read_int(run.num_rows)
         parts, part_heads = [], []
         fused = self._fused_chunk_head(C, chunk_cap) \
             if self._sort_fusion_on(ctx) else None
@@ -359,13 +359,13 @@ class SortExec(TpuExec):
             while True:
                 live_heads = [h for h in heads if h is not None]
                 if not live_heads:
-                    if carry is not None and int(carry.num_rows) > 0:
+                    if carry is not None and read_int(carry.num_rows) > 0:
                         yield carry
                     return
                 # pick the run whose pending chunk head is smallest
                 # (device comparison — exact sort semantics)
                 hs = pick_heads()
-                r = int(hs.column("__run").data[0])
+                r = read_int(hs.column("__run").data[0])
                 i = next_chunk[r]
                 chunk = with_retry_no_split(chunks[r][i].get)
                 chunks[r][i].close()
@@ -381,15 +381,15 @@ class SortExec(TpuExec):
                     with ctx.semaphore:
                         if carry is None:
                             return self._jit_sort(chunk)
-                        cap = choose_capacity(
-                            int(carry.num_rows) + int(chunk.num_rows))
+                        cap = choose_capacity(read_int(carry.num_rows)
+                                              + read_int(chunk.num_rows))
                         if fuse:
                             return self._fused_concat_sort(cap)(
                                 carry, chunk)
                         return self._jit_sort(K.concat_batches(
                             [carry, chunk], cap))
                 merged = with_retry_no_split(merge_step)
-                peak_m.set(max(peak_m.value, int(merged.num_rows)))
+                peak_m.set(max(peak_m.value, read_int(merged.num_rows)))
                 live_heads = [h for h in heads if h is not None]
                 if not live_heads:
                     carry = merged
@@ -402,12 +402,12 @@ class SortExec(TpuExec):
                     else:
                         bound = K.slice_batch(hs, 0, 1, 8)
                         n_le = self._jit_safe_prefix(merged, bound)
-                n = int(n_le)
+                n = read_int(n_le)
                 if n > 0:
                     with ctx.semaphore:
                         out = K.slice_batch(merged, 0, jnp.int32(n),
                                             choose_capacity(n))
-                        rest = int(merged.num_rows) - n
+                        rest = read_int(merged.num_rows) - n
                         carry = K.slice_batch(
                             merged, jnp.int32(n),
                             jnp.int32(max(rest, 0)),
@@ -442,7 +442,7 @@ class SortExec(TpuExec):
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         if not self.global_sort:
             for batch in self.children[0].execute(ctx):
-                if int(batch.num_rows) == 0:
+                if read_int(batch.num_rows) == 0:
                     continue
                 with ctx.semaphore:
                     yield self._jit_sort(batch)
@@ -495,14 +495,14 @@ class TopNExec(TpuExec):
         # not O(batches*input_capacity).
         part_cap = choose_capacity(self.limit)
         for batch in self.children[0].execute(ctx):
-            if int(batch.num_rows) == 0:
+            if read_int(batch.num_rows) == 0:
                 continue
             with ctx.semaphore:
                 part = self._jit_topn(batch)
                 if part.capacity > part_cap:
                     part = self._jit_shrink(part)
             partials.append(part)
-            total += int(part.num_rows)
+            total += read_int(part.num_rows)
         if not partials:
             return
         cap = choose_capacity(max(total, self.limit))

@@ -33,7 +33,7 @@ from ..columnar.vector import (Column, ColumnVector, ColumnarBatch,
 from ..expr.aggregates import (AggregateFunction, Average, Count, CountStar,
                                Max, Min, Sum)
 from ..expr.core import Expression, make_result
-from ..jit_registry import shared_method_jit
+from ..jit_registry import read_int, shared_method_jit
 from ..expr.window import (Lag, Lead, DenseRank, NTile, PercentRank, Rank,
                            RowNumber, WindowExpression, WindowFrame)
 from ..ops import kernels as K
@@ -511,9 +511,9 @@ class WindowExec(TpuExec):
         total = 0
         try:
             for b in stream:
-                if int(b.num_rows) == 0:
+                if read_int(b.num_rows) == 0:
                     continue
-                total += int(b.num_rows)
+                total += read_int(b.num_rows)
                 runs.append(with_retry_no_split(
                     lambda x=b: SpillableBatch(
                         x, SpillPriority.ACTIVE_ON_DECK)))
@@ -798,7 +798,7 @@ class BatchedRunningWindowExec(TpuExec):
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         state = self._zero_state()
         for batch in self.children[0].execute(ctx):
-            if int(batch.num_rows) == 0:
+            if read_int(batch.num_rows) == 0:
                 continue
             with ctx.semaphore:
                 out, state = self._jit(batch, state)

@@ -44,7 +44,13 @@ Launch ranges. Each dispatch of a program runs inside a host range
 on the dispatching thread, around the asynchronous dispatch and not
 around a sync; its wall time and a count are charged to the thread's
 current query token (``dispatch_ns`` / ``launches`` of the query
-record's ``phases``).
+record's ``phases``), with the thread's CPU time inside it
+(``dispatch_cpu_ns``: the rest is time the launch stood off a CPU).
+
+Read-backs. ``read_back`` is the inverse of a launch: every device
+value the engine reads to the host on a query's path goes through it,
+inside a ``device.read`` range, charged to the same token
+(``readback_ns`` / ``readbacks``).
 
 Reference role: the spark-rapids plugin loads/caches each cuDF kernel
 once per JVM, not once per operator instance
@@ -65,6 +71,7 @@ import time
 from typing import Callable, Dict, Optional, Sequence
 
 import jax
+import numpy as np
 
 from .obs.trace import annotate as _host_range
 from .robustness.admission import current_query
@@ -135,18 +142,87 @@ def _named(fn: Callable, label: str) -> Callable:
     return program
 
 
+_LAUNCHING = threading.local()
+
+
 def _dispatch(span: str, runner, args, kwargs):
     """Run one program launch inside its ``launch.<label>`` host range
-    and charge its wall time to the thread's current query. Dispatch is
-    asynchronous: this is the host's cost of a launch, not device
-    time."""
+    and charge its wall time, and the thread's CPU time within it, to
+    the thread's current query. Dispatch is asynchronous: this is the
+    host's cost of a launch, not device time. A program called while
+    another is being traced is part of that launch: not counted."""
+    if getattr(_LAUNCHING, "on", False):
+        return runner(*args, **kwargs)
+    _LAUNCHING.on = True
     t0 = time.perf_counter_ns()
-    with _host_range(span):
-        out = runner(*args, **kwargs)
+    c0 = time.thread_time_ns()
+    try:
+        with _host_range(span):
+            out = runner(*args, **kwargs)
+    finally:
+        _LAUNCHING.on = False
+    cpu_ns = time.thread_time_ns() - c0
     query = current_query()
     if query is not None:
-        query.count_launch(time.perf_counter_ns() - t0)
+        query.count_launch(time.perf_counter_ns() - t0, cpu_ns)
     return out
+
+
+_ON_HOST = (int, float, np.ndarray, np.generic)
+
+
+def _held_on_host(value) -> bool:
+    """A host value, or a device array whose host copy jax already holds
+    (read before: ``ArrayImpl._npy_value``), whose read is no round trip."""
+    return isinstance(value, _ON_HOST) \
+        or getattr(value, "_npy_value", None) is not None
+
+
+def _to_host(values: tuple) -> tuple:
+    """``values`` with each device array as a numpy array, read with the
+    device-to-host transfer guard lifted: several start their copies
+    before the first is waited for (one round trip, as ``jax.device_get``
+    makes it); one is a plain synchronous copy, without ``device_get``'s
+    tree walk and asynchronous copy (a row count is read ~230 times a
+    Q1 query: PERF.md, PR 35)."""
+    with jax.transfer_guard_device_to_host("allow"):
+        if len(values) > 1:
+            for v in values:
+                if isinstance(v, jax.Array):
+                    v.copy_to_host_async()
+        return tuple(np.asarray(v) if isinstance(v, jax.Array) else v
+                     for v in values)
+
+
+def read_back(*values) -> tuple:
+    """Host values of ``values``, read from the device together: one
+    explicit read (the transfer guard lifted for it alone) inside a
+    ``device.read`` host range, its wall charged to the thread's current
+    query (``readback_ns`` / ``readbacks``). The inverse of a launch, and
+    the one way the engine reads a device value on a query's path:
+    ``int()`` or ``np.asarray`` of a device array elsewhere is an
+    implicit read that nothing counts, and that
+    ``jax.transfer_guard_device_to_host("disallow")`` refuses
+    (``tools/audit_readbacks.py``). Values already on the host, and
+    device values read before, cost no round trip and count nothing; a
+    read while a program is traced is that launch's."""
+    if all(_held_on_host(v) for v in values):
+        return _to_host(values)
+    t0 = time.perf_counter_ns()
+    with _host_range("device.read"):
+        out = _to_host(values)
+    query = current_query()
+    if query is not None and not getattr(_LAUNCHING, "on", False):
+        query.count_readback(time.perf_counter_ns() - t0)
+    return out
+
+
+def read_int(value) -> int:
+    """``int(value)`` for a host or device scalar (a batch's
+    ``num_rows``), a device one read through ``read_back``."""
+    if isinstance(value, int):
+        return value
+    return int(read_back(value)[0])
 
 
 class _NamedProgram:

@@ -51,10 +51,9 @@ class TaskContext:
         self.split_count = 0
         self.spilled_bytes = 0
         self.alloc_attempts = 0
-        # GpuTaskMetrics.scala:81-146 accumulators
-        self.semaphore_wait_ns = 0
+        # GpuTaskMetrics.scala:81-146 accumulators (the semaphore's wait
+        # is the query's: QueryContext.semaphore_wait_ns)
         self.spill_time_ns = 0
-        self.retry_compute_ns = 0
         # test-only injection counters (None = disarmed)
         self._inject_retry_after: Optional[int] = None
         self._inject_split_after: Optional[int] = None
@@ -64,9 +63,7 @@ class TaskContext:
         return {"retryCount": self.retry_count,
                 "splitAndRetryCount": self.split_count,
                 "spilledBytes": self.spilled_bytes,
-                "semaphoreWaitTimeNs": self.semaphore_wait_ns,
-                "spillTimeNs": self.spill_time_ns,
-                "retryComputationTimeNs": self.retry_compute_ns}
+                "spillTimeNs": self.spill_time_ns}
 
     # --- fault injection (RmmSpark.forceRetryOOM analogue) ---
     def force_retry_oom(self, num_allocs_before: int = 0) -> None:

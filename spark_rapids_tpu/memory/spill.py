@@ -33,6 +33,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..columnar.vector import ColumnarBatch
+from ..jit_registry import read_int
 from ..obs import events as _events
 from ..robustness import faults as _faults
 from ..robustness.integrity import DataCorruption, array_checksum
@@ -159,7 +160,7 @@ class SpillableBatch:
         self.owner: Optional[str] = q.query_id if q is not None else None
         self._catalog.budget.reserve(self._nbytes, owner=self.owner)
         self._batch: Optional[ColumnarBatch] = batch
-        self._num_rows = int(batch.num_rows)
+        self._num_rows = read_int(batch.num_rows)
         self._host = None
         self._pooled: Optional[_PooledLeaves] = None
         self._treedef = None

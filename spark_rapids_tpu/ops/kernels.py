@@ -37,7 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
-from ..jit_registry import named_jit
+from ..jit_registry import named_jit, read_int
 from ..columnar.vector import (Column, ColumnVector, ColumnarBatch,
                                StringColumn, compaction_indices, live_mask,
                                round_pow2, rows_from_offsets)
@@ -1232,7 +1232,7 @@ def compact_for_transfer(batch: ColumnarBatch,
     padded capacity off the device. Only compacts when it saves at
     least ``slack``×; costs one host sync of the (scalar) row count."""
     from ..columnar.vector import choose_capacity
-    n = int(batch.num_rows)
+    n = read_int(batch.num_rows)
     cap = choose_capacity(n)
     if cap * slack > batch.capacity:
         return batch

@@ -33,6 +33,7 @@ from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 from ..columnar.vector import ColumnarBatch, choose_capacity
 from ..conf import (SHUFFLE_COMPRESS, SHUFFLE_MODE, SHUFFLE_PARTITIONS,
                     SrtConf, active_conf)
+from ..jit_registry import read_int
 from ..memory.spill import SpillPriority, SpillableBatch
 from ..robustness import integrity
 from ..robustness.faults import corrupt_point, fault_point
@@ -845,16 +846,17 @@ class ShuffleManager:
         local_rows: Dict[int, int] = {}
         local_bytes: Dict[int, int] = {}
         for reduce_id, batch in enumerate(partitions):
-            if batch is None or int(batch.num_rows) == 0:
+            n = 0 if batch is None else read_int(batch.num_rows)
+            if n == 0:
                 continue
-            local_rows[reduce_id] = int(batch.num_rows)
+            local_rows[reduce_id] = n
             block = (shuffle_id, map_id, reduce_id)
             if self.mode == "CACHE_ONLY" or bypass:
                 from ..memory.spill import batch_nbytes
                 nb = batch_nbytes(batch)
                 local_bytes[reduce_id] = nb
                 self.catalog.add(block, batch)
-                self.write_metrics.rows_written += int(batch.num_rows)
+                self.write_metrics.rows_written += n
                 self.write_metrics.blocks_written += 1
                 if bypass:
                     bypassed_nb += nb

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..columnar import dtypes as dt
+from ..jit_registry import read_int
 from ..columnar.vector import (ColumnarBatch, ColumnVector, StringColumn,
                                choose_capacity, column_from_numpy,
                                from_physical, string_column_from_utf8)
@@ -269,7 +270,7 @@ def table_to_batch(table: HostTable,
 
 def batch_to_table(batch: ColumnarBatch) -> HostTable:
     from ..columnar.nested import ListColumn, StructColumn
-    n = int(batch.num_rows)
+    n = read_int(batch.num_rows)
     cols = []
     for c in batch.columns:
         vals, mask = c.to_numpy(n)

@@ -22,6 +22,7 @@ from ..exec.join import JOIN_COUNTERS as _JOIN_COUNTERS
 from ..expr.aggregates import (Average, Count, CountStar, First, Last, Max,
                                Min, StddevSamp, Sum)
 from ..expr.core import Alias, ColumnRef, Expression, col, lit, output_name
+from ..jit_registry import read_int
 from ..obs.trace import annotate
 from . import logical as L
 from . import overrides
@@ -35,6 +36,7 @@ from .transitions import CpuPhysical, DeviceToHostBridge
 #: must stay small.
 import os as _os
 import sys as _sys
+import threading as _threading
 import time as _time
 
 try:
@@ -300,7 +302,15 @@ class TpuSession:
         tc0 = (tc.spilled_bytes, tc.retry_count, tc.split_count)
         # a caller's token may have run queries before this one
         launch0 = (qctx.dispatch_ns, qctx.launches,
-                   qctx.semaphore_wait_ns)
+                   qctx.semaphore_wait_ns, qctx.dispatch_cpu_ns,
+                   qctx.readback_ns, qctx.readbacks)
+        # this thread pulls the plan: its waits, launches and reads are
+        # split out of the execution's wall (``consumer_*`` phases)
+        consumer0 = qctx.consumer
+        qctx.consumer = _threading.get_ident()
+        mine0 = (qctx.consumer_scan_wait_ns,
+                 qctx.consumer_semaphore_wait_ns,
+                 qctx.consumer_dispatch_ns, qctx.consumer_readback_ns)
         fetch_ns = 0
         is_tpu = isinstance(physical, TpuExec)
         # serving identity fields ride on QueryStart/QueryEnd (only
@@ -329,7 +339,7 @@ class TpuSession:
                     reg = _registry()
                     tables = []
                     for b in adaptive_execute(physical, ctx):
-                        n = int(b.num_rows)
+                        n = read_int(b.num_rows)
                         if n == 0:
                             continue
                         # output-batch shape distributions (once per
@@ -372,6 +382,12 @@ class TpuSession:
             if self._active_query is qctx:
                 self._active_query = None
             wall_ns = _time.perf_counter_ns() - t0
+            qctx.consumer = consumer0
+            mine = dict(zip(CONSUMER_PHASES, (
+                qctx.consumer_scan_wait_ns - mine0[0],
+                qctx.consumer_semaphore_wait_ns - mine0[1],
+                qctx.consumer_dispatch_ns - mine0[2],
+                qctx.consumer_readback_ns - mine0[3])))
             _registry().observe("task_time_ns", wall_ns, "ns")
             summary = summarize_metrics(ctx.metrics,
                                         self.conf.get(METRICS_LEVEL))
@@ -385,7 +401,13 @@ class TpuSession:
                          dispatch_ns=qctx.dispatch_ns - launch0[0],
                          launches=qctx.launches - launch0[1],
                          semaphore_wait_ns=qctx.semaphore_wait_ns
-                         - launch0[2])}
+                         - launch0[2],
+                         dispatch_cpu_ns=qctx.dispatch_cpu_ns
+                         - launch0[3],
+                         readback_ns=qctx.readback_ns - launch0[4],
+                         readbacks=qctx.readbacks - launch0[5],
+                         consumer_host_ns=wall_ns - sum(mine.values()),
+                         **mine)}
             rec = _registry().record_query(qid, summary, wall_ns,
                                            status, **extra)
             self._last_execution = {"physical": physical, "ctx": ctx,
@@ -435,13 +457,21 @@ _PHASE_METRICS["aggMaskedFilterBatches"] = "agg_masked_filter_batches"
 #: a gauge's peak, not a sum: the largest over the plan's operators
 _PHASE_PEAKS = {"scanReaderThreadsPeak": "reader_threads_peak"}
 
+#: what the thread that pulls the plan spent of ``execute_ns`` standing
+#: in a prefetch queue, at the device gate, in launches and in device
+#: reads; ``consumer_host_ns`` is the rest of ``execute_ns``, its own
+#: work, so that these five add up to ``execute_ns`` exactly
+CONSUMER_PHASES = ("consumer_scan_wait_ns", "consumer_semaphore_wait_ns",
+                   "consumer_dispatch_ns", "consumer_readback_ns")
+
 #: the phases a query's session clocks itself, in the order a request
 #: meets them; ``serve_ns`` is the server's (serve/server.py), 0 for a
 #: query no server ran. With ``_PHASE_METRICS``' and ``_PHASE_PEAKS``'
 #: values, every key a record's ``phases`` holds
 TIMED_PHASES = ("parse_ns", "plan_ns", "admission_wait_ns", "execute_ns",
-                "fetch_ns", "dispatch_ns", "launches",
-                "semaphore_wait_ns", "serve_ns")
+                "fetch_ns", "dispatch_ns", "dispatch_cpu_ns", "launches",
+                "semaphore_wait_ns", "readback_ns", "readbacks",
+                *CONSUMER_PHASES, "consumer_host_ns", "serve_ns")
 
 
 def _query_phases(ctx_metrics: Dict, **timed) -> Dict[str, int]:

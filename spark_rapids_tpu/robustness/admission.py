@@ -81,8 +81,11 @@ class QueryContext:
     waits on ``_cancelled`` directly (``sleep``)."""
 
     __slots__ = ("query_id", "deadline", "cancel_reason", "_cancelled",
-                 "admission_wait_ns", "dispatch_ns", "launches",
-                 "semaphore_wait_ns", "_launch_lock")
+                 "admission_wait_ns", "dispatch_ns", "dispatch_cpu_ns",
+                 "launches", "semaphore_wait_ns", "readback_ns",
+                 "readbacks", "consumer", "consumer_scan_wait_ns",
+                 "consumer_semaphore_wait_ns", "consumer_dispatch_ns",
+                 "consumer_readback_ns", "_launch_lock")
 
     def __init__(self, query_id: str = "",
                  deadline: Optional[float] = None):
@@ -97,23 +100,60 @@ class QueryContext:
         #: reads this to bucket latency per admission tier.
         self.admission_wait_ns: Optional[int] = None
         #: host ns spent dispatching device programs on this query's
-        #: behalf, and how many dispatches, summed over its threads
+        #: behalf, the dispatching threads' CPU ns inside those
+        #: dispatches, and how many dispatches, summed over its threads
         #: (jit_registry ``launch.*`` ranges; ``phases`` of the record)
         self.dispatch_ns = 0
+        self.dispatch_cpu_ns = 0
         self.launches = 0
         #: ns its threads stood blocked for a device permit
         #: (exec/base.py TpuSemaphore, ``semaphore.wait`` ranges)
         self.semaphore_wait_ns = 0
+        #: ns its threads stood reading device values to the host, and
+        #: how many reads (jit_registry.read_back, ``device.read``)
+        self.readback_ns = 0
+        self.readbacks = 0
+        #: the thread that pulls the query's operator chain (stamped by
+        #: the session for the execution's span), and the four waits and
+        #: launches it spent of that span: leaves, none inside another
+        self.consumer: Optional[int] = None
+        self.consumer_scan_wait_ns = 0
+        self.consumer_semaphore_wait_ns = 0
+        self.consumer_dispatch_ns = 0
+        self.consumer_readback_ns = 0
         self._launch_lock = threading.Lock()
 
-    def count_launch(self, ns: int) -> None:
+    def count_launch(self, ns: int, cpu_ns: int = 0) -> None:
+        on_consumer = threading.get_ident() == self.consumer
         with self._launch_lock:
             self.dispatch_ns += ns
+            self.dispatch_cpu_ns += cpu_ns
             self.launches += 1
+            if on_consumer:
+                self.consumer_dispatch_ns += ns
 
     def count_semaphore_wait(self, ns: int) -> None:
+        on_consumer = threading.get_ident() == self.consumer
         with self._launch_lock:
             self.semaphore_wait_ns += ns
+            if on_consumer:
+                self.consumer_semaphore_wait_ns += ns
+
+    def count_readback(self, ns: int) -> None:
+        on_consumer = threading.get_ident() == self.consumer
+        with self._launch_lock:
+            self.readback_ns += ns
+            self.readbacks += 1
+            if on_consumer:
+                self.consumer_readback_ns += ns
+
+    def count_scan_wait(self, ns: int) -> None:
+        """A wait for the next batch of a prefetch queue: counted here
+        only where the consumer stood in it (every thread's wait is the
+        operators' ``prefetchWaitTime``)."""
+        if threading.get_ident() == self.consumer:
+            with self._launch_lock:
+                self.consumer_scan_wait_ns += ns
 
     @property
     def admission_tier(self) -> str:

@@ -136,3 +136,20 @@ def _collect_reasons(meta) -> list:
     for c in meta.child_plans:
         out.extend(_collect_reasons(c))
     return out
+
+
+def assert_consumer_partition(phases: dict) -> None:
+    """A query record's ``phases``: the pulling thread's four waits and
+    launches and its own host time add up to ``execute_ns`` exactly, each
+    at least 0 and each wait within the same wait over all the query's
+    threads (plan/session.py ``CONSUMER_PHASES``)."""
+    from ..plan.session import CONSUMER_PHASES
+    parts = [phases[k] for k in CONSUMER_PHASES] + [phases["consumer_host_ns"]]
+    assert all(p >= 0 for p in parts), phases
+    assert sum(parts) == phases["execute_ns"], phases
+    for mine, every in (("consumer_scan_wait_ns", "prefetch_wait_ns"),
+                        ("consumer_semaphore_wait_ns", "semaphore_wait_ns"),
+                        ("consumer_dispatch_ns", "dispatch_ns"),
+                        ("consumer_readback_ns", "readback_ns")):
+        assert phases[mine] <= phases[every], (mine, phases)
+    assert 0 <= phases["dispatch_cpu_ns"] <= phases["dispatch_ns"], phases

@@ -32,6 +32,7 @@ from spark_rapids_tpu.obs.registry import (MetricsRegistry, level_allows,
                                            query_totals, summarize_metrics)
 from spark_rapids_tpu.obs.trace import Tracer, maybe_tracer
 from spark_rapids_tpu.plan.session import TpuSession
+from spark_rapids_tpu.testing.asserts import assert_consumer_partition
 
 sys.path.insert(0, os.path.join(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__))), "tools"))
@@ -276,6 +277,7 @@ def test_session_records_query_in_registry():
     assert snap["counters"]["queries_total"] == before + 1
     last = snap["queries"][-1]
     assert last["status"] == "ok" and last["wall_ns"] > 0
+    assert_consumer_partition(last["phases"])
     assert last["totals"]["opTimeNs"] > 0
     assert session._last_execution["record"] is last or \
         session._last_execution["record"] == last
@@ -450,6 +452,11 @@ def test_nds_q3_profile_smoke(tmp_path):
             spec.name, session.read.parquet(out))
     rows = session.sql(NDS_QUERIES["q3"]).collect()
     assert isinstance(rows, list)  # may legitimately be empty at 3k
+    # two joins: the split of the pulling thread's wall still adds up
+    phases = session._last_execution["record"]["phases"]
+    assert phases["join_readbacks"] >= 1
+    assert phases["readbacks"] >= phases["join_readbacks"]
+    assert_consumer_partition(phases)
     reports = profile_report.report(str(tmp_path / "events"))
     # datagen itself runs no queries; exactly the q3 execution shows
     assert len(reports) == 1
@@ -511,7 +518,12 @@ PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
               "scan_decode_ns", "scan_pooled_files", "scan_ahead_files",
               "scan_batches", "scan_inplace_batches",
               "scan_wait_ns", "scan_upload_ns", "prefetch_wait_ns", "prefetch_early_starts",
-              "dispatch_ns", "launches",
+              "dispatch_ns", "dispatch_cpu_ns", "launches",
+              # device reads (jit_registry.read_back) and the pulling
+              # thread's split of execute_ns (plan/session.py)
+              "readback_ns", "readbacks", "consumer_scan_wait_ns",
+              "consumer_semaphore_wait_ns", "consumer_dispatch_ns",
+              "consumer_readback_ns", "consumer_host_ns",
               # the waits at the two gates, the server's share (0 for a
               # query no server ran), the reader pool's gauge
               "admission_wait_ns", "semaphore_wait_ns", "serve_ns",
@@ -576,6 +588,9 @@ def test_query_record_carries_phases(traced_parquet_query):
     assert set(phases) == PHASE_KEYS
     assert all(isinstance(v, int) and v >= 0 for v in phases.values())
     assert phases["launches"] > 0 and phases["dispatch_ns"] > 0
+    assert 0 < phases["dispatch_cpu_ns"] <= phases["dispatch_ns"]
+    assert phases["readbacks"] >= 1 and phases["readback_ns"] > 0
+    assert_consumer_partition(phases)
     assert phases["parse_ns"] > 0 and phases["plan_ns"] > 0
     assert phases["fetch_ns"] > 0
     assert phases["execute_ns"] == record["wall_ns"]
@@ -603,10 +618,31 @@ def test_query_record_carries_phases(traced_parquet_query):
     assert phases["scan_inplace_batches"] == 0
 
 
+def test_read_back_counts_a_device_read_once_on_the_current_query():
+    import jax.numpy as jnp
+    import numpy as np
+    from spark_rapids_tpu.jit_registry import read_back, read_int
+    from spark_rapids_tpu.robustness.admission import (QueryContext,
+                                                       query_scope)
+    token = QueryContext("reader")
+    with query_scope(token):
+        host, three = read_back(jnp.arange(4, dtype=jnp.int32), 3)
+        assert isinstance(host, np.ndarray) and host.tolist() == [0, 1, 2, 3]
+        assert three == 3
+        # values on the host are no round trip
+        assert read_int(5) == 5 and read_back(np.int32(2), 1.5) == (2, 1.5)
+    assert token.readbacks == 1 and token.readback_ns > 0
+    # no thread of this token pulls a plan: nothing is the consumer's
+    assert token.consumer_readback_ns == 0
+    # outside any query a read is charged to none
+    assert read_int(jnp.int32(9)) == 9 and token.readbacks == 1
+
+
 def test_host_ranges_are_in_the_profilers_trace(traced_parquet_query):
     _, _, ranges = traced_parquet_query
     for name in ("plan.parse", "plan.physical", "result.fetch",
-                 "scan.wait", "scan.upload", "prefetch.wait"):
+                 "scan.wait", "scan.upload", "prefetch.wait",
+                 "device.read"):
         assert name in ranges, f"no {name} range in the trace"
     launches = [n for n in ranges if n.startswith("launch.")]
     assert launches and all(" " not in n for n in launches)
@@ -614,6 +650,11 @@ def test_host_ranges_are_in_the_profilers_trace(traced_parquet_query):
     # consumer's wait and the planner's on the caller's
     assert ranges["scan.wait"] == ranges["scan.upload"]
     assert ranges["plan.physical"] == ranges["prefetch.wait"]
+    # the pulling thread reads its row counts and the answer; the only
+    # other reader is the shuffle's producer, which stages a device
+    # batch (SpillableBatch reads its row count), never the scan's
+    assert ranges["plan.physical"] <= ranges["device.read"]
+    assert ranges["device.read"].isdisjoint(ranges["scan.wait"])
     assert ranges["scan.wait"].isdisjoint(ranges["plan.physical"])
     # decode is a counter only: a range on a reader-pool thread would
     # be taken for the cause of idle gaps it merely overlaps

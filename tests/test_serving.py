@@ -33,6 +33,7 @@ from spark_rapids_tpu.robustness.faults import (arm_fault_plan,
                                                 disarm_fault_plan)
 from spark_rapids_tpu.serve import (ResultCache, ServeError,
                                     ServeLoadShed, SqlClient, SqlServer)
+from spark_rapids_tpu.testing.asserts import assert_consumer_partition
 
 Q_SUM = ("SELECT b, sum(a) AS s FROM t WHERE a > 100 "
          "GROUP BY b ORDER BY b")
@@ -471,6 +472,31 @@ def test_four_concurrent_streams_get_the_reference_answers_and_phases():
         # four streams under four permits: nobody queues
         assert phases["admission_wait_ns"] == 0
         assert phases["serve_ns"] > 0 and phases["semaphore_wait_ns"] >= 0
+        # four pulling threads at once, each split of its own wall whole
+        assert_consumer_partition(phases)
+
+
+def test_a_served_querys_pulling_thread_is_its_request_thread(
+        monkeypatch):
+    """The thread that executes a served query is the server's request
+    thread: its launches and reads are the record's ``consumer_*``."""
+    s = _session()
+    pullers = []
+    execute = TpuSession._execute_physical
+
+    def recorded(self, *args, **kwargs):
+        pullers.append(threading.current_thread().name)
+        return execute(self, *args, **kwargs)
+    monkeypatch.setattr(TpuSession, "_execute_physical", recorded)
+    with SqlServer(s) as server, SqlClient(server.endpoint) as c:
+        info = c.submit(Q_CNT).info
+    assert info["status"] == "ok"
+    assert len(pullers) == 1 and pullers[0].startswith("srt-serve-")
+    [rec] = _records([(Q_CNT, None, info)])
+    phases = rec["phases"]
+    assert phases["consumer_dispatch_ns"] > 0
+    assert phases["consumer_readback_ns"] > 0 and phases["serve_ns"] > 0
+    assert_consumer_partition(phases)
 
 
 def test_one_admission_permit_makes_the_queued_streams_wait():
@@ -495,20 +521,20 @@ def test_one_admission_permit_makes_the_queued_streams_wait():
 
 
 def test_admission_wait_is_not_counted_as_semaphore_wait():
-    from spark_rapids_tpu.memory.budget import task_context
     from spark_rapids_tpu.robustness.admission import (QueryContext,
-                                                       QuerySemaphore)
+                                                       QuerySemaphore,
+                                                       query_scope)
     sem = QuerySemaphore(1, max_queue_depth=4, backoff_base_s=0.01)
     sem.acquire(QueryContext("holder"))
     waited = {}
 
     def queued():
-        before = task_context().semaphore_wait_ns
         token = QueryContext("queued")
-        sem.acquire(token)
-        sem.release()
+        with query_scope(token):
+            sem.acquire(token)
+            sem.release()
         waited["admission"] = token.admission_wait_ns
-        waited["semaphore"] = task_context().semaphore_wait_ns - before
+        waited["semaphore"] = token.semaphore_wait_ns
 
     t = threading.Thread(target=queued)
     t.start()
