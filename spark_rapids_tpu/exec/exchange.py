@@ -18,6 +18,18 @@ Under a device mesh the same partitioning feeds the all-to-all
 collective instead (parallel/shuffle.py shuffle_exchange) — that path
 compiles into the SPMD program and never touches this manager
 (plan/mesh_executor.py lowers these nodes to collectives).
+
+A single-process exchange (no cluster) whose blocks stay device batches
+cuts nothing its reader would only put back together. A hash or
+round-robin map batch is held WHOLE with the rows and bytes each reduce
+partition would receive (one count program, shapes for the bytes), so
+AQE's statistics are the split's; a reader that takes every partition
+as one group streams the held batches, and any other read first cuts
+each with the same program into the same slices. A range exchange
+whose rows fit one batch takes the range split with every row in
+partition 0 (no sample, no bounds), and a one-partition exchange
+writes its batches as they are. ``shuffleWholeBatches`` /
+``shuffleSplitBatches`` count which way each map batch went.
 """
 
 from __future__ import annotations
@@ -34,15 +46,18 @@ import numpy as np
 from ..columnar import dtypes as dt
 from ..columnar.vector import (ColumnVector, ColumnarBatch, StringColumn,
                                choose_capacity)
-from ..conf import SHUFFLE_PARTITIONS
+from ..conf import BATCH_SIZE_ROWS, SHUFFLE_PARTITIONS
 from ..expr.core import Expression
-from ..jit_registry import read_int, shared_fn_jit
+from ..jit_registry import read_back, read_int, shared_fn_jit
+from ..memory.spill import batch_nbytes
 from ..ops import kernels as K
 from ..parallel.partition import (PartitionedBatch, hash_partition_ids,
-                                  partition_batch, range_partition_ids,
+                                  partition_batch, partition_counts,
+                                  range_partition_ids,
                                   round_robin_partition_ids,
                                   string_from_padded)
-from ..parallel.shuffle_manager import ShuffleManager, shuffle_manager
+from ..parallel.shuffle_manager import (WHOLE, ShuffleManager,
+                                        shuffle_manager)
 from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
 
 _SHUFFLE_IDS = itertools.count(1)
@@ -126,6 +141,62 @@ def _rr_partition_builder(num_parts):
     return run
 
 
+def _hash_count_builder(key_exprs, num_parts):
+    """Live rows per hash partition of one batch, int32[num_parts]: the
+    counts of ``_hash_partition_builder``'s slices, without the slices."""
+    def run(batch: ColumnarBatch):
+        keys = [e.eval(batch) for e in key_exprs]
+        return partition_counts(batch, hash_partition_ids(keys, num_parts),
+                                num_parts)
+    return run
+
+
+def _rr_counts(num_rows: int, num_parts: int) -> List[int]:
+    """Rows per round-robin partition of a batch whose live rows are
+    its first ``num_rows`` slots (``round_robin_partition_ids``)."""
+    return [len(range(r, num_rows, num_parts)) for r in range(num_parts)]
+
+
+#: exchange metric -> key of the query record's ``phases``: map batches
+#: that reached their reader whole, and map batches cut into partitions
+EXCHANGE_COUNTERS = {"shuffleWholeBatches": "exchange_whole_batches",
+                     "shuffleSplitBatches": "exchange_split_batches"}
+
+#: ((batch structure, partitions, leaf shapes), slice capacity) -> bytes
+_SLICE_BYTES: dict = {}
+
+
+def _slice_bytes(fn, batch: ColumnarBatch, rows: Sequence[int]) -> List[int]:
+    """What ``write_map_output`` records for each slice the partition
+    program ``fn`` cuts from ``batch`` (its device bytes, ``batch_nbytes``)
+    given the rows each receives, from shapes alone: nothing is cut or
+    launched. Every partition program cuts slices of the batch's
+    capacity, and ``compact_for_transfer`` repacks a slice to its rows'
+    capacity where that saves enough."""
+    leaves, tree = jax.tree_util.tree_flatten(batch)
+    sig = (tree, len(rows), tuple((getattr(x, "shape", None),
+                                   getattr(x, "dtype", None))
+                                  for x in leaves))
+    if len(_SLICE_BYTES) > 4096:
+        _SLICE_BYTES.clear()
+    out = []
+    for n in rows:
+        if n == 0:
+            out.append(0)
+            continue
+        cap = K.transfer_capacity(n, batch.capacity)
+        nb = _SLICE_BYTES.get((sig, cap))
+        if nb is None:
+            piece = fn.eval_shape(batch)[0]
+            if cap != batch.capacity:
+                piece = jax.eval_shape(
+                    lambda p, c=cap: K.slice_batch(p, 0, p.num_rows, c),
+                    piece)
+            nb = _SLICE_BYTES[(sig, cap)] = batch_nbytes(piece)
+        out.append(nb)
+    return out
+
+
 class ShuffleExchangeExec(TpuExec):
     """Repartitioning through the ShuffleManager.
 
@@ -158,6 +229,9 @@ class ShuffleExchangeExec(TpuExec):
         self._winners = None
         self._barrier_done = False
         self._own_map_ids: List[int] = []
+        #: map ids whose batch the manager holds whole (not yet cut)
+        self._held_ids: List[int] = []
+        self._split_lock = threading.Lock()
 
     def reset_for_rerun(self) -> None:
         super().reset_for_rerun()
@@ -170,6 +244,7 @@ class ShuffleExchangeExec(TpuExec):
         self._winners = None
         self._barrier_done = False
         self._own_map_ids = []
+        self._held_ids = []
 
     @property
     def output_schema(self) -> Schema:
@@ -218,6 +293,34 @@ class ShuffleExchangeExec(TpuExec):
                 self._jit_cache[key] = shared_fn_jit(
                     _rr_partition_builder, num_parts)
         return self._jit_cache[key]
+
+    def _holds_whole(self, ctx: ExecContext, mgr, n_parts: int) -> bool:
+        """Whether the map side keeps each batch whole and cuts it only
+        for a reader that asks for partitions apart: a single-process hash
+        or round-robin exchange of several partitions whose manager keeps
+        device batches, over keys a program can evaluate."""
+        if ctx.cluster is not None or n_parts < 2 or self.sort_orders \
+                or not mgr.holds_on_device(local_ok=True):
+            return False
+        from ..expr.misc import contains_eager
+        return not contains_eager(self.key_exprs)
+
+    def _partition_rows(self, batch: ColumnarBatch,
+                        n_parts: int) -> List[int]:
+        """Rows each reduce partition receives from ``batch``: one small
+        program and one read for hash keys, arithmetic for round-robin."""
+        if not self.key_exprs:
+            return _rr_counts(read_int(batch.num_rows), n_parts)
+        key = ("count", n_parts)
+        if key not in self._jit_cache:
+            self._jit_cache[key] = shared_fn_jit(
+                _hash_count_builder, self.key_exprs, n_parts)
+        counts, = read_back(self._jit_cache[key](batch))
+        return [int(c) for c in counts]
+
+    def _counter(self, ctx: ExecContext, name: str) -> Metric:
+        return ctx.metrics_for(self.exec_id).setdefault(
+            name, Metric(name, Metric.MODERATE))
 
     # --- range bounds (GpuRangePartitioner.sketch: sample to the
     # driver, sort, take quantile bounds) ---
@@ -367,36 +470,51 @@ class ShuffleExchangeExec(TpuExec):
             held = []
             try:
                 from ..memory.retry import with_retry_no_split
+                total = 0
                 for batch in self.children[0].execute(ctx):
-                    if read_int(batch.num_rows) == 0:
+                    n = read_int(batch.num_rows)
+                    if n == 0:
                         continue
+                    total += n
                     held.append(with_retry_no_split(
                         lambda b=batch: SpillableBatch(
                             K.compact_for_transfer(b),
                             SpillPriority.ACTIVE_ON_DECK)))
                 batches = with_retry_no_split(
                     lambda: [sb.get() for sb in held])
-                bounds, n_bounds = self._compute_bounds(ctx, batches,
-                                                        n_parts)
-                fn = self._partition_fn(n_parts, bounds=True)
+                # rows that fit one batch take the range split that puts
+                # every row in partition 0 (any bounds above every key):
+                # the sort above reads them as one, and nothing samples
+                whole = ctx.cluster is None and (
+                    n_parts == 1 or total <= ctx.conf.get(BATCH_SIZE_ROWS))
+                if not whole:
+                    bounds, n_bounds = self._compute_bounds(ctx, batches,
+                                                            n_parts)
+                    fn = self._partition_fn(n_parts, bounds=True)
+                counter = self._counter(
+                    ctx, "shuffleWholeBatches" if whole
+                    else "shuffleSplitBatches")
                 for batch in batches:
                     t0 = time.perf_counter_ns()
 
-                    def write_one(batch=batch, map_id=map_id,
-                                  bounds=bounds):
+                    def write_one(batch=batch, map_id=map_id):
                         # replay-safe: block writes overwrite by
                         # (shuffle, map, reduce)
-                        with ctx.semaphore:
-                            # per-slice compaction: each slice carries
-                            # the full input capacity (static
-                            # worst-case skew bound) but typically
-                            # holds ~1/P of the rows
-                            parts = [K.compact_for_transfer(p)
-                                     for p in fn(batch, bounds)]
+                        if whole:
+                            parts = [batch]
+                        else:
+                            with ctx.semaphore:
+                                # per-slice compaction: each slice
+                                # carries the full input capacity
+                                # (static worst-case skew bound) but
+                                # typically holds ~1/P of the rows
+                                parts = [K.compact_for_transfer(p)
+                                         for p in fn(batch, bounds)]
                         return mgr.write_map_output(
                             self.shuffle_id, map_id, parts,
                             local_ok=ctx.cluster is None)
                     write_bytes.add(with_retry_no_split(write_one))
+                    counter.add(1)
                     part_time.add(time.perf_counter_ns() - t0)
                     write_rows.add(read_int(batch.num_rows))
                     if push_route is not None:
@@ -545,6 +663,11 @@ class ShuffleExchangeExec(TpuExec):
                                    Metric("shuffleBytesWritten",
                                           Metric.ESSENTIAL, "B"))
         from ..memory.retry import with_retry_no_split
+        hold = self._holds_whole(ctx, mgr, n_parts)
+        # one local partition: the compacted batch is that partition
+        one = ctx.cluster is None and n_parts == 1
+        whole_m = self._counter(ctx, "shuffleWholeBatches")
+        split_m = self._counter(ctx, "shuffleSplitBatches")
         written: List[int] = []
         for batch in child.execute(ctx):
             if read_int(batch.num_rows) == 0:
@@ -557,17 +680,30 @@ class ShuffleExchangeExec(TpuExec):
                 # overwrites, never duplicates
                 with ctx.semaphore:
                     b = K.compact_for_transfer(batch)
-                    fn = self._partition_fn(n_parts)
-                    parts = [K.compact_for_transfer(p)
-                             for p in fn(b)]
-                wrote = mgr.write_map_output(
-                    self.shuffle_id, map_id, parts,
-                    local_ok=ctx.cluster is None)
+                    if hold:
+                        rows = self._partition_rows(b, n_parts)
+                    elif not one:
+                        fn = self._partition_fn(n_parts)
+                        parts = [K.compact_for_transfer(p)
+                                 for p in fn(b)]
+                if hold:
+                    wrote = mgr.hold_map_output(
+                        self.shuffle_id, map_id, b, rows,
+                        _slice_bytes(self._partition_fn(n_parts), b, rows),
+                        local_ok=True)
+                else:
+                    wrote = mgr.write_map_output(
+                        self.shuffle_id, map_id, [b] if one else parts,
+                        local_ok=ctx.cluster is None)
                 return read_int(b.num_rows), wrote
             rows_written, bytes_written = with_retry_no_split(write_one)
             part_time.add(time.perf_counter_ns() - t0)
             write_rows.add(rows_written)
             write_bytes.add(bytes_written)
+            if hold:
+                self._held_ids.append(map_id)
+            else:
+                (whole_m if one else split_m).add(1)
             if push_route is not None:
                 # eager push at map completion: this map's blocks start
                 # uploading to their reducers while the next batch is
@@ -631,7 +767,36 @@ class ShuffleExchangeExec(TpuExec):
                        reduce_id: int) -> Iterator[ColumnarBatch]:
         mgr = self.manager or shuffle_manager()
         self._write(ctx)
+        self._split_held(ctx, mgr)
         yield from mgr.read_partition(self.shuffle_id, reduce_id)
+
+    def _split_held(self, ctx: ExecContext, mgr) -> None:
+        """Cut every held map batch into its partitions with the program
+        and the compaction the map side uses when it does not hold: the
+        same slices, before any read of partitions apart. Their rows
+        and bytes were recorded when the batch was held."""
+        with self._split_lock:
+            held, self._held_ids = self._held_ids, []
+            if not held:
+                return
+            from ..memory.retry import with_retry_no_split
+            n_parts = mgr.num_partitions(self.shuffle_id)
+            fn = self._partition_fn(n_parts)
+            detail = mgr.map_output_statistics(
+                self.shuffle_id, map_ids=set(held)).detail
+            split_m = self._counter(ctx, "shuffleSplitBatches")
+            for map_id in held:
+                rows = [detail.get((map_id, r), (0, 0))[0]
+                        for r in range(n_parts)]
+
+                def cut(batch, rows=rows):
+                    with ctx.semaphore:
+                        return [K.compact_for_transfer(p, num_rows=n)
+                                if n else None
+                                for p, n in zip(fn(batch), rows)]
+                with_retry_no_split(lambda m=map_id, c=cut: mgr.split_held(
+                    self.shuffle_id, m, c))
+                split_m.add(1)
 
     # --- AQE surface (GpuCustomShuffleReaderExec analogue) ---
     def _cluster_barrier(self, ctx: ExecContext):
@@ -830,6 +995,25 @@ class ShuffleExchangeExec(TpuExec):
                     f"shuffle-g{gi}")
             return
 
+        n_parts = mgr.num_partitions(self.shuffle_id)
+        if self._held_ids and not map_mod and len(groups) == 1 \
+                and sorted(groups[0]) == list(range(n_parts)):
+            # one group of every partition: the held map batches, in map
+            # order, are its rows in the order the slices' concatenation
+            # gives them within each key
+            self._counter(ctx, "shuffleWholeBatches").add(
+                len(self._held_ids))
+
+            def read_whole():
+                ctx.partition_id = groups[0][0]
+                yield from mgr.read_partition(self.shuffle_id, WHOLE)
+            try:
+                yield self._maybe_prefetch(ctx, read_whole, "shuffle-g0")
+            finally:
+                self._release(mgr)
+            return
+        self._split_held(ctx, mgr)
+
         def read_group(gi, g):
             mm = (map_mod or {}).get(gi)
             for reduce_id in g:
@@ -882,6 +1066,8 @@ class ShuffleExchangeExec(TpuExec):
             # the whole job completes — the driver's post-job reset (or
             # failure-path reset) frees them (cluster.py _run_once)
             return
+
+        self._split_held(ctx, mgr)
 
         def local_read(reduce_id):
             ctx.partition_id = reduce_id

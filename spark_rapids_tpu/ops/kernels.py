@@ -1222,21 +1222,27 @@ def concat_batches(batches: Sequence[ColumnarBatch],
 _COMPACT_JIT: dict = {}
 
 
-def compact_for_transfer(batch: ColumnarBatch,
-                         slack: int = 4) -> ColumnarBatch:
+def compact_for_transfer(batch: ColumnarBatch, slack: int = 4,
+                         num_rows: Optional[int] = None) -> ColumnarBatch:
     """Shrink a sparse batch to a small power-of-two capacity before it
     crosses a serialization/transfer boundary (shuffle write, broadcast,
     collect). Operators keep their input's static capacity, so a
     partial aggregate of a 512k-row batch emits a 512k-capacity batch
     with a handful of live groups — serializing THAT pulls the whole
     padded capacity off the device. Only compacts when it saves at
-    least ``slack``×; costs one host sync of the (scalar) row count."""
+    least ``slack``×; costs one host sync of the (scalar) row count,
+    unless the caller knows it (``num_rows``)."""
+    n = read_int(batch.num_rows) if num_rows is None else num_rows
+    cap = transfer_capacity(n, batch.capacity, slack)
+    return batch if cap == batch.capacity else repack_to(batch, cap)
+
+
+def transfer_capacity(num_rows: int, capacity: int, slack: int = 4) -> int:
+    """The capacity ``compact_for_transfer`` leaves a batch of
+    ``capacity`` slots that holds ``num_rows`` rows."""
     from ..columnar.vector import choose_capacity
-    n = read_int(batch.num_rows)
-    cap = choose_capacity(n)
-    if cap * slack > batch.capacity:
-        return batch
-    return repack_to(batch, cap)
+    cap = choose_capacity(num_rows)
+    return capacity if cap * slack > capacity else cap
 
 
 def repack_to(batch: ColumnarBatch, cap: int) -> ColumnarBatch:

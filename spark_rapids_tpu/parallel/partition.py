@@ -136,6 +136,22 @@ def _pb_unflatten(aux, children):
 jax.tree_util.register_pytree_node(PartitionedBatch, _pb_flatten, _pb_unflatten)
 
 
+def _route(batch: ColumnarBatch, part_ids: jnp.ndarray, num_parts: int):
+    """Destination of every slot (dead rows past the live buckets) and
+    the live rows each destination receives."""
+    pid = jnp.where(batch.live_mask(), part_ids, jnp.int32(num_parts))
+    counts_all = jnp.zeros(num_parts + 1, jnp.int32).at[
+        jnp.clip(pid, 0, num_parts)].add(1)
+    return pid, counts_all[:num_parts]
+
+
+def partition_counts(batch: ColumnarBatch, part_ids: jnp.ndarray,
+                     num_parts: int) -> jnp.ndarray:
+    """int32[num_parts]: ``partition_batch(...).counts`` without
+    packing a single row."""
+    return _route(batch, part_ids, num_parts)[1]
+
+
 def partition_batch(batch: ColumnarBatch, part_ids: jnp.ndarray,
                     num_parts: int,
                     slot_capacity: Optional[int] = None) -> PartitionedBatch:
@@ -147,10 +163,7 @@ def partition_batch(batch: ColumnarBatch, part_ids: jnp.ndarray,
     cap = batch.capacity
     S = slot_capacity or cap
     live = batch.live_mask()
-    pid = jnp.where(live, part_ids, jnp.int32(num_parts))
-    counts_all = jnp.zeros(num_parts + 1, jnp.int32).at[
-        jnp.clip(pid, 0, num_parts)].add(1)
-    counts = counts_all[:num_parts]
+    pid, counts = _route(batch, part_ids, num_parts)
     offsets = jnp.concatenate(
         [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
     if num_parts <= 32:

@@ -41,6 +41,10 @@ from .serializer import deserialize_batch, serialize_batch
 
 BlockId = Tuple[int, int, int]  # (shuffle_id, map_id, reduce_id)
 
+#: reduce id of a map batch held whole (``ShuffleManager.hold_map_output``):
+#: it sorts before every real partition, and no reduce partition reads it
+WHOLE = -1
+
 
 class ShuffleBlockCatalog:
     """Device-resident shuffle blocks as spillables
@@ -74,6 +78,12 @@ class ShuffleBlockCatalog:
         with self._lock:
             return sorted(b for b in self._blocks
                           if b[0] == shuffle_id and b[2] == reduce_id)
+
+    def remove(self, block: BlockId) -> None:
+        with self._lock:
+            gone = self._blocks.pop(block, [])
+        for sb in gone:
+            sb.close()
 
     def remove_shuffle(self, shuffle_id: int) -> int:
         with self._lock:
@@ -834,13 +844,8 @@ class ShuffleManager:
         bypass on, MULTITHREADED writes then hand the live batch through
         the device catalog (zero-copy local channel) instead of
         serializer+socket+deserializer, counted as bypassed bytes."""
-        fault_point("shuffle.write", f"sid={shuffle_id};map={map_id};")
-        from ..robustness.admission import check_current_query
-        check_current_query()  # cancelled query: skip the whole write
-        t0 = time.perf_counter_ns()
-        bytes_before = self.write_metrics.bytes_written
-        bypass = (local_ok and self.mode == "MULTITHREADED"
-                  and self.push_enabled and self.local_bypass)
+        t0, bytes_before = self._begin_write(shuffle_id, map_id)
+        bypass = self._bypass(local_ok)
         bypassed_nb = 0
         futures = []
         local_rows: Dict[int, int] = {}
@@ -865,6 +870,69 @@ class ShuffleManager:
                     self._serialize_one, block, batch)))
         for reduce_id, f in futures:
             local_bytes[reduce_id] = f.result()
+        return self._end_write(shuffle_id, map_id, local_rows, local_bytes,
+                               bypassed_nb, t0, bytes_before)
+
+    def holds_on_device(self, local_ok: bool) -> bool:
+        """Whether this manager keeps a single-process map output as live
+        device batches in its catalog (CACHE_ONLY, or the push locality
+        bypass) rather than serialized blocks: what
+        ``hold_map_output`` needs."""
+        return self.mode == "CACHE_ONLY" or self._bypass(local_ok)
+
+    def hold_map_output(self, shuffle_id: int, map_id: int,
+                        batch: ColumnarBatch, reduce_rows: Sequence[int],
+                        reduce_bytes: Sequence[int],
+                        local_ok: bool = False) -> int:
+        """One map task's output held WHOLE in the device catalog,
+        under ``(shuffle_id, map_id, WHOLE)``, with the rows and bytes
+        each reduce partition would receive from it: the statistics,
+        the ``ShuffleWrite`` event, the fault point, the cancel check
+        and the spill priority are ``write_map_output``'s. A reader
+        that takes every partition at once streams the held batches
+        (``read_partition(shuffle_id, WHOLE)``); any other read first
+        replaces each by its slices (``split_held``). Needs
+        ``holds_on_device``."""
+        t0, bytes_before = self._begin_write(shuffle_id, map_id)
+        self.catalog.add((shuffle_id, map_id, WHOLE), batch)
+        local_rows = {r: n for r, n in enumerate(reduce_rows) if n > 0}
+        local_bytes = {r: reduce_bytes[r] for r in local_rows}
+        bypassed_nb = (sum(local_bytes.values())
+                       if self._bypass(local_ok) else 0)
+        return self._end_write(shuffle_id, map_id, local_rows, local_bytes,
+                               bypassed_nb, t0, bytes_before)
+
+    def split_held(self, shuffle_id: int, map_id: int, cut) -> None:
+        """Replace a held map batch by its slices: ``cut(batch)[i]``
+        (None where empty) becomes the block of reduce partition i. The
+        statistics were recorded when the batch was held."""
+        held, = self.catalog.get((shuffle_id, map_id, WHOLE))
+        for reduce_id, batch in enumerate(cut(held)):
+            if batch is None:
+                continue
+            self.catalog.add((shuffle_id, map_id, reduce_id), batch)
+            with self._lock:
+                self.write_metrics.rows_written += self._part_rows.get(
+                    (shuffle_id, map_id, reduce_id), 0)
+            self.write_metrics.blocks_written += 1
+        self.catalog.remove((shuffle_id, map_id, WHOLE))
+
+    def _bypass(self, local_ok: bool) -> bool:
+        return (local_ok and self.mode == "MULTITHREADED"
+                and self.push_enabled and self.local_bypass)
+
+    def _begin_write(self, shuffle_id: int, map_id: int) -> Tuple[int, int]:
+        fault_point("shuffle.write", f"sid={shuffle_id};map={map_id};")
+        from ..robustness.admission import check_current_query
+        check_current_query()  # cancelled query: skip the whole write
+        return time.perf_counter_ns(), self.write_metrics.bytes_written
+
+    def _end_write(self, shuffle_id: int, map_id: int,
+                   local_rows: Dict[int, int], local_bytes: Dict[int, int],
+                   bypassed_nb: int, t0: int, bytes_before: int) -> int:
+        """Record one map output's rows and bytes per reduce partition
+        (the AQE statistics) and emit its ``ShuffleWrite`` event;
+        returns the serialized bytes it wrote."""
         with self._lock:
             self.bypassed_bytes += bypassed_nb
             for reduce_id, rows in local_rows.items():

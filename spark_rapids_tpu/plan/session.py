@@ -18,6 +18,7 @@ from ..columnar import dtypes as dt
 from ..conf import SrtConf, active_conf, set_active_conf
 from ..exec.base import ExecContext, TpuExec
 from ..exec.aggregate import LANE_COUNTERS as _LANE_COUNTERS
+from ..exec.exchange import EXCHANGE_COUNTERS as _EXCHANGE_COUNTERS
 from ..exec.join import JOIN_COUNTERS as _JOIN_COUNTERS
 from ..expr.aggregates import (Average, Count, CountStar, First, Last, Max,
                                Min, StddevSamp, Sum)
@@ -452,6 +453,8 @@ _PHASE_METRICS.update((name, key) for name, (_, _, key)
 # whose filter a fused chain handed to the aggregate as its mask
 _PHASE_METRICS.update(_LANE_COUNTERS)
 _PHASE_METRICS["aggMaskedFilterBatches"] = "agg_masked_filter_batches"
+# the shuffle exchange's map batches read whole and cut into partitions
+_PHASE_METRICS.update(_EXCHANGE_COUNTERS)
 
 
 #: a gauge's peak, not a sum: the largest over the plan's operators

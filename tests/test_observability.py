@@ -534,7 +534,9 @@ PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
               # the grouped aggregate's (exec/aggregate.py LANE_COUNTERS,
               # exec/fused.py aggMaskedFilterBatches)
               "pallas_batches", "groups_direct_batches",
-              "groups_hash_claim_batches", "agg_masked_filter_batches"}
+              "groups_hash_claim_batches", "agg_masked_filter_batches",
+              # the shuffle exchange's (exec/exchange.py EXCHANGE_COUNTERS)
+              "exchange_whole_batches", "exchange_split_batches"}
 
 
 @pytest.fixture(scope="module")
@@ -616,6 +618,10 @@ def test_query_record_carries_phases(traced_parquet_query):
     # places nothing
     assert totals["scanBatches"] == phases["scan_batches"] == 1
     assert phases["scan_inplace_batches"] == 0
+    # the global sum's one-partition exchange writes its batch as it is
+    assert totals["shuffleWholeBatches"] == \
+        phases["exchange_whole_batches"] == 1
+    assert phases["exchange_split_batches"] == 0
 
 
 def test_read_back_counts_a_device_read_once_on_the_current_query():
