@@ -25,7 +25,9 @@ round-robin map batch is held WHOLE with the rows and bytes each reduce
 partition would receive (one count program, shapes for the bytes), so
 AQE's statistics are the split's; a reader that takes every partition
 as one group streams the held batches, and any other read first cuts
-each with the same program into the same slices. A range exchange
+each into the same slices: one program that, knowing every partition's
+rows, gathers each slice straight to its own capacity (``cut_batch``)
+instead of every partition at the batch's. A range exchange
 whose rows fit one batch takes the range split with every row in
 partition 0 (no sample, no bounds), and a one-partition exchange
 writes its batches as they are. ``shuffleWholeBatches`` /
@@ -51,8 +53,9 @@ from ..expr.core import Expression
 from ..jit_registry import read_back, read_int, shared_fn_jit
 from ..memory.spill import batch_nbytes
 from ..ops import kernels as K
-from ..parallel.partition import (PartitionedBatch, hash_partition_ids,
-                                  partition_batch, partition_counts,
+from ..parallel.partition import (PartitionedBatch, cut_batch,
+                                  hash_partition_ids, partition_batch,
+                                  partition_counts,
                                   range_partition_ids,
                                   round_robin_partition_ids,
                                   string_from_padded)
@@ -138,6 +141,27 @@ def _rr_partition_builder(num_parts):
         pids = round_robin_partition_ids(batch.capacity, num_parts)
         return _partition_slices(partition_batch(batch, pids, num_parts),
                                  num_parts)
+    return run
+
+
+def _hash_cut_builder(key_exprs, num_parts, capacities):
+    """A held batch's cut (``_split_held``): the partitions
+    ``_hash_partition_builder`` assigns, each gathered straight to its
+    capacity in ``capacities`` (``cut_batch``): the same slices as that
+    program's repacked, without its ``num_parts`` x capacity layout."""
+    def run(batch: ColumnarBatch):
+        keys = [e.eval(batch) for e in key_exprs]
+        return cut_batch(batch, hash_partition_ids(keys, num_parts),
+                         num_parts, capacities)
+    return run
+
+
+def _rr_cut_builder(num_parts, capacities):
+    """``_hash_cut_builder`` for a round-robin exchange."""
+    def run(batch: ColumnarBatch):
+        return cut_batch(batch, round_robin_partition_ids(batch.capacity,
+                                                          num_parts),
+                         num_parts, capacities)
     return run
 
 
@@ -770,11 +794,26 @@ class ShuffleExchangeExec(TpuExec):
         self._split_held(ctx, mgr)
         yield from mgr.read_partition(self.shuffle_id, reduce_id)
 
+    def _cut_fn(self, num_parts: int, capacities: tuple):
+        """Jitted held batch -> its slices at ``capacities``
+        (``_hash_cut_builder``), one program per capacities."""
+        key = ("cut", num_parts, capacities)
+        if key not in self._jit_cache:
+            self._jit_cache[key] = shared_fn_jit(
+                _hash_cut_builder, self.key_exprs, num_parts, capacities) \
+                if self.key_exprs else shared_fn_jit(
+                    _rr_cut_builder, num_parts, capacities)
+        return self._jit_cache[key]
+
     def _split_held(self, ctx: ExecContext, mgr) -> None:
-        """Cut every held map batch into its partitions with the program
-        and the compaction the map side uses when it does not hold: the
-        same slices, before any read of partitions apart. Their rows
-        and bytes were recorded when the batch was held."""
+        """Cut every held map batch into the slices the map side writes
+        when it does not hold, before any read of partitions apart: the
+        rows each partition receives were recorded when the batch was
+        held, so one program gathers each slice straight to the capacity
+        ``compact_for_transfer`` would repack it to (a batch with a list
+        column takes the partition program and the repacks). Their rows
+        and bytes are the ones recorded."""
+        from ..columnar.nested import ListColumn
         with self._split_lock:
             held, self._held_ids = self._held_ids, []
             if not held:
@@ -790,10 +829,15 @@ class ShuffleExchangeExec(TpuExec):
                         for r in range(n_parts)]
 
                 def cut(batch, rows=rows):
+                    if any(isinstance(c, ListColumn) for c in batch.columns):
+                        with ctx.semaphore:
+                            return [K.compact_for_transfer(p, num_rows=n)
+                                    if n else None
+                                    for p, n in zip(fn(batch), rows)]
+                    caps = tuple(K.transfer_capacity(n, batch.capacity)
+                                 if n else 0 for n in rows)
                     with ctx.semaphore:
-                        return [K.compact_for_transfer(p, num_rows=n)
-                                if n else None
-                                for p, n in zip(fn(batch), rows)]
+                        return self._cut_fn(n_parts, caps)(batch)
                 with_retry_no_split(lambda m=map_id, c=cut: mgr.split_held(
                     self.shuffle_id, m, c))
                 split_m.add(1)

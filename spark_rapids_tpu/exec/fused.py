@@ -198,11 +198,14 @@ def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
     *aux) -> (packed, rows_in, flags, total)``. ``total`` is the
     join kernel's true required output size — the host only trusts the
     suffix output when ``total <= out_capacity`` (the capacity-growth
-    contract of exec/join.py, unchanged by fusion)."""
-    from .join import _join_run_builder
+    contract of exec/join.py, unchanged by fusion). A HASH pair's
+    program (``_fused_hash_join_builder``) returns the rows the join
+    kept after ``total``, before the suffix filters any."""
+    from .join import HASH, _join_run_builder
     base = _join_run_builder(join_type, list(probe_keys),
                              list(build_keys), out_capacity, mode,
                              table_size)
+    matches = mode == HASH
     specs = tuple(suffix_specs)
     has_agg = bool(specs) and specs[-1][0] == "agg"
     stage_fns = [] if has_agg else [_row_stage_fn(s) for s in specs]
@@ -224,17 +227,30 @@ def _fused_join_builder(join_type, probe_keys, build_keys, out_capacity,
     if not has_agg:
         def run(probe, build, *aux):
             out, total = join(probe, build, aux)
+            kept = out.num_rows
             for f in stage_fns:
                 out = f(out)
-            return out, total
+            return (out, total, kept) if matches else (out, total)
         return run
     suffix = _masked_agg_chain(specs)
 
     def run_agg(probe, build, row_offset, *aux):
         out, total = join(probe, build, aux)
         packed, rows_in, flags = suffix(out, row_offset)
+        if matches:
+            return packed, rows_in, flags, total, out.num_rows
         return packed, rows_in, flags, total
     return run_agg
+
+
+def _fused_hash_join_builder(join_type, probe_keys, build_keys,
+                             out_capacity, reorder_n, suffix_specs):
+    """A HASH pair with its suffix: ``_fused_join_builder``'s program
+    under a name no LOOKUP program has, so a device trace tells the two
+    paths apart; it also returns the join's kept rows."""
+    from .join import HASH
+    return _fused_join_builder(join_type, probe_keys, build_keys,
+                               out_capacity, reorder_n, suffix_specs, HASH)
 
 
 def _fused_merge_builder(prefix_specs, agg_spec, cap):
@@ -588,16 +604,20 @@ class FusedHashJoinExec(TpuExec):
                 + f"]{tag}")
 
     def _fused_fn(self, out_cap: int, donate: bool, side):
+        from .join import HASH
         key = (out_cap, donate, side.mode, side.table_size)
         fn = self._fn_cache.get(key)
         if fn is None:
             jit_kwargs = {"donate_argnums": (0,)} if donate else {}
-            fn = shared_fn_jit(
-                _fused_join_builder, self.join.join_type,
-                tuple(self.join._probe_key_exprs),
-                tuple(self.join._build_key_exprs),
-                out_cap, self._reorder_n, self._suffix_specs, side.mode,
-                side.table_size, **jit_kwargs)
+            args = (self.join.join_type, tuple(self.join._probe_key_exprs),
+                    tuple(self.join._build_key_exprs), out_cap,
+                    self._reorder_n, self._suffix_specs)
+            if side.mode == HASH:
+                fn = shared_fn_jit(_fused_hash_join_builder, *args,
+                                   **jit_kwargs)
+            else:
+                fn = shared_fn_jit(_fused_join_builder, *args, side.mode,
+                                   side.table_size, **jit_kwargs)
             self._fn_cache[key] = fn
         return fn
 
@@ -637,44 +657,48 @@ class FusedHashJoinExec(TpuExec):
         join = self.join
         max_steps = ctx.conf.get(JOIN_GROWTH_STEPS)
         side = join._build_side(ctx, build)
-        out_cap = join._pair_capacity(ctx, probe, side)
+        out_cap, n_probe = join._pair_capacity(ctx, probe, side)
         measured = False
         total = 0
-        for _ in range(max_steps + 1):
-            donate = self.donate and measured
-            fn = self._fused_fn(out_cap, donate, side)
-            with ctx.semaphore, NvtxTimer(st["fuse_time"], "fused-join"):
-                if self._agg is not None:
-                    out, rows_in, used, total = fn(
-                        probe, build, jnp.int64(st["offset"]), *side.aux)
-                else:
-                    out, total = fn(probe, build, *side.aux)
-                    rows_in = out.num_rows
-            total, n_in = join._read(ctx, total, rows_in)
-            if total <= out_cap:
-                join._note_total(side, total)
-                join._count_pair(ctx, side, out_cap)
-                st["saved"].add(self._saved_bytes_per_slot * out_cap)
-                if self._agg is None:
-                    return ColumnarBatch(out.columns, out.names, n_in)
-                st["offset"] += n_in
-                if n_in == 0:
-                    # mirror the unfused partial aggregate: no partial
-                    # emitted for a pair that filtered down to nothing
-                    return None
-                if self._use_pallas:
-                    st["used"].append(used)
-                return out
-            if donate:
-                # the measured capacity makes a relaunch overflow a
-                # kernel contract violation — and the probe is gone
-                raise RuntimeError(
-                    "fused join under-reported its output size on a "
-                    "donated relaunch")
-            retries.add(1)
-            join._counter(ctx, "joinCapacityRelaunches").add(1)
-            out_cap = choose_capacity(total)
-            measured = True
+        with join._probe_range(ctx, side):
+            for _ in range(max_steps + 1):
+                donate = self.donate and measured
+                fn = self._fused_fn(out_cap, donate, side)
+                with ctx.semaphore, NvtxTimer(st["fuse_time"],
+                                              "fused-join"):
+                    if self._agg is not None:
+                        out, rows_in, used, total, *kept = fn(
+                            probe, build, jnp.int64(st["offset"]),
+                            *side.aux)
+                    else:
+                        out, total, *kept = fn(probe, build, *side.aux)
+                        rows_in = out.num_rows
+                total, n_in, *kept = join._read(ctx, total, rows_in, *kept)
+                if total <= out_cap:
+                    join._note_total(side, total)
+                    join._count_pair(ctx, side, out_cap, n_probe, total,
+                                     *kept)
+                    st["saved"].add(self._saved_bytes_per_slot * out_cap)
+                    if self._agg is None:
+                        return ColumnarBatch(out.columns, out.names, n_in)
+                    st["offset"] += n_in
+                    if n_in == 0:
+                        # mirror the unfused partial aggregate: no partial
+                        # emitted for a pair that filtered down to nothing
+                        return None
+                    if self._use_pallas:
+                        st["used"].append(used)
+                    return out
+                if donate:
+                    # the measured capacity makes a relaunch overflow a
+                    # kernel contract violation — and the probe is gone
+                    raise RuntimeError(
+                        "fused join under-reported its output size on a "
+                        "donated relaunch")
+                retries.add(1)
+                join._counter(ctx, "joinCapacityRelaunches").add(1)
+                out_cap = choose_capacity(total)
+                measured = True
         raise RuntimeError(
             f"join expansion {total} exceeded capacity after "
             f"{max_steps} growth steps")

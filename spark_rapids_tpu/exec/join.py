@@ -19,6 +19,7 @@ preservation holds per bucket).
 
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Iterator, List, NamedTuple, Optional, Sequence, Tuple
 
@@ -67,6 +68,12 @@ JOIN_COUNTERS = {
     "joinCapacityRelaunches": (Metric.MODERATE, "",
                                "join_capacity_relaunches"),
     "joinReadbacks": (Metric.MODERATE, "", "join_readbacks"),
+    # the HASH path's pairs: the ``join.probe`` range, the live probe rows
+    # it took, the candidates its hash index gave and the rows it kept
+    "hashJoinProbeTime": (Metric.MODERATE, "ns", "hash_join_probe_ns"),
+    "hashJoinProbeRows": (Metric.MODERATE, "", "hash_join_probe_rows"),
+    "hashJoinCandidates": (Metric.MODERATE, "", "hash_join_candidates"),
+    "hashJoinMatches": (Metric.MODERATE, "", "hash_join_matches"),
 }
 
 _LOOKUP_KINDS = {INNER: "inner", LEFT_OUTER: "left", RIGHT_OUTER: "left",
@@ -88,7 +95,8 @@ class BuildSide(NamedTuple):
 def _join_run_builder(join_type, probe_keys, build_keys, out_capacity,
                       mode, table_size=0):
     """``run(probe, build, *aux) -> (out, total)``; ``aux`` is the
-    ``BuildSide.aux`` of ``mode``."""
+    ``BuildSide.aux`` of ``mode``. Registered as itself for a LOOKUP
+    pair; a HASH pair registers ``_hash_join_run_builder``."""
     if mode == LOOKUP:
         kind = _LOOKUP_KINDS[join_type]
 
@@ -115,6 +123,14 @@ def _join_run_builder(join_type, probe_keys, build_keys, out_capacity,
                                      index)
         return out, total
     return run
+
+
+def _hash_join_run_builder(join_type, probe_keys, build_keys, out_capacity):
+    """The HASH pair's program (``K.join_gather_maps``): the body of
+    ``_join_run_builder``, under a name no LOOKUP program has, so a
+    device trace tells the two paths apart."""
+    return _join_run_builder(join_type, probe_keys, build_keys,
+                             out_capacity, HASH)
 
 
 def _hash_index_builder(build_keys):
@@ -261,6 +277,11 @@ class _HashJoinBase(TpuExec):
     def _join_fn(self, out_capacity: int, side: BuildSide):
         """The per-pair program: jit per output capacity and the build
         side's mode (and table size)."""
+        if side.mode == HASH:
+            return self._shared(
+                (out_capacity, HASH), _hash_join_run_builder,
+                self.join_type, self._probe_key_exprs,
+                self._build_key_exprs, out_capacity)
         return self._shared(
             (out_capacity, side.mode, side.table_size), _join_run_builder,
             self.join_type, self._probe_key_exprs, self._build_key_exprs,
@@ -342,8 +363,9 @@ class _HashJoinBase(TpuExec):
 
     # --- output capacity: follows the matches, not the probe ---
     def _pair_capacity(self, ctx: ExecContext, probe: ColumnarBatch,
-                       side: BuildSide) -> int:
-        """Capacity of a pair's first launch. A lookup's: the bucket its
+                       side: BuildSide) -> Tuple[int, int]:
+        """Capacity of a pair's first launch, and the probe's live rows
+        where they were read (0 for a lookup). A lookup's: the bucket its
         earlier pairs needed and, before any has run, the pair's own
         match count. The hash join's, as ever: the probe's rows (every
         probe row matching about one build row)."""
@@ -357,9 +379,9 @@ class _HashJoinBase(TpuExec):
                     total = count(probe, *side.aux)
                 self._note_total(side, self._read(ctx, total)[0])
             # a unique build key: no more rows than the probe holds
-            return min(self._cap_hint, probe.capacity)
+            return min(self._cap_hint, probe.capacity), 0
         n_probe, = self._read(ctx, probe.num_rows)
-        return choose_capacity(max(n_probe, 16))
+        return choose_capacity(max(n_probe, 16)), n_probe
 
     def _note_total(self, side: BuildSide, total: int) -> None:
         if side.mode == LOOKUP:
@@ -367,10 +389,36 @@ class _HashJoinBase(TpuExec):
                                  choose_capacity(max(total, 16)))
 
     def _count_pair(self, ctx: ExecContext, side: BuildSide,
-                    out_cap: int) -> None:
-        self._counter(ctx, "lookupJoinBatches" if side.mode == LOOKUP
-                      else "hashJoinBatches").add(1)
+                    out_cap: int, n_probe: int = 0, total: int = 0,
+                    matches: int = 0) -> None:
+        """One pair answered at ``out_cap``; a HASH pair also counts its
+        probe rows, its candidates (``total``: the kernel's required size,
+        ``total_cand`` of an inner, semi or anti join) and the rows the
+        join kept (``matches``), once a pair whatever the relaunches."""
+        if side.mode == LOOKUP:
+            self._counter(ctx, "lookupJoinBatches").add(1)
+        else:
+            self._counter(ctx, "hashJoinBatches").add(1)
+            self._counter(ctx, "hashJoinProbeRows").add(n_probe)
+            self._counter(ctx, "hashJoinCandidates").add(total)
+            self._counter(ctx, "hashJoinMatches").add(matches)
         self._counter(ctx, "joinOutCapacity").set(out_cap)
+
+    @contextlib.contextmanager
+    def _probe_range(self, ctx: ExecContext, side: BuildSide):
+        """A HASH pair's launches, their capacity read-backs and any
+        relaunch, inside one ``join.probe`` range timed into
+        ``hashJoinProbeTime``. A lookup's pair has no range."""
+        if side.mode != HASH:
+            yield
+            return
+        t0 = time.perf_counter_ns()
+        try:
+            with annotate("join.probe"):
+                yield
+        finally:
+            self._counter(ctx, "hashJoinProbeTime").add(
+                time.perf_counter_ns() - t0)
 
     @property
     def _probe_key_exprs(self):
@@ -455,20 +503,22 @@ class _HashJoinBase(TpuExec):
         from ..conf import JOIN_GROWTH_STEPS
         max_steps = ctx.conf.get(JOIN_GROWTH_STEPS)
         side = self._build_side(ctx, build)
-        out_cap = self._pair_capacity(ctx, probe, side)
-        for step in range(max_steps + 1):
-            with ctx.semaphore:
-                out, total = self._join_fn(out_cap, side)(
-                    probe, build, *side.aux)
-            total, n_out = self._read(ctx, total, out.num_rows)
-            if total <= out_cap:
-                self._note_total(side, total)
-                self._count_pair(ctx, side, out_cap)
-                return self._reorder_columns(
-                    ColumnarBatch(out.columns, out.names, n_out))
-            retries.add(1)
-            self._counter(ctx, "joinCapacityRelaunches").add(1)
-            out_cap = choose_capacity(total)
+        out_cap, n_probe = self._pair_capacity(ctx, probe, side)
+        with self._probe_range(ctx, side):
+            for step in range(max_steps + 1):
+                with ctx.semaphore:
+                    out, total = self._join_fn(out_cap, side)(
+                        probe, build, *side.aux)
+                total, n_out = self._read(ctx, total, out.num_rows)
+                if total <= out_cap:
+                    self._note_total(side, total)
+                    self._count_pair(ctx, side, out_cap, n_probe, total,
+                                     n_out)
+                    return self._reorder_columns(
+                        ColumnarBatch(out.columns, out.names, n_out))
+                retries.add(1)
+                self._counter(ctx, "joinCapacityRelaunches").add(1)
+                out_cap = choose_capacity(total)
         raise RuntimeError(
             f"join expansion {total} exceeded capacity after "
             f"{max_steps} growth steps")

@@ -792,7 +792,16 @@ def _join_key_hash(cols: Sequence[Column], null_sentinel: int) -> jnp.ndarray:
     for c in cols:
         any_null = any_null | ~c.validity
     h_i64 = h.astype(jnp.int64)  # wrapping convert, not bitcast (TPU-legal)
+    # real hashes stay below every sentinel (the top four values)
+    h_i64 = jnp.minimum(h_i64, jnp.int64(_HASH_MAX))
     return jnp.where(any_null, jnp.int64(null_sentinel), h_i64)
+
+
+#: the largest hash ``_join_key_hash`` gives a row with no null key; the
+#: values above it are the sentinels of dead and null rows
+_HASH_MAX = int(jnp.iinfo(jnp.int64).max) - 4
+#: the hash index's directory has at most 2^this buckets
+_DIRECTORY_MAX_BITS = 20
 
 
 def _keys_equal(a_cols: Sequence[Column], a_idx, b_cols: Sequence[Column],
@@ -834,17 +843,82 @@ def _keys_equal(a_cols: Sequence[Column], a_idx, b_cols: Sequence[Column],
     return ok
 
 
+def _directory_bits(capacity: int) -> int:
+    """Bits of the hash index's directory for a build of ``capacity``
+    slots: about four buckets a slot, at most 2^``_DIRECTORY_MAX_BITS``."""
+    return min(max(capacity.bit_length() + 1, 4), _DIRECTORY_MAX_BITS)
+
+
+def _hash_bucket(h, bits: int):
+    """The directory bucket of a hash: its top ``bits`` bits, shifted so
+    that buckets ascend with the hashes' signed order."""
+    return ((h >> (64 - bits)) + (1 << (bits - 1))).astype(jnp.int32)
+
+
 def build_hash_index(build_keys: Sequence[Column], build_live):
     """The build side's half of ``join_gather_maps``: ``(bh_sorted,
-    order)``, the 64-bit key hashes in ascending order and the build row
-    each came from. It depends on the build batch alone, so a join that
+    order, directory, run_end)``. ``bh_sorted``: the 64-bit key hashes in
+    ascending order, dead and null rows' sentinels last; ``order``: the
+    build row each came from. ``directory[b]``: how many live, non-null
+    rows hash below bucket ``b`` (``_hash_bucket``), so a probe hash's
+    equals lie in ``[directory[b], directory[b + 1])``, a handful of
+    slots; ``run_end[i]``: one past the last slot holding
+    ``bh_sorted[i]``. It depends on the build batch alone, so a join that
     probes one build with many batches computes it once (exec/join.py)
     and hands it back through ``build_index``."""
     imax = jnp.iinfo(jnp.int64).max
+    cap = build_keys[0].capacity
     bh = _join_key_hash(build_keys, imax - 2)
     bh = jnp.where(build_live, bh, jnp.int64(imax))
-    order = jnp.argsort(bh, stable=True).astype(jnp.int32)
-    return jnp.take(bh, order), order
+    # the row breaks ties: the stable order, from an unstable sort of
+    # unique keys (which compiles a quarter faster than ``argsort``'s)
+    bh_sorted, order = jax.lax.sort(
+        (bh, jnp.arange(cap, dtype=jnp.int32)), num_keys=2, is_stable=False)
+    real = bh_sorted <= _HASH_MAX
+    bits = _directory_bits(cap)
+    per_bucket = jnp.zeros(1 << bits, jnp.int32).at[
+        _hash_bucket(bh_sorted, bits)].add(real.astype(jnp.int32))
+    directory = jnp.concatenate(
+        [jnp.zeros(1, jnp.int32), jnp.cumsum(per_bucket, dtype=jnp.int32)])
+    slot = jnp.arange(cap, dtype=jnp.int32)
+    last = jnp.concatenate([bh_sorted[1:] != bh_sorted[:-1],
+                            jnp.ones(1, jnp.bool_)])
+    run_end = jax.lax.cummin(jnp.where(last, slot + 1, cap), reverse=True)
+    return bh_sorted, order, directory, run_end
+
+
+def _probe_hash_index(index, ph):
+    """``(lo, hi)``: ``searchsorted(bh_sorted, ph)`` left and right over
+    the index's live, non-null hashes, for every probe hash. Each probe
+    searches only its directory bucket, a few slots, by bisection: the
+    loop runs as many rounds as the fullest bucket needs (two or three
+    where keys are distinct, as many as a duplicated key's copies need
+    otherwise), one gather of the sorted hashes a round, instead of the
+    log2(build capacity) dependent gathers of a search over the whole
+    index, twice."""
+    bh_sorted, _, directory, run_end = index
+    cap = bh_sorted.shape[0]
+    bits = directory.shape[0].bit_length() - 1
+    bucket = _hash_bucket(ph, bits)
+    lo = jnp.take(directory, bucket)
+    end = jnp.take(directory, bucket + 1)
+
+    def unresolved(state):
+        lo, end = state
+        return jnp.any(lo < end)
+
+    def bisect(state):
+        lo, end = state
+        mid = (lo + end) // 2
+        below = jnp.take(bh_sorted, jnp.clip(mid, 0, cap - 1)) < ph
+        open_ = lo < end
+        return (jnp.where(open_ & below, mid + 1, lo),
+                jnp.where(open_ & ~below, mid, end))
+
+    lo, _ = jax.lax.while_loop(unresolved, bisect, (lo, end))
+    at = jnp.clip(lo, 0, cap - 1)
+    found = (lo < directory[-1]) & (jnp.take(bh_sorted, at) == ph)
+    return lo, jnp.where(found, jnp.take(run_end, at), lo)
 
 
 def join_gather_maps(probe_keys: Sequence[Column], build_keys: Sequence[Column],
@@ -859,13 +933,13 @@ def join_gather_maps(probe_keys: Sequence[Column], build_keys: Sequence[Column],
     """
     imax = jnp.iinfo(jnp.int64).max
     cap_b = build_keys[0].capacity
-    bh_sorted, order = build_index if build_index is not None \
+    index = build_index if build_index is not None \
         else build_hash_index(build_keys, build_live)
+    order = index[1]
 
     ph = _join_key_hash(probe_keys, imax - 3)
     ph = jnp.where(probe_live, ph, jnp.int64(imax - 1))
-    lo = jnp.searchsorted(bh_sorted, ph, side="left").astype(jnp.int32)
-    hi = jnp.searchsorted(bh_sorted, ph, side="right").astype(jnp.int32)
+    lo, hi = _probe_hash_index(index, ph)
     counts = jnp.where(probe_live, hi - lo, 0)
 
     offsets = jnp.concatenate(

@@ -96,7 +96,8 @@ def _grouped(s):
 def _case_aggregate(monkeypatch):
     """A FINAL aggregate whose AQE grouping is one group reads the held
     map batches: no partition program, and the split path's answer to
-    the bit."""
+    the bit. The split path cuts each held batch with the program that
+    gathers every slice straight to its capacity."""
     whole, m, labels = _run(_grouped(_session()), monkeypatch)
     assert m["shuffleWholeBatches"] == 2 and m["shuffleSplitBatches"] == 0
     assert m["adaptiveCoalescedPartitions"] == 7
@@ -105,7 +106,8 @@ def _case_aggregate(monkeypatch):
                               monkeypatch)
     assert m2["shuffleSplitBatches"] == 2
     assert m2.get("shuffleWholeBatches", 0) == 0
-    assert "launch._hash_partition_builder" in labels2
+    assert "launch._hash_cut_builder" in labels2
+    assert "launch._hash_partition_builder" not in labels2
     assert _rows(whole) == _rows(split)
 
 

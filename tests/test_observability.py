@@ -531,6 +531,8 @@ PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
               # the join execs' counters (exec/join.py JOIN_COUNTERS)
               "join_build_ns", "lookup_join_batches", "hash_join_batches",
               "join_capacity_relaunches", "join_readbacks",
+              "hash_join_probe_ns", "hash_join_probe_rows",
+              "hash_join_candidates", "hash_join_matches",
               # the grouped aggregate's (exec/aggregate.py LANE_COUNTERS,
               # exec/fused.py aggMaskedFilterBatches)
               "pallas_batches", "groups_direct_batches",
