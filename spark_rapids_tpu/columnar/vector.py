@@ -65,14 +65,117 @@ def compaction_indices(keep: jax.Array) -> jax.Array:
     mask dead output rows, so the duplicated gather is harmless). One
     unstable sort of the positions with every dropped row's replaced by
     ``cap``: kept positions are distinct and ascending already, so the
-    sort needs no payload and no stability (``ops/kernels.py::first_kept``
-    is the same sort). It replaced a cumsum + scatter whose compile took
-    six times as long: a 2^20-row filter of four columns, 18.7 s against
-    3.0 s for a described v5e."""
+    sort needs no stability (``ops/kernels.py::first_kept`` is the same
+    sort), and the rows it orders can ride it as payload (``sort_rows``;
+    ``ops/kernels.py::compact``). It replaced a cumsum + scatter whose
+    compile took six times as long: a 2^20-row filter of four columns,
+    18.7 s against 3.0 s for a described v5e."""
     cap = keep.shape[0]
     rows = jnp.arange(cap, dtype=jnp.int32)
     return jnp.minimum(jax.lax.sort(jnp.where(keep, rows, cap),
                                     is_stable=False), cap - 1)
+
+
+#: The least capacity at which a compaction or an exchange's cut moves a
+#: batch's fixed-width columns through the sort that orders its rows
+#: (``sort_rows``) instead of gathering each plane by the sort's result.
+#: On the v5e the carried form took less device time at every shape read,
+#: 2^15 to 2^20 rows (tools/probe_compaction.py): Q3's lineitem filter
+#: 20.9 ms against 118.4, its cut 30.1 against 92.8, a 2^18-row dimension
+#: filter 1.8 against 19.6, a 2^15-row one beside a string 7.4 against
+#: 9.4; below 2^15 nothing was read, so the gather stays there.
+SORT_CARRY_MIN_CAPACITY = 1 << 15
+
+
+def rides_sort(col) -> bool:
+    """Whether a column's planes can ride a sort as payload operands: a
+    flat fixed-width column. Strings, lists, structs and 128-bit
+    decimals are gathered by the sorted key."""
+    return type(col) is ColumnVector
+
+
+def sort_carries(batch: "ColumnarBatch") -> bool:
+    """Whether ``sort_rows`` carries this batch's columns: from the
+    batch's capacity and its columns' kinds alone, so the host knows
+    before a launch which program it runs."""
+    return batch.capacity >= SORT_CARRY_MIN_CAPACITY and \
+        any(rides_sort(c) for c in batch.columns)
+
+
+def _to_words(d: jax.Array) -> Optional[List[jax.Array]]:
+    """A fixed-width plane as uint32 words (a 64-bit integer low word
+    first), converted without a 64-bit bitcast (utils/bits.py); None for
+    a dtype that has no such form (float64)."""
+    if d.dtype == jnp.float32:
+        return [jax.lax.bitcast_convert_type(d, jnp.uint32)]
+    if d.dtype == jnp.bool_ or jnp.issubdtype(d.dtype, jnp.integer):
+        if d.dtype.itemsize == 8:
+            return [d.astype(jnp.uint32), (d >> 32).astype(jnp.uint32)]
+        return [d.astype(jnp.uint32)]
+    return None
+
+
+def _from_words(words: Sequence[jax.Array], dtype) -> jax.Array:
+    """``_to_words``' inverse: wrapping converts, bit for bit."""
+    if dtype == jnp.float32:
+        return jax.lax.bitcast_convert_type(words[0], jnp.float32)
+    if dtype == jnp.bool_:
+        return words[0] != 0
+    if len(words) == 2:
+        return ((words[1].astype(jnp.uint64) << 32)
+                | words[0].astype(jnp.uint64)).astype(dtype)
+    return words[0].astype(dtype)
+
+
+def _sort_stacked(key: jax.Array, planes: Sequence[jax.Array]):
+    """Planes of one dtype stacked into rows, each row sorted by its own
+    copy of ``key``: one sort of two operands whose compile does not grow
+    with the rows (a 2^20-row sort with eight payload operands compiles
+    in 97 s for a described v5e, eight rows of one in 5.4 s)."""
+    stack = jnp.stack(planes)
+    out = jax.lax.sort([jnp.broadcast_to(key, stack.shape), stack],
+                       dimension=1, num_keys=1, is_stable=False)
+    return out[0][0], list(out[1])
+
+
+def sort_rows(key: jax.Array, columns: Sequence["Column"]):
+    """``key`` sorted, unstably, with the rows of every column that
+    ``rides_sort`` carried through the sort as payload: ``(sorted key,
+    moved)``, ``moved[i]`` column ``i``'s rows in key order, or None
+    where the column did not ride (the caller gathers it by the sorted
+    key). The data planes go as uint32 words, the validity planes packed
+    32 to a word, float64 planes as a second sort of the same key: the
+    same rows, bit for bit, as a gather by the sorted key. Rows of equal
+    keys come out in no set order: callers give every row they keep a
+    key of its own."""
+    riders = [i for i, c in enumerate(columns) if rides_sort(c)]
+    words: List[jax.Array] = []
+    at = {}
+    wide: dict = {}
+    for i in riders:
+        w = _to_words(columns[i].data)
+        if w is None:
+            wide.setdefault(columns[i].data.dtype, []).append(i)
+        else:
+            at[i] = (len(words), len(w))
+            words += w
+    flags = len(words)
+    for s in range(0, len(riders), 32):
+        word = jnp.zeros(key.shape, jnp.uint32)
+        for b, i in enumerate(riders[s:s + 32]):
+            word = word | (columns[i].validity.astype(jnp.uint32) << b)
+        words.append(word)
+    out_key, words = _sort_stacked(key, words)
+    data = {i: _from_words(words[a:a + n], columns[i].data.dtype)
+            for i, (a, n) in at.items()}
+    for idx in wide.values():
+        data.update(zip(idx, _sort_stacked(
+            key, [columns[i].data for i in idx])[1]))
+    moved: List[Optional[ColumnVector]] = [None] * len(columns)
+    for j, i in enumerate(riders):
+        bit = (words[flags + j // 32] >> (j % 32)) & 1
+        moved[i] = ColumnVector(data[i], bit != 0, columns[i].dtype)
+    return out_key, moved
 
 
 class ColumnVector:
@@ -104,12 +207,16 @@ class ColumnVector:
         the reference's join/sort paths (JoinGatherer.scala).
         """
         safe = jnp.clip(indices, 0, self.capacity - 1)
-        data = jnp.take(self.data, safe, axis=0)
-        validity = jnp.take(self.validity, safe, axis=0)
-        if valid is not None:
-            validity = validity & valid
-            data = jnp.where(valid, data, jnp.zeros_like(data))
-        return ColumnVector(data, validity, self.dtype)
+        moved = ColumnVector(jnp.take(self.data, safe, axis=0),
+                             jnp.take(self.validity, safe, axis=0),
+                             self.dtype)
+        return moved if valid is None else moved.masked(valid)
+
+    def masked(self, valid: jax.Array) -> "ColumnVector":
+        """Rows where ``valid`` is False become null with zeroed data."""
+        return ColumnVector(jnp.where(valid, self.data,
+                                      jnp.zeros_like(self.data)),
+                            self.validity & valid, self.dtype)
 
     def to_numpy(self, num_rows: Optional[int] = None):
         """Host copy of live values as a (values, mask) pair."""
@@ -284,19 +391,26 @@ class ColumnarBatch:
         return ColumnarBatch([self.column(n) for n in names], list(names), self.num_rows)
 
     def gather(self, indices: jax.Array, new_num_rows,
-               unique: bool = False) -> "ColumnarBatch":
+               unique: bool = False,
+               moved: Optional[Sequence] = None) -> "ColumnarBatch":
         """Gather rows by index; indices beyond new_num_rows produce dead
         rows. ``unique=True`` = permutation/compaction (no source row
-        duplicated): string columns keep their tight byte buffers."""
+        duplicated): string columns keep their tight byte buffers.
+        ``moved[i]``, where not None, is column ``i`` already in the
+        order of ``indices`` (a sort carried it, ``sort_rows``): its
+        dead rows are cleared and nothing of it is gathered."""
         cap = indices.shape[0]
         valid = live_mask(cap, new_num_rows)
 
-        def g(c):
+        def g(c, m):
             from .nested import ListColumn
+            if m is not None:
+                return m.masked(valid)
             if isinstance(c, (StringColumn, ListColumn)):
                 return c.gather(indices, valid, unique=unique)
             return c.gather(indices, valid)
-        cols = [g(c) for c in self.columns]
+        cols = [g(c, m) for c, m in
+                zip(self.columns, moved or [None] * len(self.columns))]
         return ColumnarBatch(cols, self.names, new_num_rows)
 
     def schema(self):

@@ -48,6 +48,20 @@ class Metric:
         return f"{self.name}={self.value}{self.unit}"
 
 
+#: operator Metric -> key of the query record's ``phases``: batches a
+#: filter compacted or an exchange cut with their fixed-width columns
+#: carried through the sort that orders the rows, and batches whose every
+#: plane was gathered (``columnar/vector.py::sort_carries``)
+COMPACT_COUNTERS = {"compactSortedBatches": "compact_sorted_batches",
+                    "compactGatheredBatches": "compact_gathered_batches"}
+
+
+def count_compaction(metrics: Dict[str, "Metric"], carried: bool) -> None:
+    """One batch compacted or cut, the way ``sort_carries`` said."""
+    name = "compactSortedBatches" if carried else "compactGatheredBatches"
+    metrics.setdefault(name, Metric(name, Metric.MODERATE)).add(1)
+
+
 class NvtxTimer:
     """Scoped op-time accumulation (NvtxWithMetrics.scala:21-48).
 

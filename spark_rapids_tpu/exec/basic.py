@@ -19,11 +19,12 @@ import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
 from ..columnar.vector import (ColumnVector, ColumnarBatch, choose_capacity,
-                               live_mask)
+                               live_mask, sort_carries)
 from ..expr.core import Expression, output_name
 from ..jit_registry import read_int, shared_fn_jit, shared_method_jit
 from ..ops import kernels as K
-from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
+from .base import (ExecContext, Metric, NvtxTimer, Schema, TpuExec,
+                   count_compaction)
 
 
 class BatchScanExec(TpuExec):
@@ -123,7 +124,9 @@ class FilterExec(TpuExec):
         return self.children[0].output_schema
 
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
+        m = ctx.metrics_for(self.exec_id)
         for batch in self.children[0].execute(ctx):
+            count_compaction(m, sort_carries(batch))
             # (not yielded under the semaphore: a producer thread that
             # stands in backpressure would keep its permit)
             with ctx.semaphore:

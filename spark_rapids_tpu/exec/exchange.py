@@ -54,14 +54,15 @@ from ..jit_registry import read_back, read_int, shared_fn_jit
 from ..memory.spill import batch_nbytes
 from ..ops import kernels as K
 from ..parallel.partition import (PartitionedBatch, cut_batch,
-                                  hash_partition_ids, partition_batch,
-                                  partition_counts,
+                                  cut_carries, hash_partition_ids,
+                                  partition_batch, partition_counts,
                                   range_partition_ids,
                                   round_robin_partition_ids,
                                   string_from_padded)
 from ..parallel.shuffle_manager import (WHOLE, ShuffleManager,
                                         shuffle_manager)
-from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
+from .base import (ExecContext, Metric, NvtxTimer, Schema, TpuExec,
+                   count_compaction)
 
 _SHUFFLE_IDS = itertools.count(1)
 _IDS_LOCK = threading.Lock()
@@ -824,16 +825,19 @@ class ShuffleExchangeExec(TpuExec):
             detail = mgr.map_output_statistics(
                 self.shuffle_id, map_ids=set(held)).detail
             split_m = self._counter(ctx, "shuffleSplitBatches")
+            metrics = ctx.metrics_for(self.exec_id)
             for map_id in held:
                 rows = [detail.get((map_id, r), (0, 0))[0]
                         for r in range(n_parts)]
 
                 def cut(batch, rows=rows):
                     if any(isinstance(c, ListColumn) for c in batch.columns):
+                        count_compaction(metrics, False)
                         with ctx.semaphore:
                             return [K.compact_for_transfer(p, num_rows=n)
                                     if n else None
                                     for p, n in zip(fn(batch), rows)]
+                    count_compaction(metrics, cut_carries(batch, n_parts))
                     caps = tuple(K.transfer_capacity(n, batch.capacity)
                                  if n else 0 for n in rows)
                     with ctx.semaphore:

@@ -46,12 +46,13 @@ from typing import Iterator, List
 import jax
 import jax.numpy as jnp
 
-from ..columnar.vector import ColumnarBatch
+from ..columnar.vector import ColumnarBatch, sort_carries
 from ..jit_registry import read_int, shared_fn_jit
 from ..jit_registry import stats as _registry_stats
 from ..ops import kernels as K
 from .aggregate import count_lane_flags
-from .base import ExecContext, Metric, NvtxTimer, Schema, TpuExec
+from .base import (ExecContext, Metric, NvtxTimer, Schema, TpuExec,
+                   count_compaction)
 
 #: module-level fusion tally (bench reads this + the registry's
 #: per-module stats to report compile reuse across a sweep);
@@ -343,6 +344,8 @@ class FusedPipelineExec(TpuExec):
                              tuple(tuple(getattr(st, f))
                                    for f in _AGG_FIELDS))
         self._specs = tuple(specs)
+        # batch signature -> sort_carries of each filter stage's batch
+        self._carries: dict = {}
         # donation is only sound when the source's buffers are
         # single-use (planner gates on file scans) and only effective
         # off-CPU (the CPU backend ignores donations with a warning)
@@ -384,6 +387,23 @@ class FusedPipelineExec(TpuExec):
             tags.append("donate")
         tag = f" ({', '.join(tags)})" if tags else ""
         return f"FusedPipeline[{inner}]{tag}"
+
+    def _filter_carries(self, batch: ColumnarBatch) -> List[bool]:
+        """``sort_carries`` of the batch each filter stage of a chain
+        without an aggregate compacts, from the shapes the stages before
+        it give (``jax.eval_shape``), once a batch signature."""
+        leaves, tree = jax.tree_util.tree_flatten(batch)
+        sig = (tree, tuple((getattr(x, "shape", None),
+                            getattr(x, "dtype", None)) for x in leaves))
+        carries = self._carries.get(sig)
+        if carries is None:
+            carries, cur = [], batch
+            for spec in self._specs:
+                if spec[0] == "filter":
+                    carries.append(sort_carries(cur))
+                cur = jax.eval_shape(_row_stage_fn(spec), cur)
+            self._carries[sig] = carries
+        return carries
 
     # --- per-stage attribution (tracer-gated calibration) ---
     def _calibrate(self, ctx: ExecContext, batch: ColumnarBatch,
@@ -482,6 +502,8 @@ class FusedPipelineExec(TpuExec):
                     if self._use_pallas:
                         used_flags.append(used)
                 else:
+                    for carried in self._filter_carries(batch):
+                        count_compaction(m, carried)
                     out = self._fn(batch)
             saved.add(self._saved_bytes_per_slot * int(batch.capacity))
             sb.close()

@@ -26,12 +26,12 @@ from typing import Iterator, List, NamedTuple, Optional, Sequence, Tuple
 import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
-from ..columnar.vector import ColumnarBatch, choose_capacity
+from ..columnar.vector import ColumnarBatch, choose_capacity, sort_carries
 from ..expr.core import Expression
 from ..jit_registry import read_back, read_int, shared_fn_jit
 from ..ops import kernels as K
 from ..obs.trace import annotate
-from .base import ExecContext, Metric, Schema, TpuExec
+from .base import ExecContext, Metric, Schema, TpuExec, count_compaction
 
 # Join types (Catalyst names)
 INNER = "inner"
@@ -717,6 +717,7 @@ class _HashJoinBase(TpuExec):
                 if n < min_rows:
                     yield probe
                     continue
+                count_compaction(m, sort_carries(probe))
                 with ctx.semaphore:
                     out = self._jit_cache[pkey](bits, probe)
                 dropped.add(n - read_int(out.num_rows))

@@ -40,7 +40,8 @@ from ..columnar import dtypes as dt
 from ..jit_registry import named_jit, read_int
 from ..columnar.vector import (Column, ColumnVector, ColumnarBatch,
                                StringColumn, compaction_indices, live_mask,
-                               round_pow2, rows_from_offsets)
+                               round_pow2, rows_from_offsets, sort_carries,
+                               sort_rows)
 
 # ---------------------------------------------------------------------------
 # Filter
@@ -48,11 +49,24 @@ from ..columnar.vector import (Column, ColumnVector, ColumnarBatch,
 
 
 def compact(batch: ColumnarBatch, keep: jnp.ndarray) -> ColumnarBatch:
-    """Keep rows where ``keep`` (restricted to live rows), preserving order."""
+    """Keep rows where ``keep`` (restricted to live rows), preserving order.
+
+    One unstable sort of the positions, every dropped row's replaced by
+    the capacity (``compaction_indices``): the kept rows come out first,
+    in order. Where ``sort_carries`` (the batch's capacity and its
+    columns' kinds), the fixed-width columns' planes ride that sort as
+    payload, and only strings, lists, structs and 128-bit decimals are
+    gathered by the sorted positions; otherwise every plane is gathered
+    by them. Both give the same batch."""
     keep = keep & batch.live_mask()
     n = jnp.sum(keep).astype(jnp.int32)
-    idx = compaction_indices(keep)
-    return batch.gather(idx, n, unique=True)
+    if not sort_carries(batch):
+        return batch.gather(compaction_indices(keep), n, unique=True)
+    cap = batch.capacity
+    rows = jnp.arange(cap, dtype=jnp.int32)
+    pos, moved = sort_rows(jnp.where(keep, rows, cap), batch.columns)
+    return batch.gather(jnp.minimum(pos, cap - 1), n, unique=True,
+                        moved=moved)
 
 
 def filter_batch(batch: ColumnarBatch, cond: ColumnVector) -> ColumnarBatch:
@@ -1103,8 +1117,10 @@ def first_kept(keep: jnp.ndarray, out_capacity: int) -> jnp.ndarray:
     j is the position of the j-th kept row (past the last one: the
     capacity's last row; callers mask). One sort of the positions with
     every dead row's replaced by ``cap``: kept positions are distinct and
-    ascending already, so the sort needs no payload and no stability,
-    and its cost does not follow ``out_capacity``. On the v5e 0.45 ms
+    ascending already, so the sort needs no stability, and its cost does
+    not follow ``out_capacity``. The lookup join gathers its columns by
+    these positions (``compact`` carries a filter's columns through the
+    same sort instead). On the v5e 0.45 ms
     at 2^20 rows, where binary searches of the prefix sum (up to PR 33)
     cost 19.8 ms for 2^17 rows out and 0.35 for 2^10
     (tools/probe_first_kept.py, PR 34)."""

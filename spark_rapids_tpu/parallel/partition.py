@@ -25,7 +25,8 @@ import jax.numpy as jnp
 
 from ..columnar import dtypes as dt
 from ..columnar.vector import (Column, ColumnVector, ColumnarBatch,
-                               StringColumn, round_pow2)
+                               StringColumn, round_pow2, sort_carries,
+                               sort_rows)
 from ..expr.hashing import murmur3_row_hash
 
 
@@ -152,41 +153,73 @@ def partition_counts(batch: ColumnarBatch, part_ids: jnp.ndarray,
     return _route(batch, part_ids, num_parts)[1]
 
 
+def cut_carries(batch: ColumnarBatch, num_parts: int) -> bool:
+    """Whether ``cut_batch`` moves this batch's fixed-width columns
+    through its sort (``sort_carries``, and a sort key that fits 32
+    bits): known on the host before the launch."""
+    return sort_carries(batch) and \
+        (num_parts + 1) * batch.capacity < 2 ** 31
+
+
 def cut_batch(batch: ColumnarBatch, part_ids: jnp.ndarray, num_parts: int,
               capacities: Sequence[int]) -> List[Optional[ColumnarBatch]]:
-    """Partition ``p``'s live rows, in their order, gathered straight into
+    """Partition ``p``'s live rows, in their order, moved straight into
     a batch of ``capacities[p]`` slots (None where that is 0): what
     ``partition_batch``'s slot ``p`` holds once repacked to that capacity
     (``ops/kernels.py::repack_to``), with the same shapes, a string
-    column's byte buffer included. Each partition gathers its own
+    column's byte buffer included. Each partition takes its own
     capacity and not the batch's, so a cut whose partitions' rows are
-    known gathers about the batch once instead of ``num_parts`` times.
-    ListColumns are not cut here (``partition_batch``)."""
+    known moves about the batch once instead of ``num_parts`` times.
+
+    One unstable sort of a key unique to each slot, its destination then
+    its position, lays the partitions end to end, each in its rows'
+    order. Where ``cut_carries``, the fixed-width columns ride that sort
+    as payload and partition ``p`` is a contiguous slice of the sorted
+    planes; strings, and every column otherwise, are gathered by the
+    sorted positions. Both give the same slices. ListColumns are not
+    cut here (``partition_batch``)."""
     cap = batch.capacity
     pid, counts = _route(batch, part_ids, num_parts)
     offsets = jnp.concatenate(
         [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
+    moved: List[Optional[ColumnVector]] = [None] * len(batch.columns)
     if (num_parts + 1) * cap < 2 ** 31:
-        # one sort of a key unique to each slot, its destination then its
-        # position: the rows of a partition in their order. Unique keys
-        # need no stable sort, and the unstable one compiles five times
-        # faster than the stable sort or the counting sort's cumsums
-        slot = jnp.arange(cap, dtype=jnp.int32)
-        order = jax.lax.sort(pid * cap + slot, is_stable=False) % cap
+        # unique keys need no stable sort, and the unstable one compiles
+        # five times faster than the stable sort or the counting sort's
+        # cumsums
+        key = pid * cap + jnp.arange(cap, dtype=jnp.int32)
+        if cut_carries(batch, num_parts):
+            key, moved = sort_rows(key, batch.columns)
+        else:
+            key = jax.lax.sort(key, is_stable=False)
+        order = key % cap
     else:
         order = jnp.argsort(pid, stable=True).astype(jnp.int32)
+    # padded by the widest slice, so that no slice's start is clamped
+    width = max(capacities, default=0)
+    moved = [None if m is None else
+             ColumnVector(jnp.pad(m.data, (0, width)),
+                          jnp.pad(m.validity, (0, width)), m.dtype)
+             for m in moved]
+    gathers = any(m is None for m in moved)
     slices: List[Optional[ColumnarBatch]] = []
     for p, c in enumerate(capacities):
         if not c:
             slices.append(None)
             continue
-        rows = jnp.take(order, jnp.clip(
-            offsets[p] + jnp.arange(c, dtype=jnp.int32), 0, cap - 1))
+        at = jnp.arange(c, dtype=jnp.int32)
+        rows = jnp.take(order, jnp.clip(offsets[p] + at, 0, cap - 1)) \
+            if gathers else None
         n = jnp.minimum(counts[p], c)
-        valid = jnp.arange(c, dtype=jnp.int32) < n
+        valid = at < n
         cols = []
-        for col in batch.columns:
-            if isinstance(col, StringColumn):
+        for col, m in zip(batch.columns, moved):
+            if m is not None:
+                cols.append(ColumnVector(
+                    jax.lax.dynamic_slice_in_dim(m.data, offsets[p], c),
+                    jax.lax.dynamic_slice_in_dim(m.validity, offsets[p], c),
+                    m.dtype).masked(valid))
+            elif isinstance(col, StringColumn):
                 # the byte buffer partition_batch's slot would have after
                 # the repack: min(capacity x width, pow2(c x width))
                 w = col.pad_bucket

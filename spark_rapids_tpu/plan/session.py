@@ -16,6 +16,7 @@ import numpy as np
 
 from ..columnar import dtypes as dt
 from ..conf import SrtConf, active_conf, set_active_conf
+from ..exec.base import COMPACT_COUNTERS as _COMPACT_COUNTERS
 from ..exec.base import ExecContext, TpuExec
 from ..exec.aggregate import LANE_COUNTERS as _LANE_COUNTERS
 from ..exec.exchange import EXCHANGE_COUNTERS as _EXCHANGE_COUNTERS
@@ -455,6 +456,9 @@ _PHASE_METRICS.update(_LANE_COUNTERS)
 _PHASE_METRICS["aggMaskedFilterBatches"] = "agg_masked_filter_batches"
 # the shuffle exchange's map batches read whole and cut into partitions
 _PHASE_METRICS.update(_EXCHANGE_COUNTERS)
+# the batches a filter compacted or an exchange cut with their fixed-width
+# columns carried through the sort, and with every plane gathered
+_PHASE_METRICS.update(_COMPACT_COUNTERS)
 
 
 #: a gauge's peak, not a sum: the largest over the plan's operators

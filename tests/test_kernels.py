@@ -1,7 +1,9 @@
+import pytest
 import jax.numpy as jnp
 import numpy as np
 
 from spark_rapids_tpu.columnar import batch_from_pydict, batch_to_pydict, dtypes as dt
+from spark_rapids_tpu.columnar.vector import ColumnVector
 from spark_rapids_tpu.expr import aggregates as agg
 from spark_rapids_tpu.ops import kernels as K
 
@@ -122,3 +124,169 @@ def test_local_limit():
     b = _mk({"a": [1, 2, 3, 4, 5]})
     out = K.local_limit(b, 3)
     assert batch_to_pydict(out)["a"] == [1, 2, 3]
+
+
+# ---------------------------------------------------------------------------
+# A compaction's and a cut's fixed-width columns carried through the sort
+# (columnar/vector.py::sort_rows) against every plane gathered
+# ---------------------------------------------------------------------------
+
+_CARRY_SCHEMA = [("i32", dt.INT32), ("i64", dt.INT64), ("f32", dt.FLOAT32),
+                 ("f64", dt.FLOAT64), ("d", dt.DATE), ("b", dt.BOOL),
+                 ("s", dt.STRING)]
+
+
+def _carry_batch(n=200, cap=256):
+    """Every fixed-width kind the sort carries, with nulls, extremes, NaN,
+    infinities and -0.0, beside a string column it does not."""
+    rng = np.random.default_rng(39)
+
+    def nulls(values):
+        return [None if rng.random() < 0.15 else v for v in values]
+    i64 = rng.integers(-2 ** 63, 2 ** 63, n, dtype=np.int64).tolist()
+    i64[:2] = [-2 ** 63, 2 ** 63 - 1]
+    data = {"i32": nulls(rng.integers(-2 ** 31, 2 ** 31, n).tolist()),
+            "i64": nulls(i64),
+            "f32": nulls(rng.standard_normal(n).astype(np.float32).tolist()),
+            "f64": nulls([float("nan"), float("-inf"), -0.0, 1e308]
+                         + rng.standard_normal(n - 4).tolist()),
+            "d": nulls(rng.integers(0, 20000, n).tolist()),
+            "b": nulls([bool(x) for x in rng.integers(0, 2, n)]),
+            "s": nulls(["x" * (i % 9) for i in range(n)])}
+    return batch_from_pydict(data, capacity=cap, schema=_CARRY_SCHEMA)
+
+
+def _bits(batch):
+    import jax
+    return [(x.dtype, x.shape, np.asarray(x).tobytes())
+            for x in jax.tree_util.tree_leaves(batch)]
+
+
+@pytest.mark.parametrize("case", ["compact_some", "compact_all",
+                                  "compact_none", "filter_some",
+                                  "cut_zero_capacity", "cut_over_capacity"])
+def test_sort_carried_rows_equal_the_gathered(monkeypatch, case):
+    """The same batches, bit for bit, whether the fixed-width columns ride
+    the sort or every plane is gathered by its result: values, validity,
+    ``num_rows``, dead rows zeroed and null, string byte buffers; a cut's
+    slices too, where a partition takes no capacity and where its rows
+    outrun its capacity."""
+    import jax
+    from spark_rapids_tpu.columnar import vector
+    from spark_rapids_tpu.parallel.partition import cut_batch
+    batch = _carry_batch()
+    rng = np.random.default_rng(7)
+
+    gathers = {}
+
+    def traced(fn, carry):
+        # the threshold at or past the batch's capacity as the program
+        # traces; a new function each time, so that nothing is reused
+        monkeypatch.setattr(vector, "SORT_CARRY_MIN_CAPACITY",
+                            batch.capacity + (0 if carry else 1))
+
+        def program(*args):
+            fresh = lambda *a: fn(*a)  # noqa: E731
+            gathers[carry] = str(jax.make_jaxpr(fresh)(*args)).count("gather[")
+            return jax.jit(fresh)(*args)
+        return program
+    if case.startswith("cut"):
+        pids = jnp.asarray(rng.integers(0, 8, 256).astype(np.int32))
+        caps = (64, 0, 32, 64, 16, 64, 64, 64) if case == "cut_zero_capacity" \
+            else (8,) * 8
+
+        def run(carry):
+            return traced(lambda b, p: cut_batch(b, p, 8, caps), carry)(
+                batch, pids)
+        gathered, carried = run(False), run(True)
+        assert gathers[True] < gathers[False]
+        assert [g is None for g in gathered] == [c == 0 for c in caps]
+        for g, c, cap in zip(gathered, carried, caps):
+            assert (g is None and c is None) or _bits(g) == _bits(c)
+            if cap:
+                assert int(c.num_rows) <= cap == c.capacity
+        if case == "cut_over_capacity":
+            assert all(int(c.num_rows) == 8 for c in carried)
+        return
+    keep = {"compact_some": rng.random(256) < 0.5,
+            "filter_some": rng.random(256) < 0.3,
+            "compact_all": np.ones(256, bool),
+            "compact_none": np.zeros(256, bool)}[case]
+    if case == "filter_some":
+        b = batch.column("b")
+        cond = ColumnVector(jnp.asarray(keep) & b.data, b.validity, dt.BOOL)
+
+        def run(carry):
+            return traced(K.filter_batch, carry)(batch, cond)
+    else:
+        def run(carry):
+            return traced(K.compact, carry)(batch, jnp.asarray(keep))
+    gathered, carried = run(False), run(True)
+    assert gathers[True] < gathers[False]
+    assert _bits(gathered) == _bits(carried)
+    n = int(carried.num_rows)
+    assert n == {"compact_all": 200, "compact_none": 0}.get(case, n)
+    for c in carried.columns[:-1]:
+        assert not np.asarray(c.validity)[n:].any()
+        assert not np.asarray(c.data)[n:].astype(bool).any()
+    want = batch_to_pydict(batch)
+    kept = [i for i in range(200) if keep[i] and (
+        case != "filter_some" or want["b"][i])]
+    got = batch_to_pydict(carried)
+    for name, _ in _CARRY_SCHEMA:
+        exp = [want[name][i] for i in kept]
+        if name == "f64":
+            assert [repr(x) for x in got[name]] == [repr(x) for x in exp]
+        else:
+            assert got[name] == exp
+
+
+def test_sort_carries_from_capacity_and_column_kinds():
+    """The rule the host and the program share: a batch at the threshold
+    capacity with a fixed-width column rides the sort; half that
+    capacity, or strings alone, keep the gather."""
+    from spark_rapids_tpu.columnar.vector import (SORT_CARRY_MIN_CAPACITY,
+                                                  sort_carries)
+    from spark_rapids_tpu.parallel.partition import cut_carries
+    big = _carry_batch(cap=SORT_CARRY_MIN_CAPACITY)
+    assert sort_carries(big) and cut_carries(big, 8)
+    small = _carry_batch(cap=SORT_CARRY_MIN_CAPACITY // 2)
+    assert not sort_carries(small) and not cut_carries(small, 8)
+    assert not sort_carries(big.select(["s"]))
+
+
+@pytest.mark.parametrize("min_capacity", [None, 8])
+def test_compaction_counters_count_the_path_taken(monkeypatch, min_capacity):
+    """A filter's compaction and an exchange's cut land in the query's
+    ``phases`` as ``compact_sorted_batches`` where the batch's capacity
+    reaches the threshold and as ``compact_gathered_batches`` where it
+    does not, with the same answers."""
+    from spark_rapids_tpu.columnar import vector
+    from spark_rapids_tpu.conf import SrtConf
+    from spark_rapids_tpu.expr.core import col
+    from spark_rapids_tpu.plan import TpuSession
+    if min_capacity:
+        monkeypatch.setattr(vector, "SORT_CARRY_MIN_CAPACITY", min_capacity)
+    s = TpuSession(SrtConf({
+        "srt.shuffle.partitions": 8,
+        "srt.sql.adaptive.coalescePartitions.enabled": False}))
+    k = [i % 11 for i in range(100)]
+    v = [float(i) for i in range(100)]
+    df = s.create_dataframe({"k": k, "v": v})
+    taken = "sorted" if min_capacity else "gathered"
+    other = "gathered" if min_capacity else "sorted"
+
+    kept = df.filter(col("v") > 60.0).collect()
+    assert [r["v"] for r in kept] == v[61:]
+    phases = s._last_execution["phases"]
+    assert phases[f"compact_{taken}_batches"] == 1
+    assert phases[f"compact_{other}_batches"] == 0
+
+    sums = df.group_by(col("k")).agg(agg.Sum(col("v")).alias("sv")).collect()
+    assert sorted((r["k"], r["sv"]) for r in sums) == sorted(
+        (g, sum(x for key, x in zip(k, v) if key == g)) for g in range(11))
+    phases = s._last_execution["phases"]
+    assert phases["exchange_split_batches"] >= 1
+    assert phases[f"compact_{taken}_batches"] == \
+        phases["exchange_split_batches"]
+    assert phases[f"compact_{other}_batches"] == 0

@@ -538,7 +538,10 @@ PHASE_KEYS = {"parse_ns", "plan_ns", "execute_ns", "fetch_ns",
               "pallas_batches", "groups_direct_batches",
               "groups_hash_claim_batches", "agg_masked_filter_batches",
               # the shuffle exchange's (exec/exchange.py EXCHANGE_COUNTERS)
-              "exchange_whole_batches", "exchange_split_batches"}
+              "exchange_whole_batches", "exchange_split_batches",
+              # filters' compactions and exchanges' cuts (exec/base.py
+              # COMPACT_COUNTERS)
+              "compact_sorted_batches", "compact_gathered_batches"}
 
 
 @pytest.fixture(scope="module")
@@ -624,6 +627,9 @@ def test_query_record_carries_phases(traced_parquet_query):
     assert totals["shuffleWholeBatches"] == \
         phases["exchange_whole_batches"] == 1
     assert phases["exchange_split_batches"] == 0
+    # the filter is the aggregate's mask: nothing compacted, nothing cut
+    assert phases["compact_sorted_batches"] == \
+        phases["compact_gathered_batches"] == 0
 
 
 def test_read_back_counts_a_device_read_once_on_the_current_query():
