@@ -23,7 +23,7 @@ whole batches flow through ``jax.jit`` / ``shard_map`` untouched.
 
 from __future__ import annotations
 
-from typing import List, Optional, Sequence, Union
+from typing import List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -76,30 +76,25 @@ def compaction_indices(keep: jax.Array) -> jax.Array:
                                     is_stable=False), cap - 1)
 
 
-#: The least capacity at which a compaction or an exchange's cut moves a
-#: batch's fixed-width columns through the sort that orders its rows
-#: (``sort_rows``) instead of gathering each plane by the sort's result.
-#: On the v5e the carried form took less device time at every shape read,
-#: 2^15 to 2^20 rows (tools/probe_compaction.py): Q3's lineitem filter
-#: 20.9 ms against 118.4, its cut 30.1 against 92.8, a 2^18-row dimension
-#: filter 1.8 against 19.6, a 2^15-row one beside a string 7.4 against
-#: 9.4; below 2^15 nothing was read, so the gather stays there.
-SORT_CARRY_MIN_CAPACITY = 1 << 15
+def rides_sort(dtype: dt.DType) -> bool:
+    """Whether a column of ``dtype`` rides a sort as payload operands
+    (``sort_rows``): a flat fixed-width column, a ``ColumnVector``.
+    Strings, lists, structs, maps and 128-bit decimals are gathered by
+    the sorted key. On the v5e the carried form took less device time
+    than the gather at every shape read, 2^10 to 2^20 rows
+    (tools/probe_compaction.py): Q3's lineitem filter 20.9 ms against
+    118.4, its cut 30.1 against 92.8, a 2^18-row dimension filter 1.8
+    against 19.6, a 2^15-row one beside a string 7.4 against 9.4, a
+    2^10-row one 0.39 against 0.44, a 2^10-row cut 0.05 against 0.16.
+    So the rule has no capacity: a small batch carries too."""
+    return dtype.physical is not None and not getattr(dtype, "is_wide", False)
 
 
-def rides_sort(col) -> bool:
-    """Whether a column's planes can ride a sort as payload operands: a
-    flat fixed-width column. Strings, lists, structs and 128-bit
-    decimals are gathered by the sorted key."""
-    return type(col) is ColumnVector
-
-
-def sort_carries(batch: "ColumnarBatch") -> bool:
-    """Whether ``sort_rows`` carries this batch's columns: from the
-    batch's capacity and its columns' kinds alone, so the host knows
-    before a launch which program it runs."""
-    return batch.capacity >= SORT_CARRY_MIN_CAPACITY and \
-        any(rides_sort(c) for c in batch.columns)
+def sort_carries(schema: Sequence[Tuple[str, dt.DType]]) -> bool:
+    """Whether a compaction or a cut of a batch of this schema (its
+    ``schema()``, or an operator's ``output_schema``) carries any of its
+    columns through the sort: the host knows it before the launch."""
+    return any(rides_sort(t) for _, t in schema)
 
 
 def _to_words(d: jax.Array) -> Optional[List[jax.Array]]:
@@ -148,7 +143,10 @@ def sort_rows(key: jax.Array, columns: Sequence["Column"]):
     same rows, bit for bit, as a gather by the sorted key. Rows of equal
     keys come out in no set order: callers give every row they keep a
     key of its own."""
-    riders = [i for i, c in enumerate(columns) if rides_sort(c)]
+    riders = [i for i, c in enumerate(columns) if rides_sort(c.dtype)]
+    moved: List[Optional[ColumnVector]] = [None] * len(columns)
+    if not riders:
+        return jax.lax.sort(key, is_stable=False), moved
     words: List[jax.Array] = []
     at = {}
     wide: dict = {}
@@ -171,7 +169,6 @@ def sort_rows(key: jax.Array, columns: Sequence["Column"]):
     for idx in wide.values():
         data.update(zip(idx, _sort_stacked(
             key, [columns[i].data for i in idx])[1]))
-    moved: List[Optional[ColumnVector]] = [None] * len(columns)
     for j, i in enumerate(riders):
         bit = (words[flags + j // 32] >> (j % 32)) & 1
         moved[i] = ColumnVector(data[i], bit != 0, columns[i].dtype)

@@ -126,7 +126,7 @@ class FilterExec(TpuExec):
     def do_execute(self, ctx: ExecContext) -> Iterator[ColumnarBatch]:
         m = ctx.metrics_for(self.exec_id)
         for batch in self.children[0].execute(ctx):
-            count_compaction(m, sort_carries(batch))
+            count_compaction(m, sort_carries(batch.schema()))
             # (not yielded under the semaphore: a producer thread that
             # stands in backpressure would keep its permit)
             with ctx.semaphore:

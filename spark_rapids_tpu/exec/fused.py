@@ -344,8 +344,11 @@ class FusedPipelineExec(TpuExec):
                              tuple(tuple(getattr(st, f))
                                    for f in _AGG_FIELDS))
         self._specs = tuple(specs)
-        # batch signature -> sort_carries of each filter stage's batch
-        self._carries: dict = {}
+        # sort_carries of each filter stage's batch, a chain without an
+        # aggregate's (its filters compact)
+        self._filter_carries = [] if self._agg is not None else [
+            sort_carries(st.output_schema) for st in self.stages
+            if isinstance(st, FilterExec)]
         # donation is only sound when the source's buffers are
         # single-use (planner gates on file scans) and only effective
         # off-CPU (the CPU backend ignores donations with a warning)
@@ -387,23 +390,6 @@ class FusedPipelineExec(TpuExec):
             tags.append("donate")
         tag = f" ({', '.join(tags)})" if tags else ""
         return f"FusedPipeline[{inner}]{tag}"
-
-    def _filter_carries(self, batch: ColumnarBatch) -> List[bool]:
-        """``sort_carries`` of the batch each filter stage of a chain
-        without an aggregate compacts, from the shapes the stages before
-        it give (``jax.eval_shape``), once a batch signature."""
-        leaves, tree = jax.tree_util.tree_flatten(batch)
-        sig = (tree, tuple((getattr(x, "shape", None),
-                            getattr(x, "dtype", None)) for x in leaves))
-        carries = self._carries.get(sig)
-        if carries is None:
-            carries, cur = [], batch
-            for spec in self._specs:
-                if spec[0] == "filter":
-                    carries.append(sort_carries(cur))
-                cur = jax.eval_shape(_row_stage_fn(spec), cur)
-            self._carries[sig] = carries
-        return carries
 
     # --- per-stage attribution (tracer-gated calibration) ---
     def _calibrate(self, ctx: ExecContext, batch: ColumnarBatch,
@@ -502,7 +488,7 @@ class FusedPipelineExec(TpuExec):
                     if self._use_pallas:
                         used_flags.append(used)
                 else:
-                    for carried in self._filter_carries(batch):
+                    for carried in self._filter_carries:
                         count_compaction(m, carried)
                     out = self._fn(batch)
             saved.add(self._saved_bytes_per_slot * int(batch.capacity))

@@ -717,7 +717,7 @@ class _HashJoinBase(TpuExec):
                 if n < min_rows:
                     yield probe
                     continue
-                count_compaction(m, sort_carries(probe))
+                count_compaction(m, sort_carries(probe.schema()))
                 with ctx.semaphore:
                     out = self._jit_cache[pkey](bits, probe)
                 dropped.add(n - read_int(out.num_rows))

@@ -40,8 +40,7 @@ from ..columnar import dtypes as dt
 from ..jit_registry import named_jit, read_int
 from ..columnar.vector import (Column, ColumnVector, ColumnarBatch,
                                StringColumn, compaction_indices, live_mask,
-                               round_pow2, rows_from_offsets, sort_carries,
-                               sort_rows)
+                               round_pow2, rows_from_offsets, sort_rows)
 
 # ---------------------------------------------------------------------------
 # Filter
@@ -53,15 +52,12 @@ def compact(batch: ColumnarBatch, keep: jnp.ndarray) -> ColumnarBatch:
 
     One unstable sort of the positions, every dropped row's replaced by
     the capacity (``compaction_indices``): the kept rows come out first,
-    in order. Where ``sort_carries`` (the batch's capacity and its
-    columns' kinds), the fixed-width columns' planes ride that sort as
-    payload, and only strings, lists, structs and 128-bit decimals are
-    gathered by the sorted positions; otherwise every plane is gathered
-    by them. Both give the same batch."""
+    in order. The fixed-width columns' planes ride that sort as payload
+    (``sort_rows``); strings, lists, structs and 128-bit decimals are
+    gathered by the sorted positions. The same batch as every column
+    gathered by ``compaction_indices``."""
     keep = keep & batch.live_mask()
     n = jnp.sum(keep).astype(jnp.int32)
-    if not sort_carries(batch):
-        return batch.gather(compaction_indices(keep), n, unique=True)
     cap = batch.capacity
     rows = jnp.arange(cap, dtype=jnp.int32)
     pos, moved = sort_rows(jnp.where(keep, rows, cap), batch.columns)

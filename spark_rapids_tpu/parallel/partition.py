@@ -153,12 +153,18 @@ def partition_counts(batch: ColumnarBatch, part_ids: jnp.ndarray,
     return _route(batch, part_ids, num_parts)[1]
 
 
+def _slot_keys_fit(capacity: int, num_parts: int) -> bool:
+    """Whether ``cut_batch``'s key, a slot's destination then its
+    position, fits 32 bits."""
+    return (num_parts + 1) * capacity < 2 ** 31
+
+
 def cut_carries(batch: ColumnarBatch, num_parts: int) -> bool:
-    """Whether ``cut_batch`` moves this batch's fixed-width columns
-    through its sort (``sort_carries``, and a sort key that fits 32
-    bits): known on the host before the launch."""
-    return sort_carries(batch) and \
-        (num_parts + 1) * batch.capacity < 2 ** 31
+    """Whether ``cut_batch`` moves any of this batch's columns through
+    its sort (``sort_carries``, and a key that fits 32 bits): the host's
+    count of the launch."""
+    return sort_carries(batch.schema()) and \
+        _slot_keys_fit(batch.capacity, num_parts)
 
 
 def cut_batch(batch: ColumnarBatch, part_ids: jnp.ndarray, num_parts: int,
@@ -173,25 +179,23 @@ def cut_batch(batch: ColumnarBatch, part_ids: jnp.ndarray, num_parts: int,
 
     One unstable sort of a key unique to each slot, its destination then
     its position, lays the partitions end to end, each in its rows'
-    order. Where ``cut_carries``, the fixed-width columns ride that sort
-    as payload and partition ``p`` is a contiguous slice of the sorted
-    planes; strings, and every column otherwise, are gathered by the
-    sorted positions. Both give the same slices. ListColumns are not
-    cut here (``partition_batch``)."""
+    order. The fixed-width columns ride that sort as payload
+    (``sort_rows``) and partition ``p`` is a contiguous slice of the
+    sorted planes; strings are gathered by the sorted positions, and so
+    is every column of a batch whose key would pass 32 bits (a stable
+    argsort of the destinations). ListColumns are not cut here
+    (``partition_batch``)."""
     cap = batch.capacity
     pid, counts = _route(batch, part_ids, num_parts)
     offsets = jnp.concatenate(
         [jnp.zeros(1, jnp.int32), jnp.cumsum(counts, dtype=jnp.int32)])
     moved: List[Optional[ColumnVector]] = [None] * len(batch.columns)
-    if (num_parts + 1) * cap < 2 ** 31:
+    if _slot_keys_fit(cap, num_parts):
         # unique keys need no stable sort, and the unstable one compiles
         # five times faster than the stable sort or the counting sort's
         # cumsums
-        key = pid * cap + jnp.arange(cap, dtype=jnp.int32)
-        if cut_carries(batch, num_parts):
-            key, moved = sort_rows(key, batch.columns)
-        else:
-            key = jax.lax.sort(key, is_stable=False)
+        key, moved = sort_rows(pid * cap + jnp.arange(cap, dtype=jnp.int32),
+                               batch.columns)
         order = key % cap
     else:
         order = jnp.argsort(pid, stable=True).astype(jnp.int32)

@@ -128,17 +128,21 @@ def test_local_limit():
 
 # ---------------------------------------------------------------------------
 # A compaction's and a cut's fixed-width columns carried through the sort
-# (columnar/vector.py::sort_rows) against every plane gathered
+# (columnar/vector.py::sort_rows) against every column gathered
 # ---------------------------------------------------------------------------
 
+_DEC128 = dt.DecimalType(30, 2)
 _CARRY_SCHEMA = [("i32", dt.INT32), ("i64", dt.INT64), ("f32", dt.FLOAT32),
                  ("f64", dt.FLOAT64), ("d", dt.DATE), ("b", dt.BOOL),
-                 ("s", dt.STRING)]
+                 ("s", dt.STRING), ("dec", _DEC128)]
+_FIXED = ["i32", "i64", "f32", "f64", "d", "b"]
 
 
 def _carry_batch(n=200, cap=256):
     """Every fixed-width kind the sort carries, with nulls, extremes, NaN,
-    infinities and -0.0, beside a string column it does not."""
+    infinities and -0.0, beside a string and a 128-bit decimal it does
+    not."""
+    import decimal
     rng = np.random.default_rng(39)
 
     def nulls(values):
@@ -152,59 +156,72 @@ def _carry_batch(n=200, cap=256):
                          + rng.standard_normal(n - 4).tolist()),
             "d": nulls(rng.integers(0, 20000, n).tolist()),
             "b": nulls([bool(x) for x in rng.integers(0, 2, n)]),
-            "s": nulls(["x" * (i % 9) for i in range(n)])}
+            "s": nulls(["x" * (i % 9) for i in range(n)]),
+            "dec": nulls([decimal.Decimal(f"{i * 10 ** 20 + i}.{i % 100:02d}")
+                          for i in range(n)])}
     return batch_from_pydict(data, capacity=cap, schema=_CARRY_SCHEMA)
 
 
-def _bits(batch):
+def _bits(tree):
     import jax
     return [(x.dtype, x.shape, np.asarray(x).tobytes())
-            for x in jax.tree_util.tree_leaves(batch)]
+            for x in jax.tree_util.tree_leaves(tree)]
+
+
+def _traced(fn, gathers, name):
+    """``fn`` jitted, with the gathers its program holds counted into
+    ``gathers[name]``."""
+    import jax
+
+    def program(*args):
+        gathers[name] = str(jax.make_jaxpr(fn)(*args)).count("gather[")
+        return jax.jit(fn)(*args)
+    return program
 
 
 @pytest.mark.parametrize("case", ["compact_some", "compact_all",
                                   "compact_none", "filter_some",
                                   "cut_zero_capacity", "cut_over_capacity"])
-def test_sort_carried_rows_equal_the_gathered(monkeypatch, case):
-    """The same batches, bit for bit, whether the fixed-width columns ride
-    the sort or every plane is gathered by its result: values, validity,
-    ``num_rows``, dead rows zeroed and null, string byte buffers; a cut's
-    slices too, where a partition takes no capacity and where its rows
-    outrun its capacity."""
-    import jax
-    from spark_rapids_tpu.columnar import vector
+def test_sort_carried_rows_equal_the_gathered(case):
+    """The batches a compaction or a cut returns with the fixed-width
+    columns riding the sort are, bit for bit, those every column gathered
+    by the sorted positions gives: values, validity, ``num_rows``, dead
+    rows zeroed and null; strings and 128-bit decimals, which are still
+    gathered, with their buffers; a cut's slices too, where a partition
+    takes no capacity and where its rows outrun its capacity."""
+    from spark_rapids_tpu.columnar.vector import (ColumnarBatch,
+                                                  compaction_indices)
     from spark_rapids_tpu.parallel.partition import cut_batch
     batch = _carry_batch()
     rng = np.random.default_rng(7)
-
     gathers = {}
-
-    def traced(fn, carry):
-        # the threshold at or past the batch's capacity as the program
-        # traces; a new function each time, so that nothing is reused
-        monkeypatch.setattr(vector, "SORT_CARRY_MIN_CAPACITY",
-                            batch.capacity + (0 if carry else 1))
-
-        def program(*args):
-            fresh = lambda *a: fn(*a)  # noqa: E731
-            gathers[carry] = str(jax.make_jaxpr(fresh)(*args)).count("gather[")
-            return jax.jit(fresh)(*args)
-        return program
     if case.startswith("cut"):
-        pids = jnp.asarray(rng.integers(0, 8, 256).astype(np.int32))
+        pids = rng.integers(0, 8, 256).astype(np.int32)
         caps = (64, 0, 32, 64, 16, 64, 64, 64) if case == "cut_zero_capacity" \
             else (8,) * 8
 
-        def run(carry):
-            return traced(lambda b, p: cut_batch(b, p, 8, caps), carry)(
-                batch, pids)
-        gathered, carried = run(False), run(True)
-        assert gathers[True] < gathers[False]
-        assert [g is None for g in gathered] == [c == 0 for c in caps]
-        for g, c, cap in zip(gathered, carried, caps):
-            assert (g is None and c is None) or _bits(g) == _bits(c)
-            if cap:
-                assert int(c.num_rows) <= cap == c.capacity
+        def gathered(b):
+            # partition p's live rows in their order, the first caps[p]
+            out = []
+            for p, c in enumerate(caps):
+                rows = [i for i in range(200) if pids[i] == p][:c]
+                out.append(None if not c else b.gather(
+                    jnp.asarray(rows + [255] * (c - len(rows)), jnp.int32),
+                    jnp.int32(len(rows)), unique=True))
+            return out
+        carried = _traced(lambda b, p: cut_batch(b, p, 8, caps), gathers,
+                          "carried")(batch, jnp.asarray(pids))
+        want = _traced(gathered, gathers, "gathered")(batch)
+        assert gathers["carried"] < gathers["gathered"]
+        assert [c is None for c in carried] == [c == 0 for c in caps]
+        for c, w, cap in zip(carried, want, caps):
+            if not cap:
+                continue
+            assert c.capacity == cap and int(c.num_rows) == int(w.num_rows)
+            assert _bits(c.select(_FIXED).columns) == \
+                _bits(w.select(_FIXED).columns)
+            assert _bits(c.column("dec")) == _bits(w.column("dec"))
+            assert batch_to_pydict(c)["s"] == batch_to_pydict(w)["s"]
         if case == "cut_over_capacity":
             assert all(int(c.num_rows) == 8 for c in carried)
         return
@@ -212,81 +229,94 @@ def test_sort_carried_rows_equal_the_gathered(monkeypatch, case):
             "filter_some": rng.random(256) < 0.3,
             "compact_all": np.ones(256, bool),
             "compact_none": np.zeros(256, bool)}[case]
+    keep = jnp.asarray(keep)
     if case == "filter_some":
         b = batch.column("b")
-        cond = ColumnVector(jnp.asarray(keep) & b.data, b.validity, dt.BOOL)
-
-        def run(carry):
-            return traced(K.filter_batch, carry)(batch, cond)
+        cond = ColumnVector(keep & b.data, b.validity, dt.BOOL)
+        keep = cond.data & cond.validity
+        carried = _traced(K.filter_batch, gathers, "carried")(batch, cond)
     else:
-        def run(carry):
-            return traced(K.compact, carry)(batch, jnp.asarray(keep))
-    gathered, carried = run(False), run(True)
-    assert gathers[True] < gathers[False]
-    assert _bits(gathered) == _bits(carried)
+        carried = _traced(K.compact, gathers, "carried")(batch, keep)
+
+    def gathered(b: ColumnarBatch, k):
+        k = k & b.live_mask()
+        return b.gather(compaction_indices(k), jnp.sum(k).astype(jnp.int32),
+                        unique=True)
+    want = _traced(gathered, gathers, "gathered")(batch, keep)
+    assert gathers["carried"] < gathers["gathered"]
+    assert _bits(carried) == _bits(want)
     n = int(carried.num_rows)
     assert n == {"compact_all": 200, "compact_none": 0}.get(case, n)
-    for c in carried.columns[:-1]:
+    for name in _FIXED:
+        c = carried.column(name)
         assert not np.asarray(c.validity)[n:].any()
         assert not np.asarray(c.data)[n:].astype(bool).any()
-    want = batch_to_pydict(batch)
-    kept = [i for i in range(200) if keep[i] and (
-        case != "filter_some" or want["b"][i])]
+    rows = batch_to_pydict(batch)
+    kept = [i for i in range(200) if bool(keep[i])]
     got = batch_to_pydict(carried)
     for name, _ in _CARRY_SCHEMA:
-        exp = [want[name][i] for i in kept]
+        exp = [rows[name][i] for i in kept]
         if name == "f64":
             assert [repr(x) for x in got[name]] == [repr(x) for x in exp]
         else:
             assert got[name] == exp
 
 
-def test_sort_carries_from_capacity_and_column_kinds():
-    """The rule the host and the program share: a batch at the threshold
-    capacity with a fixed-width column rides the sort; half that
-    capacity, or strings alone, keep the gather."""
-    from spark_rapids_tpu.columnar.vector import (SORT_CARRY_MIN_CAPACITY,
-                                                  sort_carries)
+def test_sort_carries_from_column_kinds():
+    """The rule the host and the program share: a column rides the sort
+    by its type, a fixed-width one; strings, lists, structs, maps and
+    128-bit decimals are gathered. A cut carries too, until its key, a
+    slot's destination then its position, would pass 32 bits."""
+    from types import SimpleNamespace
+    from spark_rapids_tpu.columnar.vector import rides_sort, sort_carries
     from spark_rapids_tpu.parallel.partition import cut_carries
-    big = _carry_batch(cap=SORT_CARRY_MIN_CAPACITY)
-    assert sort_carries(big) and cut_carries(big, 8)
-    small = _carry_batch(cap=SORT_CARRY_MIN_CAPACITY // 2)
-    assert not sort_carries(small) and not cut_carries(small, 8)
-    assert not sort_carries(big.select(["s"]))
+    riders = [t for _, t in _CARRY_SCHEMA if rides_sort(t)]
+    assert riders == [t for n, t in _CARRY_SCHEMA if n in _FIXED]
+    assert rides_sort(dt.DecimalType(18, 2)) and rides_sort(dt.TIMESTAMP)
+    assert not any(rides_sort(t) for t in (
+        dt.STRING, _DEC128, dt.ArrayType(dt.INT32),
+        dt.StructType((("a", dt.INT32),)), dt.MapType(dt.STRING, dt.INT64)))
+    batch = _carry_batch()
+    assert sort_carries(batch.schema()) and cut_carries(batch, 8)
+    assert not sort_carries(batch.select(["s", "dec"]).schema())
+    huge = SimpleNamespace(capacity=1 << 28, schema=batch.schema)
+    assert not cut_carries(huge, 8) and cut_carries(huge, 6)
 
 
-@pytest.mark.parametrize("min_capacity", [None, 8])
-def test_compaction_counters_count_the_path_taken(monkeypatch, min_capacity):
+@pytest.mark.parametrize("kinds", ["fixed_width", "strings"])
+def test_compaction_counters_count_the_path_taken(kinds):
     """A filter's compaction and an exchange's cut land in the query's
-    ``phases`` as ``compact_sorted_batches`` where the batch's capacity
-    reaches the threshold and as ``compact_gathered_batches`` where it
-    does not, with the same answers."""
-    from spark_rapids_tpu.columnar import vector
+    ``phases`` as ``compact_sorted_batches`` where the batch has a column
+    that rides the sort and as ``compact_gathered_batches`` where every
+    column is a string, with the right answers either way."""
     from spark_rapids_tpu.conf import SrtConf
-    from spark_rapids_tpu.expr.core import col
+    from spark_rapids_tpu.expr.core import col, lit
     from spark_rapids_tpu.plan import TpuSession
-    if min_capacity:
-        monkeypatch.setattr(vector, "SORT_CARRY_MIN_CAPACITY", min_capacity)
     s = TpuSession(SrtConf({
         "srt.shuffle.partitions": 8,
         "srt.sql.adaptive.coalescePartitions.enabled": False}))
-    k = [i % 11 for i in range(100)]
+    k = [f"k{i % 11}" for i in range(100)]
     v = [float(i) for i in range(100)]
-    df = s.create_dataframe({"k": k, "v": v})
-    taken = "sorted" if min_capacity else "gathered"
-    other = "gathered" if min_capacity else "sorted"
-
-    kept = df.filter(col("v") > 60.0).collect()
-    assert [r["v"] for r in kept] == v[61:]
+    taken, other = ("sorted", "gathered") if kinds == "fixed_width" \
+        else ("gathered", "sorted")
+    if kinds == "fixed_width":
+        df = s.create_dataframe({"k": [i % 11 for i in range(100)], "v": v})
+        kept = df.filter(col("v") > lit(60.0)).collect()
+        assert [r["v"] for r in kept] == v[61:]
+    else:
+        df = s.create_dataframe({"k": k})
+        kept = df.filter(col("k") == lit("k3")).collect()
+        assert [r["k"] for r in kept] == [x for x in k if x == "k3"]
     phases = s._last_execution["phases"]
     assert phases[f"compact_{taken}_batches"] == 1
     assert phases[f"compact_{other}_batches"] == 0
 
-    sums = df.group_by(col("k")).agg(agg.Sum(col("v")).alias("sv")).collect()
-    assert sorted((r["k"], r["sv"]) for r in sums) == sorted(
-        (g, sum(x for key, x in zip(k, v) if key == g)) for g in range(11))
+    keys = k if kinds == "strings" else [i % 11 for i in range(100)]
+    out = df.select(col("k")).distinct().collect()
+    assert sorted(r["k"] for r in out) == sorted(set(keys))
     phases = s._last_execution["phases"]
     assert phases["exchange_split_batches"] >= 1
     assert phases[f"compact_{taken}_batches"] == \
         phases["exchange_split_batches"]
     assert phases[f"compact_{other}_batches"] == 0
+

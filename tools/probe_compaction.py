@@ -2,10 +2,11 @@
 """Times a filter's compaction (``ops/kernels.py::compact``) and an exchange's
 cut (``parallel/partition.py::cut_batch``) on the attached device, each both
 ways: ``gather``, one sort of the positions and then every plane gathered by
-the sorted positions, and ``carry``, the fixed-width planes carried through
-the sort as payload (``columnar/vector.py::sort_rows``: uint32 words and
-validity bits stacked into rows of one two-operand sort, float64 rows in a
-second). Strings are gathered either way.
+the sorted positions (written out here), and ``carry``, the engine's own,
+the fixed-width planes carried through the sort as payload
+(``columnar/vector.py::sort_rows``: uint32 words and validity bits stacked
+into rows of one two-operand sort, float64 rows in a second). Strings are
+gathered either way.
 
     python3 tools/probe_compaction.py                 # every shape below
     python3 tools/probe_compaction.py q3_filter       # the shapes named
@@ -39,9 +40,8 @@ import numpy as np  # noqa: E402
 
 from probe_first_kept import device_ms, time_launches  # noqa: E402
 from spark_rapids_tpu.columnar import dtypes as dt  # noqa: E402
-from spark_rapids_tpu.columnar import vector  # noqa: E402
 from spark_rapids_tpu.columnar.vector import (  # noqa: E402
-    ColumnarBatch, column_from_numpy)
+    ColumnarBatch, column_from_numpy, compaction_indices)
 from spark_rapids_tpu.ops import kernels as K  # noqa: E402
 from spark_rapids_tpu.parallel.partition import cut_batch  # noqa: E402
 
@@ -62,6 +62,17 @@ SHAPES = {
                     None),
     "filter_2e15": (1 << 15, 1 << 15, 2, "INT64,INT64,FLOAT64,STRING",
                     None),
+    # small batches: a dimension's filter beside a string, one of keys
+    # alone, and a cut of 8 partitions
+    "filter_2e14": (1 << 14, 1 << 14, 2, "INT64,INT64,FLOAT64,STRING",
+                    None),
+    "filter_2e12": (1 << 12, 1 << 12, 2, "INT64,INT64,FLOAT64,STRING",
+                    None),
+    "filter_2e10": (1 << 10, 1 << 10, 2, "INT64,INT64,FLOAT64,STRING",
+                    None),
+    "keys_2e12": (1 << 12, 3_000, 4, "INT64,INT64,INT64", None),
+    "cut_2e14": (1 << 14, 1 << 14, 0, "INT64,FLOAT64,FLOAT64", (8, 1 << 12)),
+    "cut_2e10": (1 << 10, 1 << 10, 0, "INT64,FLOAT64,FLOAT64", (8, 1 << 8)),
 }
 
 
@@ -81,30 +92,50 @@ def make_batch(rng, cap: int, live: int, types: str) -> ColumnarBatch:
     return ColumnarBatch(cols, [f"c{i}" for i in range(len(cols))], live)
 
 
+def gather_compact(b: ColumnarBatch, keep) -> ColumnarBatch:
+    """The compaction with every column gathered by the sorted positions."""
+    keep = keep & b.live_mask()
+    return b.gather(compaction_indices(keep), jnp.sum(keep).astype(jnp.int32),
+                    unique=True)
+
+
+def gather_cut(b: ColumnarBatch, part_ids, n_parts: int, slot: int):
+    """The cut with every column of each slice gathered by the sorted
+    positions (fixed-width columns only, as the cut shapes hold)."""
+    cap = b.capacity
+    pid = jnp.where(b.live_mask(), part_ids, n_parts)
+    counts = jnp.zeros(n_parts + 1, jnp.int32).at[pid].add(1)[:n_parts]
+    offsets = jnp.concatenate([jnp.zeros(1, jnp.int32),
+                               jnp.cumsum(counts, dtype=jnp.int32)])
+    order = jax.lax.sort(pid * cap + jnp.arange(cap, dtype=jnp.int32),
+                         is_stable=False) % cap
+    at = jnp.arange(slot, dtype=jnp.int32)
+    out = []
+    for p in range(n_parts):
+        rows = jnp.take(order, jnp.clip(offsets[p] + at, 0, cap - 1))
+        n = jnp.minimum(counts[p], slot)
+        out.append(ColumnarBatch([c.gather(rows, at < n) for c in b.columns],
+                                 b.names, n))
+    return out
+
+
 def programs(cap: int, one_in: int, parts, rng):
     """Formulation -> its program of (batch, keep or partition ids), and
-    that second argument, made once."""
+    that second argument, made once: ``gather`` here, ``carry`` the
+    engine's own ``compact`` or ``cut_batch``."""
     if one_in:
         arg = jnp.asarray(rng.integers(0, one_in, cap) == 0)
-
-        def step(b, k):
-            return K.compact(b, k)
+        steps = {"gather": gather_compact, "carry": K.compact}
     else:
         n_parts, slot = parts
         arg = jnp.asarray(rng.integers(0, n_parts, cap).astype(np.int32))
-
-        def step(b, p):
-            return cut_batch(b, p, n_parts, (slot,) * n_parts)
+        steps = {"gather": lambda b, p: gather_cut(b, p, n_parts, slot),
+                 "carry": lambda b, p: cut_batch(b, p, n_parts,
+                                                 (slot,) * n_parts)}
     out = {}
-    for name, least in (("gather", cap + 1), ("carry", cap)):
-        def fn(b, a, least=least):
-            # the engine reads SORT_CARRY_MIN_CAPACITY as it traces
-            kept = vector.SORT_CARRY_MIN_CAPACITY
-            vector.SORT_CARRY_MIN_CAPACITY = least
-            try:
-                return step(b, a)
-            finally:
-                vector.SORT_CARRY_MIN_CAPACITY = kept
+    for name, step in steps.items():
+        def fn(b, a, step=step):
+            return step(b, a)
         # the trace names a program after its function
         fn.__name__ = f"probe_{name}"
         out[name] = jax.jit(fn)
